@@ -1,6 +1,7 @@
 """Kernel micro-benchmarks: correctness-at-scale sweeps plus the analytic
 TPU benefit model for each Pallas kernel (wall-clock on CPU interpret mode
 is meaningless; the TPU win is structural and computed from traffic).
+Every traffic ratio here is a ROOFLINE MODEL, not a chip measurement.
 
   int8_matmul_fq     : fused-quantize prologue removes the standalone
                        quantize pass (fp32 read + int8 write of the full
@@ -612,7 +613,7 @@ def _residue_rows(rows) -> None:
           jax.random.normal(ks[4], (M, N)))
     bv = jnp.repeat(jnp.arange(B, dtype=jnp.int32), M // B)
     out = int8_matmul_fq(x, wq, sx, zx, scale, corr, bias, g=1, ps=ps,
-                         nm=nm, gr=gr, bv=bv, interpret=True)
+                         nm=nm, gr=gr, rows_per_batch=M // B, interpret=True)
     want = jax.jit(lambda *a: ref.int8_matmul_fq_fused_ref(
         *a, bias, g=1, ps=ps, nm=nm, gr=gr, bv=bv))(x, wq, sx, zx, scale,
                                                     corr)

@@ -30,7 +30,8 @@ def measure_variant(arch, shape_id, overrides=None, mesh_shape=None,
     from benchmarks.roofline import analyse
 
     if mesh_shape is not None:
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        from repro.launch.mesh import make_debug_mesh
+        mesh = make_debug_mesh(*mesh_shape)
         tp = mesh_shape[1]
     else:
         from repro.launch.mesh import make_production_mesh

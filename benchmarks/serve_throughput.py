@@ -1,6 +1,10 @@
 """Serving throughput: fused-int8 vs fp requests/sec under the sharded
 batched serving subsystem (``repro.serving``).
 
+Every rate and time this module reports is a ROOFLINE MODEL of a v5e
+chip, or a CPU run that checks identities — not a chip measurement. The
+executed sections force the CPU (``JAX_PLATFORMS=cpu``) on purpose.
+
 Two sections, same philosophy as ``kernel_micro``:
 
 1. **Modeled (TPU v5e)** — per-op roofline over one CFG-paired DiT-XL/2
@@ -393,7 +397,10 @@ def bench_serve_data(steps: int = 100, b_local: int = 2) -> dict:
     buckets = (25, 50, 100)
     micro, chunk = b_local * N_DEV, 5
     trace = poisson_trace(400, 16.0, buckets, seed=7)
-    data = {"meta": {"model": "DiT-XL/2", "n_dev": N_DEV,
+    data = {"meta": {"source": "roofline model (benchmarks/"
+                               "serve_throughput.py), not a chip "
+                               "measurement",
+                     "model": "DiT-XL/2", "n_dev": N_DEV,
                      "slots_per_device": b_local, "steps": steps,
                      "buckets": list(buckets), "chunk": chunk,
                      "load_rps": 16.0},

@@ -88,9 +88,8 @@ def save(path: str, step: int, tree: Any, keep: int = 3,
         "step": step,
         "n_leaves": len(arrays),
         "index": index,
-        "treedef": jax.tree_util.tree_structure(tree).serialize_using_proto().hex()
-        if hasattr(jax.tree_util.tree_structure(tree), "serialize_using_proto")
-        else None,
+        "treedef": jax.tree_util.tree_structure(
+            tree).serialize_using_proto().hex(),
         "hashes": hashes,
         "dtypes": [str(a.dtype) for a in arrays],
         "shapes": [list(a.shape) for a in arrays],

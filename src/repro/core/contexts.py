@@ -18,7 +18,8 @@ Provenance tracking uses tensor identity: ``act(name, x, kind)`` marks
 ``id(x)`` so the directly-consuming matmul knows its operand is the
 specially-distributed tensor the paper treats with MRQ/TGQ. This works
 both eagerly (concrete arrays) and under a single trace (tracer ids are
-stable within a trace).
+stable within a trace). The mark holds the tensor itself and matches
+only that object: an id alone is reused as soon as its array is freed.
 """
 from __future__ import annotations
 
@@ -62,7 +63,7 @@ class RecordingContext(OpContext):
     """
     registry: Dict[str, OpInfo] = dataclasses.field(default_factory=dict)
     acts: Dict[str, str] = dataclasses.field(default_factory=dict)
-    _marks: Dict[int, str] = dataclasses.field(default_factory=dict)
+    _marks: Dict[int, tuple] = dataclasses.field(default_factory=dict)
 
     def _reg(self, name, **kw):
         if name in self.registry:
@@ -80,7 +81,7 @@ class RecordingContext(OpContext):
         # looked up on the ORIGINAL tensor — fusion sites with norm_mod
         # have plain inputs (the post-GELU fc2 site carries only
         # gate_residual, which leaves x untouched).
-        a_kind = self._marks.get(id(x), "plain")
+        a_kind = self._kind_of(x)
         x = apply_norm_mod(x, norm_mod)
         self._reg(name, kind="linear", a_kind=a_kind,
                   x_shape=tuple(x.shape), w_shape=tuple(w.shape))
@@ -92,16 +93,20 @@ class RecordingContext(OpContext):
 
     def einsum(self, name, spec, a, b, b_is_weight=False):
         self._reg(name, kind="einsum", spec=spec, b_is_weight=b_is_weight,
-                  a_kind=self._marks.get(id(a), "plain"),
+                  a_kind=self._kind_of(a),
                   x_shape=tuple(a.shape), w_shape=tuple(b.shape))
         y = jnp.einsum(spec, a, b)
         self.registry[name].out_shape = tuple(y.shape)
         return y
 
     def act(self, name, x, kind):
-        self._marks[id(x)] = kind
+        self._marks[id(x)] = (kind, x)
         self.acts[name] = kind
         return x
+
+    def _kind_of(self, x) -> str:
+        mark = self._marks.get(id(x))
+        return mark[0] if mark is not None and mark[1] is x else "plain"
 
 
 # ---------------------------------------------------------------------------
@@ -281,6 +286,22 @@ class QuantContext(OpContext):
     qparams: Dict[str, dict] = dataclasses.field(default_factory=dict)
     kernel: bool = False
     attn_impl: str = "flash"
+
+    def split_arrays(self):
+        """The qparams' array leaves (quantizer parameters and kernel
+        packs); every other leaf — bit widths, group counts — stays static
+        in the rebuilt context. See ``OpContext.split_arrays``."""
+        leaves, tree = jax.tree.flatten(self.qparams)
+        idx = [i for i, a in enumerate(leaves)
+               if isinstance(a, (jax.Array, np.ndarray))]
+
+        def rebuild(arrays):
+            out = list(leaves)
+            for i, a in zip(idx, arrays):
+                out[i] = a
+            return dataclasses.replace(
+                self, qparams=jax.tree.unflatten(tree, out))
+        return tuple(leaves[i] for i in idx), rebuild
 
     def _q_in(self, qp, x):
         q = qp.get("x")

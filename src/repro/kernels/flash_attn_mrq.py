@@ -83,14 +83,33 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.int4_packed import nibble_split, pack_int4
-from repro.kernels.int8_bmm import _sym_codes
-from repro.kernels.int8_matmul import _ceil, _pad_to
+from repro.kernels.int8_bmm import _sym_codes, _sym_levels
+from repro.kernels.int8_matmul import _ceil, _group_param, _pad_to, _stack3
 
-# q-tile covers DiT-XL/2's full S = 256, so K/V stream from HBM exactly
-# once there; VMEM stays small (q/acc1/acc2 tiles: 3 x 256 x hd f32).
+# The q-tile covers DiT-XL/2's full S = 256, so K/V stream from HBM
+# exactly once there.
 DEFAULT_BM = 256
-DEFAULT_BN = 128
+# The kv tile follows the sequence: one tile over all N keys while the
+# (bm, N) f32 score tile fits ONE_TILE_SCORE_BYTES of VMEM (N <= 1024 at
+# bm = 256), MULTI_TILE_BN-wide tiles above. Accuracy contract at that
+# boundary: with one kv tile the prob codes round against the final
+# softmax sum and flash equals the composed path to f32 ulp; with several,
+# the codes of every tile but the last round against a partial sum
+# (bounded by ``ref.flash_vs_composed_atol``), which at XL/2 W8A8 on a
+# v5e doubled eps's distance from fake-quant (6.7e-2 vs 3.2e-2).
+ONE_TILE_SCORE_BYTES = 1 << 20
+MULTI_TILE_BN = 256
 _M_INIT = -1e30         # below any masked score; exp(_M_INIT - m) == 0.0
+
+
+def kv_tile(m: int, n: int, bm: int = DEFAULT_BM) -> int:
+    """The kv tile width for ``m`` queries against ``n`` keys at q-tile
+    ``bm`` when the caller gives none: the whole padded sequence if its
+    score tile fits ``ONE_TILE_SCORE_BYTES``, else ``MULTI_TILE_BN``."""
+    full = _ceil(n)
+    if min(bm, _ceil(m)) * full * 4 <= ONE_TILE_SCORE_BYTES:
+        return full
+    return MULTI_TILE_BN
 
 
 def _flash_kernel(g_ref, *refs, nkv: int, half: int, n_real: int, bn: int,
@@ -131,12 +150,12 @@ def _flash_kernel(g_ref, *refs, nkv: int, half: int, n_real: int, bn: int,
     q8 = _sym_codes(q_ref[0], sq_ref[0, 0], half)
     if packed_kv:                # widen two-nibbles-per-byte codes in VMEM
         lo, hi = nibble_split(k_ref[0])
-        k8 = jnp.stack([lo, hi], axis=2).reshape(k_ref.shape[1], bd)
+        k8 = jnp.stack([lo, hi], axis=2).reshape(
+            k_ref.shape[1], bd).astype(jnp.int8)
     else:
-        k8 = _sym_codes(k_ref[0], sk_ref[0, 0], half).astype(jnp.int32)
+        k8 = _sym_codes(k_ref[0], sk_ref[0, 0], half)
     s = jax.lax.dot_general(
-        q8.astype(jnp.int32), k8,
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
+        q8, k8, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
     ).astype(jnp.float32) * qs_ref[0, 0]
 
     # -- NEG_INF masking BEFORE the online max ------------------------------
@@ -162,19 +181,23 @@ def _flash_kernel(g_ref, *refs, nkv: int, half: int, n_real: int, bn: int,
     s2 = 1.0 / half
     region1 = p < half * s1
     c1 = jnp.where(region1, jnp.clip(jnp.round(p / s1), 0, half - 1), 0.0
-                   ).astype(jnp.int32)
-    c2 = jnp.where(region1, 0.0, jnp.clip(jnp.round(p / s2), 0, half)
-                   ).astype(jnp.int32)
+                   ).astype(jnp.int8)
+    # region-2 codes reach 2^{k-1}, one past the s8 maximum: they enter the
+    # MXU negated, against the negated v codes (symmetric, so still s8)
+    c2n = jnp.where(region1, 0.0, -jnp.clip(jnp.round(p / s2), 0, half)
+                    ).astype(jnp.int8)
 
     # -- dual-region P·V with fp running-rescale ----------------------------
     if packed_kv:
         lo_v, hi_v = nibble_split(v_ref[0])
-        v8 = jnp.stack([lo_v, hi_v], axis=2).reshape(v_ref.shape[1], bd)
+        vq = jnp.stack([lo_v, hi_v], axis=2).reshape(v_ref.shape[1], bd)
     else:
-        v8 = _sym_codes(v_ref[0], sv_ref[0, 0], half).astype(jnp.int32)
+        vq = _sym_levels(v_ref[0], sv_ref[0, 0], half)
     dims = (((1,), (0,)), ((), ()))                  # ONE v-tile read
-    d1 = jax.lax.dot_general(c1, v8, dims, preferred_element_type=jnp.int32)
-    d2 = jax.lax.dot_general(c2, v8, dims, preferred_element_type=jnp.int32)
+    d1 = jax.lax.dot_general(c1, vq.astype(jnp.int8), dims,
+                             preferred_element_type=jnp.int32)
+    d2 = jax.lax.dot_general(c2n, (-vq).astype(jnp.int8), dims,
+                             preferred_element_type=jnp.int32)
     rho = corr * l_prev / l_new                      # <= 1; 0 at n == 0
     acc1_ref[...] = acc1_ref[...] * rho + d1.astype(jnp.float32)
     acc2_ref[...] = acc2_ref[...] * rho + d2.astype(jnp.float32)
@@ -191,7 +214,7 @@ def _flash_kernel(g_ref, *refs, nkv: int, half: int, n_real: int, bn: int,
                                              "out_dtype", "interpret"))
 def flash_attn_mrq(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
                    g_qk=None, g_pv=None, mask=None, *, bits=8,
-                   packed_kv=False, bm=DEFAULT_BM, bn=DEFAULT_BN,
+                   packed_kv=False, bm=DEFAULT_BM, bn=None,
                    out_dtype=jnp.float32, interpret=False):
     """out[B,M,D] = MRQ-quantized softmax(q8 k8^T · qk_scale[g]) @ v8 —
     one kernel, no (S, S) HBM round-trip.
@@ -228,7 +251,8 @@ def flash_attn_mrq(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
     assert s_v.shape == (Gp, 1) and scale1.shape == (Gp, 1) \
         and scale2.shape == (Gp, 1), (s1.shape, s_v.shape)
     half = 2 ** (bits - 1)
-    bm_, bn_ = min(bm, _ceil(M)), min(bn, _ceil(N))
+    bm_ = min(bm, _ceil(M))
+    bn_ = kv_tile(M, N, bm) if bn is None else min(bn, _ceil(N))
     bd_ = _ceil(D)
     Mp, Np = _pad_to(M, bm_), _pad_to(N, bn_)
 
@@ -265,14 +289,12 @@ def flash_attn_mrq(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
         operands.append(mask8)
         in_specs.append(
             pl.BlockSpec((1, bm_, bn_), lambda b, m, n, g: (b, m, n)))
-    qk_row = lambda b, m, n, g: (g[0], 0)                    # qk-side group
-    pv_row = lambda b, m, n, g: (g[1], 0)                    # pv-side group
-    operands += [s_q.astype(jnp.float32), s_k.astype(jnp.float32),
-                 qk_scale.astype(jnp.float32), s1.astype(jnp.float32),
-                 s_v.astype(jnp.float32), scale1.astype(jnp.float32),
-                 scale2.astype(jnp.float32)]
-    in_specs += [pl.BlockSpec((1, 1), qk_row)] * 3 \
-        + [pl.BlockSpec((1, 1), pv_row)] * 4
+    qk_row = lambda b, m, n, g: (g[0], 0, 0)                 # qk-side group
+    pv_row = lambda b, m, n, g: (g[1], 0, 0)                 # pv-side group
+    operands += [_stack3(p.astype(jnp.float32)) for p in
+                 (s_q, s_k, qk_scale, s1, s_v, scale1, scale2)]
+    in_specs += [_group_param((1,), qk_row)] * 3 \
+        + [_group_param((1,), pv_row)] * 4
 
     # the one masking value, shared with the composed path and the oracle
     # (deferred import: repro.nn pulls in model layers at package init)
@@ -304,7 +326,7 @@ def flash_attn_mrq(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
                                              "out_dtype", "interpret"))
 def flash_attn_mrq_vec(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
                        g_qk=None, g_pv=None, mask=None, *, bits=8,
-                       packed_kv=False, bm=DEFAULT_BM, bn=DEFAULT_BN,
+                       packed_kv=False, bm=DEFAULT_BM, bn=None,
                        out_dtype=jnp.float32, interpret=False):
     """Vector-tgroup ``flash_attn_mrq``: per-BATCH-ROW group vectors.
 
@@ -312,7 +334,7 @@ def flash_attn_mrq_vec(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
     params. The kernel BODY is ``_flash_kernel`` unchanged; only the
     prefetch layout differs — the two vectors ride concatenated as one
     (2B,) prefetched array and the param index maps pick ``(g[b], 0)`` /
-    ``(g[B + b], 0)``, so each grid row DMAs exactly its group's (1, 1)
+    ``(g[B + b], 0, 0)``, so each grid row DMAs exactly its group's (1, 1)
     param rows (the per-group gather stays in the index maps; weights —
     here the kv stream — are untouched by the group mix). Constant
     vectors are bit-identical to scalar ``g_qk``/``g_pv``.
@@ -333,7 +355,8 @@ def flash_attn_mrq_vec(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
     assert s_v.shape == (Gp, 1) and scale1.shape == (Gp, 1) \
         and scale2.shape == (Gp, 1), (s1.shape, s_v.shape)
     half = 2 ** (bits - 1)
-    bm_, bn_ = min(bm, _ceil(M)), min(bn, _ceil(N))
+    bm_ = min(bm, _ceil(M))
+    bn_ = kv_tile(M, N, bm) if bn is None else min(bn, _ceil(N))
     bd_ = _ceil(D)
     Mp, Np = _pad_to(M, bm_), _pad_to(N, bn_)
 
@@ -376,14 +399,12 @@ def flash_attn_mrq_vec(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
         operands.append(mask8)
         in_specs.append(
             pl.BlockSpec((1, bm_, bn_), lambda b, m, n, g: (b, m, n)))
-    qk_row = lambda b, m, n, g: (g[b], 0)                # row b's qk group
-    pv_row = lambda b, m, n, g: (g[B + b], 0)            # row b's pv group
-    operands += [s_q.astype(jnp.float32), s_k.astype(jnp.float32),
-                 qk_scale.astype(jnp.float32), s1.astype(jnp.float32),
-                 s_v.astype(jnp.float32), scale1.astype(jnp.float32),
-                 scale2.astype(jnp.float32)]
-    in_specs += [pl.BlockSpec((1, 1), qk_row)] * 3 \
-        + [pl.BlockSpec((1, 1), pv_row)] * 4
+    qk_row = lambda b, m, n, g: (g[b], 0, 0)             # row b's qk group
+    pv_row = lambda b, m, n, g: (g[B + b], 0, 0)         # row b's pv group
+    operands += [_stack3(p.astype(jnp.float32)) for p in
+                 (s_q, s_k, qk_scale, s1, s_v, scale1, scale2)]
+    in_specs += [_group_param((1,), qk_row)] * 3 \
+        + [_group_param((1,), pv_row)] * 4
 
     from repro.nn.ctx import NEG_INF
 

@@ -35,14 +35,16 @@ s32 partial product of each K step is dequantized into a persistent
 
 TGQ rides the same scalar-prefetch contract as ``int8_fused``: all
 activation-side params are (G, ·)-stacked, ``g`` is a traced scalar
-gathered by the BlockSpec index maps (scale/corr are (G, nk, N) with
-``(g[0], k, n)`` maps), so the DDPM scan still compiles ONCE.
+gathered by the BlockSpec index maps (scale/corr are (G, nk, N), viewed
+as (G, nk, 1, N) with ``(g[0], k, 0, n)`` maps), so the DDPM scan still
+compiles ONCE.
 
 ``int4_matmul_fq_vec`` / ``int4_matmul_mrq_fq_vec`` are the
 vector-tgroup variants (see ``int8_fused``): a per-ROW (M,) group vector
-replaces the scalar prefetch, the (G, 1, bn) param slices of EVERY group
+replaces the scalar prefetch, the (G, bn) param slices of EVERY group
 stream per K step, and each row gathers its own group's params in VMEM
-via the exact one-hot product — one nibble-packed weight stream covers a
+with the exact select of ``int8_fused._gather_rows`` — one nibble-packed
+weight stream covers a
 batch mixing timestep groups.
 
 Prologue/epilogue fusions: the whole family shares ``int8_fused``'s
@@ -74,11 +76,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.int8_fused import (
-    _fusion_epilogue, _fusion_prologue, _fusion_specs_args, _gather_rows,
-    _onehot_rows, _prep_fusions, _unpack_fusion_refs,
+    _fusion_epilogue, _fusion_prologue, _fusions, _gather_rows,
+    _unpack_fusion_refs,
 )
 from repro.kernels.int8_matmul import (
-    DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, _ceil, _pad_to,
+    DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, _ceil, _group_param, _pad_to, _stack3,
 )
 
 
@@ -125,14 +127,28 @@ def unpack_int4(packed, k=None, axis=0):
 
 
 def _unpack_w(w_ref, bk):
-    """VMEM prologue: (bk/2, bn) packed bytes -> (bk, bn) s32 codes."""
+    """VMEM prologue: (bk/2, bn) packed bytes -> (bk, bn) s8 MXU codes."""
     lo, hi = nibble_split(w_ref[...])
-    return jnp.stack([lo, hi], axis=1).reshape(bk, w_ref.shape[-1])
+    return jnp.stack([lo, hi], axis=1).reshape(
+        bk, w_ref.shape[-1]).astype(jnp.int8)
 
 
-def _fq4_kernel(g_ref, *refs, nk: int, bk: int, half: int,
-                has_ps: bool = False, has_nm: bool = False,
-                has_gr: bool = False):
+def _kgroup_param(bn_, index_map):
+    """BlockSpec for one (group, K step) row of a (G, nk, 1, N) scale or
+    correction stack: both leading axes squeezed, so the last two block
+    dims equal the array's (1, ·) at any G and nk."""
+    return pl.BlockSpec((pl.squeezed, pl.squeezed, 1, bn_), index_map)
+
+
+def _kstep_stack(G, bn_, nbn):
+    """BlockSpec for K step k's (G, bn) slice of every group's scales or
+    corrections, over a (G, nk, Np) stack viewed as (G, nk * Np) — a free
+    reshape, where a block over the (nk, ·) axes would not tile: K step
+    k, column tile n is block column ``k * nbn + n``."""
+    return pl.BlockSpec((G, bn_), lambda m, n, k: (0, k * nbn + n))
+
+
+def _fq4_kernel(g_ref, *refs, nk: int, bk: int, half: int, **fusions):
     """Grid body for ``int4_matmul_fq`` at grid point (m, n, k).
 
     One K step == one weight-scale group: the (bk/2, bn) packed tile is
@@ -146,8 +162,7 @@ def _fq4_kernel(g_ref, *refs, nk: int, bk: int, half: int,
     x_ref, w_ref, sx_ref, zx_ref, scale_ref, corr_ref, bias_ref = refs[:7]
     o_ref, acc_ref = refs[-2], refs[-1]
     ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
-        _unpack_fusion_refs(refs[7:-2], has_ps=has_ps, has_nm=has_nm,
-                            has_gr=has_gr)
+        _unpack_fusion_refs(refs[7:-2], **fusions)
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -162,10 +177,9 @@ def _fq4_kernel(g_ref, *refs, nk: int, bk: int, half: int,
                   -half, half - 1).astype(jnp.int8)
     w = _unpack_w(w_ref, bk)
     partial = jax.lax.dot_general(
-        xq.astype(jnp.int32), w,
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-    acc_ref[...] += ((partial - corr_ref[0, 0][None, :]).astype(jnp.float32)
-                     * scale_ref[0, 0][None, :])
+        xq, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+    acc_ref[...] += ((partial - corr_ref[...]).astype(jnp.float32)
+                     * scale_ref[...])
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -175,9 +189,10 @@ def _fq4_kernel(g_ref, *refs, nk: int, bk: int, half: int,
 
 
 @functools.partial(jax.jit, static_argnames=("group_k", "bm", "bn",
-                                             "out_dtype", "interpret"))
+                                             "rows_per_batch", "out_dtype",
+                                             "interpret"))
 def int4_matmul_fq(x, wp, sx, zx, scale, corr, bias=None, g=None, *,
-                   ps=None, nm=None, gr=None, bv=None,
+                   ps=None, nm=None, gr=None, rows_per_batch=None,
                    group_k=DEFAULT_BK, bm=DEFAULT_BM, bn=DEFAULT_BN,
                    out_dtype=jnp.float32, interpret=False):
     """y[M,N] = sum_k (q4(x_k; sx[g], zx[g]) @ s4(wp_k) - corr[g,k]) * scale[g,k].
@@ -189,7 +204,8 @@ def int4_matmul_fq(x, wp, sx, zx, scale, corr, bias=None, g=None, *,
     per-K-group zero-point corrections. ``group_k`` is the pack-time
     K-group size and MUST equal the kernel's K tile (it is the K tile).
     g as in ``int8_matmul_fq``: python int or traced scalar.
-    Optional ``ps``/``nm``/``gr``/``bv`` fusions as ``int8_matmul_fq``.
+    Optional ``ps``/``nm``/``gr``/``rows_per_batch`` fusions as
+    ``int8_matmul_fq``.
     """
     M, K = x.shape
     Kp = 2 * wp.shape[0]
@@ -207,8 +223,9 @@ def int4_matmul_fq(x, wp, sx, zx, scale, corr, bias=None, g=None, *,
         bias = jnp.zeros((N,), jnp.float32)
     if g is None:
         g = 0
-    ps2, bv2, nm_rows, gr_rows = _prep_fusions(
-        x, ps, nm, gr, bv, M=M, K=K, N=N, Mp=Mp, Kp=Kp, Np=Np)
+    fspecs, fargs, fusions = _fusions(
+        x, ps, nm, gr, rows_per_batch, has_g=True, M=M, K=K, N=N, Mp=Mp,
+        Kp=Kp, Np=Np, bm_=bm_, bk_=group_k, bn_=bn_)
     x = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))
     wp = jnp.pad(wp, ((0, 0), (0, Np - N)))
     scale = jnp.pad(scale.astype(jnp.float32), ((0, 0), (0, 0), (0, Np - N)))
@@ -220,9 +237,6 @@ def int4_matmul_fq(x, wp, sx, zx, scale, corr, bias=None, g=None, *,
     # gathered axis: scale/corr are (G, nk, N) and each K step pulls its
     # own (g, k) row — the per-group weight scales ride the grid, not the
     # executable, so one compile still covers all timestep groups.
-    fspecs, fargs = _fusion_specs_args(
-        has_g=True, ps=ps2, bv=bv2, nm_rows=nm_rows, gr_rows=gr_rows,
-        bm_=bm_, bk_=group_k, bn_=bn_)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
@@ -230,12 +244,10 @@ def int4_matmul_fq(x, wp, sx, zx, scale, corr, bias=None, g=None, *,
             pl.BlockSpec((bm_, group_k), lambda m, n, k, g: (m, k)),   # x
             pl.BlockSpec((group_k // 2, bn_),
                          lambda m, n, k, g: (k, n)),         # packed W
-            pl.BlockSpec((1, 1), lambda m, n, k, g: (g[0], 0)),        # sx[g]
-            pl.BlockSpec((1, 1), lambda m, n, k, g: (g[0], 0)),        # zx[g]
-            pl.BlockSpec((1, 1, bn_),
-                         lambda m, n, k, g: (g[0], k, n)),   # scale[g, k]
-            pl.BlockSpec((1, 1, bn_),
-                         lambda m, n, k, g: (g[0], k, n)),   # corr[g, k]
+            _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),   # sx[g]
+            _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),   # zx[g]
+            _kgroup_param(bn_, lambda m, n, k, g: (g[0], k, 0, n)),  # scale
+            _kgroup_param(bn_, lambda m, n, k, g: (g[0], k, 0, n)),  # corr
             pl.BlockSpec((1, bn_), lambda m, n, k, g: (0, n)),         # bias
         ] + fspecs,
         out_specs=pl.BlockSpec((bm_, bn_), lambda m, n, k, g: (m, n)),
@@ -243,20 +255,17 @@ def int4_matmul_fq(x, wp, sx, zx, scale, corr, bias=None, g=None, *,
     )
     out = pl.pallas_call(
         functools.partial(_fq4_kernel, nk=nk, bk=group_k, half=8,
-                          has_ps=ps2 is not None, has_nm=nm_rows is not None,
-                          has_gr=gr_rows is not None),
+                          **fusions),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         interpret=interpret,
     )(jnp.asarray(g, jnp.int32).reshape(1), x, wp,
-      sx.astype(jnp.float32), zx.astype(jnp.float32), scale, corr, bias,
-      *fargs)
+      _stack3(sx.astype(jnp.float32)), _stack3(zx.astype(jnp.float32)),
+      scale.reshape(G, nk, 1, Np), corr.reshape(G, nk, 1, Np), bias, *fargs)
     return out[:M, :N]
 
 
-def _mrq4_kernel(g_ref, *refs, nk: int, bk: int, half: int,
-                 has_ps: bool = False, has_nm: bool = False,
-                 has_gr: bool = False):
+def _mrq4_kernel(g_ref, *refs, nk: int, bk: int, half: int, **fusions):
     """Grid body for ``int4_matmul_mrq_fq`` at grid point (m, n, k).
 
     MRQ twin-region split as in ``int8_fused._mrq_kernel`` — ONE unpacked
@@ -270,8 +279,7 @@ def _mrq4_kernel(g_ref, *refs, nk: int, bk: int, half: int,
         refs[:7]
     o_ref, acc_ref = refs[-2], refs[-1]
     ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
-        _unpack_fusion_refs(refs[7:-2], has_ps=has_ps, has_nm=has_nm,
-                            has_gr=has_gr)
+        _unpack_fusion_refs(refs[7:-2], **fusions)
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -287,12 +295,10 @@ def _mrq4_kernel(g_ref, *refs, nk: int, bk: int, half: int,
                    ).astype(jnp.int8)
     w = _unpack_w(w_ref, bk)                  # ONE weight-tile read, two dots
     dims = (((1,), (0,)), ((), ()))
-    pn = jax.lax.dot_general(qn.astype(jnp.int32), w, dims,
-                             preferred_element_type=jnp.int32)
-    pp = jax.lax.dot_general(qp.astype(jnp.int32), w, dims,
-                             preferred_element_type=jnp.int32)
-    acc_ref[...] += (pn.astype(jnp.float32) * scale_n_ref[0, 0][None, :]
-                     + pp.astype(jnp.float32) * scale_p_ref[0, 0][None, :])
+    pn = jax.lax.dot_general(qn, w, dims, preferred_element_type=jnp.int32)
+    pp = jax.lax.dot_general(qp, w, dims, preferred_element_type=jnp.int32)
+    acc_ref[...] += (pn.astype(jnp.float32) * scale_n_ref[...]
+                     + pp.astype(jnp.float32) * scale_p_ref[...])
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -302,9 +308,11 @@ def _mrq4_kernel(g_ref, *refs, nk: int, bk: int, half: int,
 
 
 @functools.partial(jax.jit, static_argnames=("group_k", "bm", "bn",
-                                             "out_dtype", "interpret"))
+                                             "rows_per_batch", "out_dtype",
+                                             "interpret"))
 def int4_matmul_mrq_fq(x, wp, s_neg, s_pos, scale_neg, scale_pos, bias=None,
-                       g=None, *, ps=None, nm=None, gr=None, bv=None,
+                       g=None, *, ps=None, nm=None, gr=None,
+                       rows_per_batch=None,
                        group_k=DEFAULT_BK, bm=DEFAULT_BM,
                        bn=DEFAULT_BN, out_dtype=jnp.float32, interpret=False):
     """Single-pass MRQ matmul on nibble-packed weights, per-K-group scales.
@@ -312,7 +320,7 @@ def int4_matmul_mrq_fq(x, wp, s_neg, s_pos, scale_neg, scale_pos, bias=None,
     y = sum_k s_neg[g]*sw[k]*(qn_k @ w_k) + s_pos[g]*sw[k]*(qp_k @ w_k)
     (+ bias). Operand layout as ``int4_matmul_fq`` but with the twin
     region steps s_neg/s_pos (G, 1) and scales scale_neg/scale_pos
-    (G, nk, N). Optional ``ps``/``nm``/``gr``/``bv`` fusions as
+    (G, nk, N). Optional ``ps``/``nm``/``gr``/``rows_per_batch`` fusions as
     ``int8_matmul_fq``.
     """
     M, K = x.shape
@@ -330,8 +338,9 @@ def int4_matmul_mrq_fq(x, wp, s_neg, s_pos, scale_neg, scale_pos, bias=None,
         bias = jnp.zeros((N,), jnp.float32)
     if g is None:
         g = 0
-    ps2, bv2, nm_rows, gr_rows = _prep_fusions(
-        x, ps, nm, gr, bv, M=M, K=K, N=N, Mp=Mp, Kp=Kp, Np=Np)
+    fspecs, fargs, fusions = _fusions(
+        x, ps, nm, gr, rows_per_batch, has_g=True, M=M, K=K, N=N, Mp=Mp,
+        Kp=Kp, Np=Np, bm_=bm_, bk_=group_k, bn_=bn_)
     x = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))
     wp = jnp.pad(wp, ((0, 0), (0, Np - N)))
     scale_neg = jnp.pad(scale_neg.astype(jnp.float32),
@@ -341,9 +350,6 @@ def int4_matmul_mrq_fq(x, wp, s_neg, s_pos, scale_neg, scale_pos, bias=None,
     bias = jnp.pad(bias.astype(jnp.float32), (0, Np - N)).reshape(1, Np)
 
     grid = (Mp // bm_, Np // bn_, nk)
-    fspecs, fargs = _fusion_specs_args(
-        has_g=True, ps=ps2, bv=bv2, nm_rows=nm_rows, gr_rows=gr_rows,
-        bm_=bm_, bk_=group_k, bn_=bn_)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
@@ -351,12 +357,10 @@ def int4_matmul_mrq_fq(x, wp, s_neg, s_pos, scale_neg, scale_pos, bias=None,
             pl.BlockSpec((bm_, group_k), lambda m, n, k, g: (m, k)),   # x
             pl.BlockSpec((group_k // 2, bn_),
                          lambda m, n, k, g: (k, n)),         # packed W
-            pl.BlockSpec((1, 1), lambda m, n, k, g: (g[0], 0)),     # s_neg[g]
-            pl.BlockSpec((1, 1), lambda m, n, k, g: (g[0], 0)),     # s_pos[g]
-            pl.BlockSpec((1, 1, bn_),
-                         lambda m, n, k, g: (g[0], k, n)),   # scale_neg[g, k]
-            pl.BlockSpec((1, 1, bn_),
-                         lambda m, n, k, g: (g[0], k, n)),   # scale_pos[g, k]
+            _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),   # s_neg[g]
+            _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),   # s_pos[g]
+            _kgroup_param(bn_, lambda m, n, k, g: (g[0], k, 0, n)),  # scale_n
+            _kgroup_param(bn_, lambda m, n, k, g: (g[0], k, 0, n)),  # scale_p
             pl.BlockSpec((1, bn_), lambda m, n, k, g: (0, n)),         # bias
         ] + fspecs,
         out_specs=pl.BlockSpec((bm_, bn_), lambda m, n, k, g: (m, n)),
@@ -364,43 +368,38 @@ def int4_matmul_mrq_fq(x, wp, s_neg, s_pos, scale_neg, scale_pos, bias=None,
     )
     out = pl.pallas_call(
         functools.partial(_mrq4_kernel, nk=nk, bk=group_k, half=8,
-                          has_ps=ps2 is not None, has_nm=nm_rows is not None,
-                          has_gr=gr_rows is not None),
+                          **fusions),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         interpret=interpret,
     )(jnp.asarray(g, jnp.int32).reshape(1), x, wp,
-      s_neg.astype(jnp.float32), s_pos.astype(jnp.float32),
-      scale_neg, scale_pos, bias, *fargs)
+      _stack3(s_neg.astype(jnp.float32)), _stack3(s_pos.astype(jnp.float32)),
+      scale_neg.reshape(G, nk, 1, Np), scale_pos.reshape(G, nk, 1, Np),
+      bias, *fargs)
     return out[:M, :N]
 
 
 # ---------------------------------------------------------------------------
 # vector-tgroup variants: per-ROW group indices, one packed weight stream
 # ---------------------------------------------------------------------------
-def _fq4_vec_kernel(gv_ref, *refs, nk: int, bk: int, half: int,
-                    has_ps: bool = False, has_nm: bool = False,
-                    has_gr: bool = False):
-    """Vector-tgroup body for ``int4_matmul_fq``: the (G, 1, bn) stacks of
+def _fq4_vec_kernel(gv_ref, *refs, nk: int, bk: int, half: int, **fusions):
+    """Vector-tgroup body for ``int4_matmul_fq``: the (G, bn) stacks of
     THIS K step's scales/corrections stream for every group; each row
-    gathers its own group's values with the exact one-hot product before
+    gathers its own group's values with the exact select before
     the per-step dequantized accumulation."""
     x_ref, w_ref, sx_ref, zx_ref, scale_ref, corr_ref, bias_ref = refs[:7]
     o_ref, acc_ref = refs[-2], refs[-1]
     ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
-        _unpack_fusion_refs(refs[7:-2], has_ps=has_ps, has_nm=has_nm,
-                            has_gr=has_gr)
+        _unpack_fusion_refs(refs[7:-2], **fusions)
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    G = sx_ref.shape[0]
-    oh = _onehot_rows(gv_ref, G)
-    ohf = oh.astype(jnp.float32)
-    sx_row = _gather_rows(ohf, sx_ref, jnp.float32)      # (bm, 1)
-    zx_row = _gather_rows(ohf, zx_ref, jnp.float32)      # (bm, 1)
+    gv = gv_ref[...]
+    sx_row = _gather_rows(gv, sx_ref[...])               # (bm, 1)
+    zx_row = _gather_rows(gv, zx_ref[...])               # (bm, 1)
     xf = _fusion_prologue(x_ref[...].astype(jnp.float32), ps_ref, bv_ref,
                           mu_ref, rsig_ref, sh_ref, sc_ref)
     xq = jnp.clip(
@@ -408,16 +407,9 @@ def _fq4_vec_kernel(gv_ref, *refs, nk: int, bk: int, half: int,
         -half, half - 1).astype(jnp.int8)
     w = _unpack_w(w_ref, bk)
     partial = jax.lax.dot_general(
-        xq.astype(jnp.int32), w,
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-    scale_k = scale_ref[...][:, 0, :]                    # (G, bn)
-    corr_k = corr_ref[...][:, 0, :]                      # (G, bn)
-    scale_row = jax.lax.dot_general(
-        ohf, scale_k.astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    corr_row = jax.lax.dot_general(
-        oh.astype(jnp.int32), corr_k.astype(jnp.int32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        xq, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+    scale_row = _gather_rows(gv, scale_ref[...])         # (bm, bn)
+    corr_row = _gather_rows(gv, corr_ref[...])           # (bm, bn)
     acc_ref[...] += (partial - corr_row).astype(jnp.float32) * scale_row
 
     @pl.when(k == nk - 1)
@@ -428,18 +420,19 @@ def _fq4_vec_kernel(gv_ref, *refs, nk: int, bk: int, half: int,
 
 
 @functools.partial(jax.jit, static_argnames=("group_k", "bm", "bn",
-                                             "out_dtype", "interpret"))
+                                             "rows_per_batch", "out_dtype",
+                                             "interpret"))
 def int4_matmul_fq_vec(x, wp, sx, zx, scale, corr, bias=None, gv=None, *,
-                       ps=None, nm=None, gr=None, bv=None,
+                       ps=None, nm=None, gr=None, rows_per_batch=None,
                        group_k=DEFAULT_BK, bm=DEFAULT_BM, bn=DEFAULT_BN,
                        out_dtype=jnp.float32, interpret=False):
     """``int4_matmul_fq`` with a per-ROW group vector gv (M,) int32.
 
     The nibble-packed weight streams ONCE for the whole mixed-group
-    batch; per K step the (G, 1, bn) scale/corr slices of every group
+    batch; per K step the (G, bn) scale/corr slices of every group
     ride along. A constant gv is bit-identical to the scalar path (same
     elementwise ops, same f32 accumulation order). Optional ``ps``/
-    ``nm``/``gr``/``bv`` fusions as ``int8_matmul_fq``.
+    ``nm``/``gr``/``rows_per_batch`` fusions as ``int8_matmul_fq``.
     """
     M, K = x.shape
     Kp = 2 * wp.shape[0]
@@ -458,22 +451,20 @@ def int4_matmul_fq_vec(x, wp, sx, zx, scale, corr, bias=None, gv=None, *,
     if gv is None:
         gv = jnp.zeros((M,), jnp.int32)
     gv = jnp.pad(jnp.asarray(gv, jnp.int32), (0, Mp - M)).reshape(Mp, 1)
-    ps2, bv2, nm_rows, gr_rows = _prep_fusions(
-        x, ps, nm, gr, bv, M=M, K=K, N=N, Mp=Mp, Kp=Kp, Np=Np)
+    fspecs, fargs, fusions = _fusions(
+        x, ps, nm, gr, rows_per_batch, has_g=False, M=M, K=K, N=N, Mp=Mp,
+        Kp=Kp, Np=Np, bm_=bm_, bk_=group_k, bn_=bn_)
     x = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))
     wp = jnp.pad(wp, ((0, 0), (0, Np - N)))
     scale = jnp.pad(scale.astype(jnp.float32), ((0, 0), (0, 0), (0, Np - N)))
     corr = jnp.pad(corr.astype(jnp.int32), ((0, 0), (0, 0), (0, Np - N)))
     bias = jnp.pad(bias.astype(jnp.float32), (0, Np - N)).reshape(1, Np)
 
-    grid = (Mp // bm_, Np // bn_, nk)
-    fspecs, fargs = _fusion_specs_args(
-        has_g=False, ps=ps2, bv=bv2, nm_rows=nm_rows, gr_rows=gr_rows,
-        bm_=bm_, bk_=group_k, bn_=bn_)
+    nbn = Np // bn_
+    grid = (Mp // bm_, nbn, nk)
     out = pl.pallas_call(
         functools.partial(_fq4_vec_kernel, nk=nk, bk=group_k, half=8,
-                          has_ps=ps2 is not None, has_nm=nm_rows is not None,
-                          has_gr=gr_rows is not None),
+                          **fusions),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, 1), lambda m, n, k: (m, 0)),          # gv rows
@@ -482,10 +473,8 @@ def int4_matmul_fq_vec(x, wp, sx, zx, scale, corr, bias=None, gv=None, *,
                          lambda m, n, k: (k, n)),          # packed W
             pl.BlockSpec((G, 1), lambda m, n, k: (0, 0)),            # sx stack
             pl.BlockSpec((G, 1), lambda m, n, k: (0, 0)),            # zx stack
-            pl.BlockSpec((G, 1, bn_),
-                         lambda m, n, k: (0, k, n)),       # scale[:, k]
-            pl.BlockSpec((G, 1, bn_),
-                         lambda m, n, k: (0, k, n)),       # corr[:, k]
+            _kstep_stack(G, bn_, nbn),                     # scale[:, k]
+            _kstep_stack(G, bn_, nbn),                     # corr[:, k]
             pl.BlockSpec((1, bn_), lambda m, n, k: (0, n)),          # bias
         ] + fspecs,
         out_specs=pl.BlockSpec((bm_, bn_), lambda m, n, k: (m, n)),
@@ -493,31 +482,27 @@ def int4_matmul_fq_vec(x, wp, sx, zx, scale, corr, bias=None, gv=None, *,
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
         interpret=interpret,
     )(gv, x, wp, sx.astype(jnp.float32), zx.astype(jnp.float32),
-      scale, corr, bias, *fargs)
+      scale.reshape(G, nk * Np), corr.reshape(G, nk * Np), bias, *fargs)
     return out[:M, :N]
 
 
-def _mrq4_vec_kernel(gv_ref, *refs, nk: int, bk: int, half: int,
-                     has_ps: bool = False, has_nm: bool = False,
-                     has_gr: bool = False):
+def _mrq4_vec_kernel(gv_ref, *refs, nk: int, bk: int, half: int, **fusions):
     """Vector-tgroup body for ``int4_matmul_mrq_fq``: per-row twin-region
     steps, ONE unpacked weight tile, per-row per-K-group region scales."""
     x_ref, w_ref, sn_ref, sp_ref, scale_n_ref, scale_p_ref, bias_ref = \
         refs[:7]
     o_ref, acc_ref = refs[-2], refs[-1]
     ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
-        _unpack_fusion_refs(refs[7:-2], has_ps=has_ps, has_nm=has_nm,
-                            has_gr=has_gr)
+        _unpack_fusion_refs(refs[7:-2], **fusions)
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    G = sn_ref.shape[0]
-    ohf = _onehot_rows(gv_ref, G).astype(jnp.float32)
-    sn_row = _gather_rows(ohf, sn_ref, jnp.float32)      # (bm, 1)
-    sp_row = _gather_rows(ohf, sp_ref, jnp.float32)      # (bm, 1)
+    gv = gv_ref[...]
+    sn_row = _gather_rows(gv, sn_ref[...])               # (bm, 1)
+    sp_row = _gather_rows(gv, sp_ref[...])               # (bm, 1)
     xf = _fusion_prologue(x_ref[...].astype(jnp.float32), ps_ref, bv_ref,
                           mu_ref, rsig_ref, sh_ref, sc_ref)
     neg = xf < 0
@@ -527,16 +512,10 @@ def _mrq4_vec_kernel(gv_ref, *refs, nk: int, bk: int, half: int,
                    ).astype(jnp.int8)
     w = _unpack_w(w_ref, bk)                  # ONE weight-tile read, two dots
     dims = (((1,), (0,)), ((), ()))
-    pn = jax.lax.dot_general(qn.astype(jnp.int32), w, dims,
-                             preferred_element_type=jnp.int32)
-    pp = jax.lax.dot_general(qp.astype(jnp.int32), w, dims,
-                             preferred_element_type=jnp.int32)
-    scale_n_row = jax.lax.dot_general(
-        ohf, scale_n_ref[...][:, 0, :].astype(jnp.float32), dims,
-        preferred_element_type=jnp.float32)
-    scale_p_row = jax.lax.dot_general(
-        ohf, scale_p_ref[...][:, 0, :].astype(jnp.float32), dims,
-        preferred_element_type=jnp.float32)
+    pn = jax.lax.dot_general(qn, w, dims, preferred_element_type=jnp.int32)
+    pp = jax.lax.dot_general(qp, w, dims, preferred_element_type=jnp.int32)
+    scale_n_row = _gather_rows(gv, scale_n_ref[...])     # (bm, bn)
+    scale_p_row = _gather_rows(gv, scale_p_ref[...])     # (bm, bn)
     acc_ref[...] += (pn.astype(jnp.float32) * scale_n_row
                      + pp.astype(jnp.float32) * scale_p_row)
 
@@ -548,10 +527,11 @@ def _mrq4_vec_kernel(gv_ref, *refs, nk: int, bk: int, half: int,
 
 
 @functools.partial(jax.jit, static_argnames=("group_k", "bm", "bn",
-                                             "out_dtype", "interpret"))
+                                             "rows_per_batch", "out_dtype",
+                                             "interpret"))
 def int4_matmul_mrq_fq_vec(x, wp, s_neg, s_pos, scale_neg, scale_pos,
                            bias=None, gv=None, *, ps=None, nm=None, gr=None,
-                           bv=None, group_k=DEFAULT_BK,
+                           rows_per_batch=None, group_k=DEFAULT_BK,
                            bm=DEFAULT_BM, bn=DEFAULT_BN,
                            out_dtype=jnp.float32, interpret=False):
     """``int4_matmul_mrq_fq`` with a per-ROW group vector gv (M,) int32
@@ -572,8 +552,9 @@ def int4_matmul_mrq_fq_vec(x, wp, s_neg, s_pos, scale_neg, scale_pos,
     if gv is None:
         gv = jnp.zeros((M,), jnp.int32)
     gv = jnp.pad(jnp.asarray(gv, jnp.int32), (0, Mp - M)).reshape(Mp, 1)
-    ps2, bv2, nm_rows, gr_rows = _prep_fusions(
-        x, ps, nm, gr, bv, M=M, K=K, N=N, Mp=Mp, Kp=Kp, Np=Np)
+    fspecs, fargs, fusions = _fusions(
+        x, ps, nm, gr, rows_per_batch, has_g=False, M=M, K=K, N=N, Mp=Mp,
+        Kp=Kp, Np=Np, bm_=bm_, bk_=group_k, bn_=bn_)
     x = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))
     wp = jnp.pad(wp, ((0, 0), (0, Np - N)))
     scale_neg = jnp.pad(scale_neg.astype(jnp.float32),
@@ -582,14 +563,11 @@ def int4_matmul_mrq_fq_vec(x, wp, s_neg, s_pos, scale_neg, scale_pos,
                         ((0, 0), (0, 0), (0, Np - N)))
     bias = jnp.pad(bias.astype(jnp.float32), (0, Np - N)).reshape(1, Np)
 
-    grid = (Mp // bm_, Np // bn_, nk)
-    fspecs, fargs = _fusion_specs_args(
-        has_g=False, ps=ps2, bv=bv2, nm_rows=nm_rows, gr_rows=gr_rows,
-        bm_=bm_, bk_=group_k, bn_=bn_)
+    nbn = Np // bn_
+    grid = (Mp // bm_, nbn, nk)
     out = pl.pallas_call(
         functools.partial(_mrq4_vec_kernel, nk=nk, bk=group_k, half=8,
-                          has_ps=ps2 is not None, has_nm=nm_rows is not None,
-                          has_gr=gr_rows is not None),
+                          **fusions),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, 1), lambda m, n, k: (m, 0)),          # gv rows
@@ -598,10 +576,8 @@ def int4_matmul_mrq_fq_vec(x, wp, s_neg, s_pos, scale_neg, scale_pos,
                          lambda m, n, k: (k, n)),          # packed W
             pl.BlockSpec((G, 1), lambda m, n, k: (0, 0)),         # s_neg stack
             pl.BlockSpec((G, 1), lambda m, n, k: (0, 0)),         # s_pos stack
-            pl.BlockSpec((G, 1, bn_),
-                         lambda m, n, k: (0, k, n)),       # scale_neg[:, k]
-            pl.BlockSpec((G, 1, bn_),
-                         lambda m, n, k: (0, k, n)),       # scale_pos[:, k]
+            _kstep_stack(G, bn_, nbn),                     # scale_neg[:, k]
+            _kstep_stack(G, bn_, nbn),                     # scale_pos[:, k]
             pl.BlockSpec((1, bn_), lambda m, n, k: (0, n)),          # bias
         ] + fspecs,
         out_specs=pl.BlockSpec((bm_, bn_), lambda m, n, k: (m, n)),
@@ -609,5 +585,6 @@ def int4_matmul_mrq_fq_vec(x, wp, s_neg, s_pos, scale_neg, scale_pos,
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
         interpret=interpret,
     )(gv, x, wp, s_neg.astype(jnp.float32), s_pos.astype(jnp.float32),
-      scale_neg, scale_pos, bias, *fargs)
+      scale_neg.reshape(G, nk * Np), scale_pos.reshape(G, nk * Np), bias,
+      *fargs)
     return out[:M, :N]

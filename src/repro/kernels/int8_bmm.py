@@ -55,13 +55,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.int8_matmul import DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, \
-    _ceil, _pad_to
+    _ceil, _group_param, _pad_to, _stack3
+
+
+def _sym_levels(x, scale, half):
+    """fp tile -> symmetric integer levels as f32 (code range, no -half)."""
+    return jnp.clip(jnp.round(x.astype(jnp.float32) / scale),
+                    -(half - 1), half - 1)
 
 
 def _sym_codes(x, scale, half):
     """fp tile -> symmetric s8 codes in VMEM (weight code range, no -128)."""
-    return jnp.clip(jnp.round(x.astype(jnp.float32) / scale),
-                    -(half - 1), half - 1).astype(jnp.int8)
+    return _sym_levels(x, scale, half).astype(jnp.int8)
 
 
 def _qk_kernel(g_ref, q_ref, k_ref, sq_ref, sk_ref, scale_ref, o_ref,
@@ -84,8 +89,7 @@ def _qk_kernel(g_ref, q_ref, k_ref, sq_ref, sk_ref, scale_ref, o_ref,
     q8 = _sym_codes(q_ref[0], sq_ref[0, 0], half)
     k8 = _sym_codes(k_ref[0], sk_ref[0, 0], half)
     acc_ref[...] += jax.lax.dot_general(
-        q8.astype(jnp.int32), k8.astype(jnp.int32),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32)
+        q8, k8, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32)
 
     @pl.when(d == nk - 1)
     def _epilogue():
@@ -135,9 +139,9 @@ def int8_bmm_qk(q, k, s_q, s_k, scale, g=None, *, bits=8, bm=DEFAULT_BM,
             pl.BlockSpec((1, bm_, bk_), lambda b, m, n, d, g: (b, m, d)),
             pl.BlockSpec((1, bn_, bk_),
                          lambda b, m, n, d, g: (b // rep, n, d)),  # shared kv
-            pl.BlockSpec((1, 1), lambda b, m, n, d, g: (g[0], 0)),   # s_q[g]
-            pl.BlockSpec((1, 1), lambda b, m, n, d, g: (g[0], 0)),   # s_k[g]
-            pl.BlockSpec((1, 1), lambda b, m, n, d, g: (g[0], 0)),   # scale[g]
+            _group_param((1,), lambda b, m, n, d, g: (g[0], 0, 0)),   # s_q[g]
+            _group_param((1,), lambda b, m, n, d, g: (g[0], 0, 0)),   # s_k[g]
+            _group_param((1,), lambda b, m, n, d, g: (g[0], 0, 0)),   # scale
         ],
         out_specs=pl.BlockSpec((1, bm_, bn_), lambda b, m, n, d, g: (b, m, n)),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
@@ -148,8 +152,8 @@ def int8_bmm_qk(q, k, s_q, s_k, scale, g=None, *, bits=8, bm=DEFAULT_BM,
         out_shape=jax.ShapeDtypeStruct((B, Mp, Np), out_dtype),
         interpret=interpret,
     )(jnp.asarray(g, jnp.int32).reshape(1), q, k,
-      s_q.astype(jnp.float32), s_k.astype(jnp.float32),
-      scale.astype(jnp.float32))
+      _stack3(s_q.astype(jnp.float32)), _stack3(s_k.astype(jnp.float32)),
+      _stack3(scale.astype(jnp.float32)))
     return out[:, :M, :N]
 
 
@@ -158,11 +162,15 @@ def _pv_kernel(g_ref, c_ref, v_ref, sv_ref, scale1_ref, scale2_ref, o_ref,
     """Grid body for ``int8_bmm_pv`` at grid point (b, m, d, n).
 
     The prob-code tile (1, bm, bn) is split by SIGN into the two region
-    magnitude tiles (region 1: c, region 2: -c — disjoint support by
-    construction of the encoding) feeding dual s32 accumulators against
-    a single read of the v tile, which is quantized in the prologue with
-    the group-``g`` symmetric step. Epilogue recombines with the
-    per-region combined scales. n (the Skv contraction) is innermost.
+    tiles (region 1: max(c, 0), region 2: min(c, 0) — disjoint support
+    by construction of the encoding) feeding dual s32 accumulators
+    against a single read of the v tile, which is quantized in the
+    prologue with the group-``g`` symmetric step. Both stay s8 MXU
+    operands: region 2 keeps its negated codes (magnitude up to 2^{k-1},
+    one past the s8 maximum) and meets the negated v codes, which the
+    symmetric code range keeps inside s8.
+    Epilogue recombines with the per-region combined scales. n (the Skv
+    contraction) is innermost.
     """
     del g_ref
     n = pl.program_id(3)
@@ -172,14 +180,14 @@ def _pv_kernel(g_ref, c_ref, v_ref, sv_ref, scale1_ref, scale2_ref, o_ref,
         acc1_ref[...] = jnp.zeros_like(acc1_ref)
         acc2_ref[...] = jnp.zeros_like(acc2_ref)
 
-    c = c_ref[0].astype(jnp.int32)
-    c1 = jnp.maximum(c, 0)                    # region-1 codes [0, half-1]
-    c2 = jnp.maximum(-c, 0)                   # region-2 codes [0, half]
-    v8 = _sym_codes(v_ref[0], sv_ref[0, 0], half).astype(jnp.int32)
+    c = c_ref[0].astype(jnp.int32)            # the VPU has no s8 min/max
+    c1 = jnp.maximum(c, 0).astype(jnp.int8)   # region-1 codes [0, half-1]
+    c2n = jnp.minimum(c, 0).astype(jnp.int8)  # -(region-2 codes) [-half, 0]
+    vq = _sym_levels(v_ref[0], sv_ref[0, 0], half)
     dims = (((1,), (0,)), ((), ()))           # ONE v-tile read, two dots
-    acc1_ref[...] += jax.lax.dot_general(c1, v8, dims,
+    acc1_ref[...] += jax.lax.dot_general(c1, vq.astype(jnp.int8), dims,
                                          preferred_element_type=jnp.int32)
-    acc2_ref[...] += jax.lax.dot_general(c2, v8, dims,
+    acc2_ref[...] += jax.lax.dot_general(c2n, (-vq).astype(jnp.int8), dims,
                                          preferred_element_type=jnp.int32)
 
     @pl.when(n == nk - 1)
@@ -229,9 +237,9 @@ def int8_bmm_pv(codes, v, s_v, scale1, scale2, g=None, *, bits=8,
             pl.BlockSpec((1, bm_, bn_), lambda b, m, d, n, g: (b, m, n)),
             pl.BlockSpec((1, bn_, bd_),
                          lambda b, m, d, n, g: (b // rep, n, d)),  # shared kv
-            pl.BlockSpec((1, 1), lambda b, m, d, n, g: (g[0], 0)),  # s_v[g]
-            pl.BlockSpec((1, 1), lambda b, m, d, n, g: (g[0], 0)),  # scale1
-            pl.BlockSpec((1, 1), lambda b, m, d, n, g: (g[0], 0)),  # scale2
+            _group_param((1,), lambda b, m, d, n, g: (g[0], 0, 0)),  # s_v[g]
+            _group_param((1,), lambda b, m, d, n, g: (g[0], 0, 0)),  # scale1
+            _group_param((1,), lambda b, m, d, n, g: (g[0], 0, 0)),  # scale2
         ],
         out_specs=pl.BlockSpec((1, bm_, bd_), lambda b, m, d, n, g: (b, m, d)),
         scratch_shapes=[pltpu.VMEM((bm_, bd_), jnp.int32),
@@ -243,8 +251,8 @@ def int8_bmm_pv(codes, v, s_v, scale1, scale2, g=None, *, bits=8,
         out_shape=jax.ShapeDtypeStruct((B, Mp, Dp), out_dtype),
         interpret=interpret,
     )(jnp.asarray(g, jnp.int32).reshape(1), codes, v,
-      s_v.astype(jnp.float32), scale1.astype(jnp.float32),
-      scale2.astype(jnp.float32))
+      _stack3(s_v.astype(jnp.float32)), _stack3(scale1.astype(jnp.float32)),
+      _stack3(scale2.astype(jnp.float32)))
     return out[:, :M, :D]
 
 
@@ -289,9 +297,9 @@ def int8_bmm_qk_vec(q, k, s_q, s_k, scale, gv=None, *, bits=8, bm=DEFAULT_BM,
             pl.BlockSpec((1, bm_, bk_), lambda b, m, n, d, g: (b, m, d)),
             pl.BlockSpec((1, bn_, bk_),
                          lambda b, m, n, d, g: (b // rep, n, d)),  # shared kv
-            pl.BlockSpec((1, 1), lambda b, m, n, d, g: (g[b], 0)),  # s_q[g_b]
-            pl.BlockSpec((1, 1), lambda b, m, n, d, g: (g[b], 0)),  # s_k[g_b]
-            pl.BlockSpec((1, 1), lambda b, m, n, d, g: (g[b], 0)),  # scale
+            _group_param((1,), lambda b, m, n, d, g: (g[b], 0, 0)),  # s_q[g_b]
+            _group_param((1,), lambda b, m, n, d, g: (g[b], 0, 0)),  # s_k[g_b]
+            _group_param((1,), lambda b, m, n, d, g: (g[b], 0, 0)),  # scale
         ],
         out_specs=pl.BlockSpec((1, bm_, bn_), lambda b, m, n, d, g: (b, m, n)),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
@@ -301,8 +309,8 @@ def int8_bmm_qk_vec(q, k, s_q, s_k, scale, gv=None, *, bits=8, bm=DEFAULT_BM,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Mp, Np), out_dtype),
         interpret=interpret,
-    )(gv, q, k, s_q.astype(jnp.float32), s_k.astype(jnp.float32),
-      scale.astype(jnp.float32))
+    )(gv, q, k, _stack3(s_q.astype(jnp.float32)),
+      _stack3(s_k.astype(jnp.float32)), _stack3(scale.astype(jnp.float32)))
     return out[:, :M, :N]
 
 
@@ -338,9 +346,9 @@ def int8_bmm_pv_vec(codes, v, s_v, scale1, scale2, gv=None, *, bits=8,
             pl.BlockSpec((1, bm_, bn_), lambda b, m, d, n, g: (b, m, n)),
             pl.BlockSpec((1, bn_, bd_),
                          lambda b, m, d, n, g: (b // rep, n, d)),  # shared kv
-            pl.BlockSpec((1, 1), lambda b, m, d, n, g: (g[b], 0)),  # s_v[g_b]
-            pl.BlockSpec((1, 1), lambda b, m, d, n, g: (g[b], 0)),  # scale1
-            pl.BlockSpec((1, 1), lambda b, m, d, n, g: (g[b], 0)),  # scale2
+            _group_param((1,), lambda b, m, d, n, g: (g[b], 0, 0)),  # s_v[g_b]
+            _group_param((1,), lambda b, m, d, n, g: (g[b], 0, 0)),  # scale1
+            _group_param((1,), lambda b, m, d, n, g: (g[b], 0, 0)),  # scale2
         ],
         out_specs=pl.BlockSpec((1, bm_, bd_), lambda b, m, d, n, g: (b, m, d)),
         scratch_shapes=[pltpu.VMEM((bm_, bd_), jnp.int32),
@@ -351,6 +359,6 @@ def int8_bmm_pv_vec(codes, v, s_v, scale1, scale2, gv=None, *, bits=8,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Mp, Dp), out_dtype),
         interpret=interpret,
-    )(gv, codes, v, s_v.astype(jnp.float32), scale1.astype(jnp.float32),
-      scale2.astype(jnp.float32))
+    )(gv, codes, v, _stack3(s_v.astype(jnp.float32)),
+      _stack3(scale1.astype(jnp.float32)), _stack3(scale2.astype(jnp.float32)))
     return out[:, :M, :D]

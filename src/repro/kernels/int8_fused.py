@@ -24,7 +24,9 @@ TGQ (time-grouped quantization, the paper's §III-A) lives *inside* the
 kernels: every activation-side parameter is stacked along a leading
 (G,) group axis and the timestep group ``g`` — a traced scalar inside
 the ``ddpm_sample`` lax.scan — is scalar-prefetched; the per-group row
-is gathered by the BlockSpec index maps (``(g[0], n)``). The whole
+is gathered by the BlockSpec index maps (``(g[0], 0, n)`` over the
+stacks viewed as (G, 1, ·), so a one-row block fits the TPU tiling at
+any G). The whole
 sampling loop therefore stays ONE compiled executable with the int8
 kernels inside; no per-group repacking or retracing.
 
@@ -32,10 +34,9 @@ kernels inside; no per-group repacking or retracing.
 **vector-tgroup** variants: instead of one scalar-prefetched group, a
 per-ROW ``(M,)`` int32 group vector rides as a (M, 1) VMEM operand and
 the FULL (G, ·) param stacks stream in; each row gathers its own group's
-params inside the kernel via an exact one-hot product (f32 one-hot
-matmul is bit-exact — exactly one 1.0·value term, the rest exact zeros —
-and the s32 ``corr`` gather uses an integer dot so values beyond f32's
-24-bit exact-integer range survive). A batch mixing slots at different
+params inside the kernel with an exact select over the G groups
+(``_gather_rows``; bit-exact for the f32 scales and the s32 ``corr``
+alike, and no MXU pass). A batch mixing slots at different
 timesteps therefore runs as ONE call that streams the weights exactly
 once; a constant group vector is bit-identical to the scalar-prefetch
 sibling (asserted in tests/test_kernel_conformance.py).
@@ -50,9 +51,11 @@ elementwise chains that used to round-trip through HBM around it.
     with the exact ``nn.layers.layernorm_apply`` ops) and the per-batch
     adaLN (shift, scale) rows; it replays ``(x - mu) * rsig`` then
     ``x * (1 + scale) + shift`` in VMEM right before the quantize, so
-    the normalized/modulated tensor never exists in HBM. Per-batch rows
-    are gathered per x row via the exact one-hot product against a
-    (M, 1) row->batch index operand.
+    the normalized/modulated tensor never exists in HBM. x rows are
+    batch-major, ``rows_per_batch`` to an entry: when that is a multiple
+    of the row tile, each x tile lies in one entry and the index maps
+    fetch its (1, ·) rows; otherwise each row selects its entry's row in
+    VMEM (``_batch_rows``).
 
 ``gr`` (gate+residual epilogue)
     The dequantized output tile is scaled by the per-batch adaLN gate
@@ -86,36 +89,33 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.int8_matmul import (
-    DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, _ceil, _pad_to,
+    DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, _ceil, _group_param, _pad_to, _stack3,
 )
 
 
 # ---------------------------------------------------------------------------
 # in-VMEM row gathers (shared by the vector-tgroup and fusion paths)
 # ---------------------------------------------------------------------------
-def _onehot_rows(gv_ref, n_groups: int):
-    """(bm, 1) int32 group-index tile -> (bm, G) bool one-hot."""
-    gv = gv_ref[...]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (gv.shape[0], n_groups), 1)
-    return gv == iota
+def _gather_rows(idx, table):
+    """Per-row gather of a (G, ·) param stack: row i takes ``table[idx[i]]``.
 
-
-def _gather_rows(oh, param_ref, dtype):
-    """Per-row gather of a (G, ·) param stack via a one-hot product.
-
-    Exactly one term per output element is 1·value and the rest are exact
-    zeros, so the f32 product is bit-exact; the int32 path uses an integer
-    dot because s32 corr values can exceed f32's exact-integer range.
+    ``idx`` is a (rows, 1) int32 tile, ``table`` a loaded (G, n) value. A
+    static select over the G rows is exact for every dtype, whatever the
+    MXU's contract precision, since it needs no MXU pass (which takes no
+    s32 operands at all). Indices outside [0, G) — padded rows only —
+    take row 0.
     """
-    return jax.lax.dot_general(
-        oh.astype(dtype), param_ref[...].astype(dtype),
-        (((1,), (0,)), ((), ())), preferred_element_type=dtype)
+    out = jnp.broadcast_to(table[0:1], (idx.shape[0], table.shape[1]))
+    for g in range(1, table.shape[0]):
+        out = jnp.where(idx == g, table[g:g + 1], out)
+    return out
 
 
 # ---------------------------------------------------------------------------
 # prologue/epilogue fusion plumbing (shared with int4_packed)
 # ---------------------------------------------------------------------------
-def _unpack_fusion_refs(refs, *, has_ps: bool, has_nm: bool, has_gr: bool):
+def _unpack_fusion_refs(refs, *, has_ps: bool = False, has_bv: bool = False,
+                        has_nm: bool = False, has_gr: bool = False):
     """Split the conditional fusion operand refs appended after ``bias``.
 
     Order (present-only): ps, bv, mu, rsig, shift, scale, gate, resid.
@@ -123,7 +123,7 @@ def _unpack_fusion_refs(refs, *, has_ps: bool, has_nm: bool, has_gr: bool):
     """
     it = iter(refs)
     ps = next(it) if has_ps else None
-    bv = next(it) if (has_nm or has_gr) else None
+    bv = next(it) if has_bv else None
     mu = rsig = sh = sc = None
     if has_nm:
         mu, rsig, sh, sc = next(it), next(it), next(it), next(it)
@@ -133,17 +133,24 @@ def _unpack_fusion_refs(refs, *, has_ps: bool, has_nm: bool, has_gr: bool):
     return ps, bv, mu, rsig, sh, sc, gate, res
 
 
+def _batch_rows(bv_ref, ref):
+    """The per-batch adaLN values for the x tile's rows: the one batch
+    entry's (1, n) row the index map fetched, or — with a row->batch
+    operand ``bv_ref`` — each row's entry gathered by ``_gather_rows``."""
+    if bv_ref is None:
+        return ref[...]
+    return _gather_rows(bv_ref[...], ref[...])
+
+
 def _fusion_prologue(xf, ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref):
     """Replay, in VMEM and in the fake-quant path's exact op order, the
     elementwise chain ahead of the quantize: layernorm (per-row stats
-    pre-computed by the wrapper) -> adaLN modulate (per-batch rows
-    gathered by the exact one-hot product) -> channel-balance divide."""
+    pre-computed by the wrapper) -> adaLN modulate (per-batch rows,
+    ``_batch_rows``) -> channel-balance divide."""
     if mu_ref is not None:
         xf = (xf - mu_ref[...]) * rsig_ref[...]
-        ohb = _onehot_rows(bv_ref, sh_ref.shape[0])
-        sh_rows = _gather_rows(ohb, sh_ref, jnp.float32)
-        sc_rows = _gather_rows(ohb, sc_ref, jnp.float32)
-        xf = xf * (1.0 + sc_rows) + sh_rows
+        xf = xf * (1.0 + _batch_rows(bv_ref, sc_ref)) + _batch_rows(
+            bv_ref, sh_ref)
     if ps_ref is not None:
         xf = xf / ps_ref[...]
     return xf
@@ -151,16 +158,17 @@ def _fusion_prologue(xf, ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref):
 
 def _fusion_epilogue(y, bv_ref, gate_ref, res_ref):
     """gate+residual epilogue: y -> resid + gate_rows * y before the
-    single HBM write (per-batch gate rows gathered by one-hot)."""
+    single HBM write (per-batch gate rows, ``_batch_rows``)."""
     if gate_ref is not None:
-        ohb = _onehot_rows(bv_ref, gate_ref.shape[0])
-        gate_rows = _gather_rows(ohb, gate_ref, jnp.float32)
-        y = res_ref[...] + gate_rows * y
+        y = res_ref[...] + _batch_rows(bv_ref, gate_ref) * y
     return y
 
 
-def _prep_fusions(x, ps, nm, gr, bv, *, M, K, N, Mp, Kp, Np):
-    """Pad/shape the optional fusion operands for the kernel call.
+def _fusions(x, ps, nm, gr, rows_per_batch, *, has_g: bool, M, K, N, Mp,
+             Kp, Np, bm_, bk_, bn_):
+    """(in_specs, operands, flags) for the optional fusion inputs, in the
+    ``_unpack_fusion_refs`` order; ``flags`` are the kernel's ``has_*``
+    switches.
 
     ps : (K,) f32 channel-balance divisors (padded with 1 — inert).
     nm : (shift, scale) per-batch (B, K) adaLN modulate rows; the
@@ -169,78 +177,80 @@ def _prep_fusions(x, ps, nm, gr, bv, *, M, K, N, Mp, Kp, Np):
          eps=1e-6), so the fused path is bit-identical to the unfused
          norm -> modulate chain.
     gr : (gate, resid) — (B, N) gate rows + (M, N) residual.
-    bv : (M,) int32 row -> batch index (required by nm/gr).
-
-    Returns (ps2, bv2, nm_rows, gr_rows) ready to append as operands.
+    rows_per_batch : x rows per batch entry (rows are batch-major),
+         required by nm/gr. When it is a multiple of ``bm_`` every x tile
+         lies in one batch entry, and the index maps fetch that entry's
+         (1, ·) shift/scale/gate rows from the stacks viewed as (B, 1, ·).
+         Otherwise a (M, 1) row->batch operand rides along and the kernel
+         selects each row's entry from the whole (B, ·) stacks.
+    ``has_g`` selects index-map arity (scalar-prefetch grids take a
+    trailing g argument).
     """
     f32 = jnp.float32
-    ps2 = None
+
+    def im(f):
+        return (lambda m, n, k, g: f(m, n, k)) if has_g else f
+    per_batch = nm is not None or gr is not None
+    if per_batch:
+        assert rows_per_batch and M % rows_per_batch == 0, \
+            ("norm_mod/gate_residual need rows_per_batch dividing M",
+             rows_per_batch, M)
+    tiled = per_batch and rows_per_batch % bm_ == 0
+    specs, args = [], []
+
+    def batch_stack(a, width, pad, col):
+        a = jnp.pad(a.astype(f32), ((0, 0), (0, pad)))
+        if tiled:              # x tile m lies in batch entry m // per_entry
+            per_entry = rows_per_batch // bm_
+            return _group_param((width,), im(
+                lambda m, n, k: (m // per_entry, 0, col(n, k)))), _stack3(a)
+        return pl.BlockSpec((a.shape[0], width),
+                            im(lambda m, n, k: (0, col(n, k)))), a
+
+    def rows_col(a):
+        return (pl.BlockSpec((bm_, 1), im(lambda m, n, k: (m, 0))),
+                jnp.pad(a, ((0, Mp - M), (0, 0))))
+
     if ps is not None:
-        ps2 = jnp.pad(jnp.asarray(ps, f32).reshape(1, K),
-                      ((0, 0), (0, Kp - K)), constant_values=1.0)
-    bv2 = None
-    if nm is not None or gr is not None:
-        assert bv is not None, "norm_mod/gate_residual need a row->batch map"
-        bv2 = jnp.pad(jnp.asarray(bv, jnp.int32), (0, Mp - M)).reshape(Mp, 1)
-    nm_rows = None
+        specs.append(pl.BlockSpec((1, bk_), im(lambda m, n, k: (0, k))))
+        args.append(jnp.pad(jnp.asarray(ps, f32).reshape(1, K),
+                            ((0, 0), (0, Kp - K)), constant_values=1.0))
+    if per_batch and not tiled:
+        bv = jnp.repeat(jnp.arange(M // rows_per_batch, dtype=jnp.int32),
+                        rows_per_batch)
+        spec, arg = rows_col(bv.reshape(M, 1))
+        specs.append(spec)
+        args.append(arg)
     if nm is not None:
         sh, sc = nm
         xf = x.astype(f32)
         mu = jnp.mean(xf, axis=-1, keepdims=True)
-        var = jnp.var(xf, axis=-1, keepdims=True)
-        rsig = jax.lax.rsqrt(var + 1e-6)
-        nm_rows = (jnp.pad(mu, ((0, Mp - M), (0, 0))),
-                   jnp.pad(rsig, ((0, Mp - M), (0, 0))),
-                   jnp.pad(sh.astype(f32), ((0, 0), (0, Kp - K))),
-                   jnp.pad(sc.astype(f32), ((0, 0), (0, Kp - K))))
-    gr_rows = None
+        rsig = jax.lax.rsqrt(jnp.var(xf, axis=-1, keepdims=True) + 1e-6)
+        for spec, arg in (rows_col(mu), rows_col(rsig),
+                          batch_stack(sh, bk_, Kp - K, lambda n, k: k),
+                          batch_stack(sc, bk_, Kp - K, lambda n, k: k)):
+            specs.append(spec)
+            args.append(arg)
     if gr is not None:
         gate, res = gr
-        gr_rows = (jnp.pad(gate.astype(f32), ((0, 0), (0, Np - N))),
-                   jnp.pad(res.astype(f32), ((0, Mp - M), (0, Np - N))))
-    return ps2, bv2, nm_rows, gr_rows
+        spec, arg = batch_stack(gate, bn_, Np - N, lambda n, k: n)
+        specs += [spec, pl.BlockSpec((bm_, bn_), im(lambda m, n, k: (m, n)))]
+        args += [arg, jnp.pad(res.astype(f32), ((0, Mp - M), (0, Np - N)))]
+    flags = dict(has_ps=ps is not None, has_bv=per_batch and not tiled,
+                 has_nm=nm is not None, has_gr=gr is not None)
+    return specs, args, flags
 
 
-def _fusion_specs_args(*, has_g: bool, ps, bv, nm_rows, gr_rows,
-                       bm_, bk_, bn_):
-    """(in_specs, operands) for the present fusion inputs, in the
-    ``_unpack_fusion_refs`` order. ``has_g`` selects index-map arity
-    (scalar-prefetch grids take a trailing g argument)."""
-    def im(f):
-        return (lambda m, n, k, g: f(m, n, k)) if has_g else f
-    specs, args = [], []
-    if ps is not None:
-        specs.append(pl.BlockSpec((1, bk_), im(lambda m, n, k: (0, k))))
-        args.append(ps)
-    if bv is not None:
-        specs.append(pl.BlockSpec((bm_, 1), im(lambda m, n, k: (m, 0))))
-        args.append(bv)
-    if nm_rows is not None:
-        mu, rsig, sh, sc = nm_rows
-        B = sh.shape[0]
-        specs += [pl.BlockSpec((bm_, 1), im(lambda m, n, k: (m, 0))),
-                  pl.BlockSpec((bm_, 1), im(lambda m, n, k: (m, 0))),
-                  pl.BlockSpec((B, bk_), im(lambda m, n, k: (0, k))),
-                  pl.BlockSpec((B, bk_), im(lambda m, n, k: (0, k)))]
-        args += [mu, rsig, sh, sc]
-    if gr_rows is not None:
-        gate, res = gr_rows
-        B = gate.shape[0]
-        specs += [pl.BlockSpec((B, bn_), im(lambda m, n, k: (0, n))),
-                  pl.BlockSpec((bm_, bn_), im(lambda m, n, k: (m, n)))]
-        args += [gate, res]
-    return specs, args
-
-
-def _fq_kernel(g_ref, *refs, nk: int, half: int, has_ps: bool = False,
-               has_nm: bool = False, has_gr: bool = False):
+def _fq_kernel(g_ref, *refs, nk: int, half: int, **fusions):
     """Grid body for ``int8_matmul_fq`` at grid point (m, n, k).
 
     Refs arrive as VMEM tiles already gathered by the BlockSpec index
     maps: x (bm, bk) fp32, w (bk, bn) int8, and the TGQ-resolved rows of
     the activation-side params — sx/zx (1, 1) and scale/corr (1, bn) are
     the group-``g`` slices of the stacked (G, ·) arrays (see the
-    ``(g[0], n)`` index maps below), so the body itself is group-agnostic.
+    ``(g[0], 0, n)`` index maps below), so the body itself is
+    group-agnostic. Both dot operands are s8 (the MXU multiplies s8 x s8
+    into s32; it takes no s32 operands).
     ``acc_ref`` is a persistent (bm, bn) s32 scratch: zeroed at k == 0,
     accumulated over the K-traversal (k innermost), epilogued at
     k == nk - 1. ``g_ref`` itself is unused here — prefetched scalars
@@ -252,8 +262,7 @@ def _fq_kernel(g_ref, *refs, nk: int, half: int, has_ps: bool = False,
     x_ref, w_ref, sx_ref, zx_ref, scale_ref, corr_ref, bias_ref = refs[:7]
     o_ref, acc_ref = refs[-2], refs[-1]
     ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
-        _unpack_fusion_refs(refs[7:-2], has_ps=has_ps, has_nm=has_nm,
-                            has_gr=has_gr)
+        _unpack_fusion_refs(refs[7:-2], **fusions)
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -270,8 +279,8 @@ def _fq_kernel(g_ref, *refs, nk: int, half: int, has_ps: bool = False,
     xq = jnp.clip(jnp.round(xf / sx) + zx - half,
                   -half, half - 1).astype(jnp.int8)
     acc_ref[...] += jax.lax.dot_general(
-        xq.astype(jnp.int32), w_ref[...].astype(jnp.int32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        xq, w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -282,9 +291,10 @@ def _fq_kernel(g_ref, *refs, nk: int, half: int, has_ps: bool = False,
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "bm", "bn", "bk",
-                                             "out_dtype", "interpret"))
+                                             "rows_per_batch", "out_dtype",
+                                             "interpret"))
 def int8_matmul_fq(x, wq, sx, zx, scale, corr, bias=None, g=None, *,
-                   ps=None, nm=None, gr=None, bv=None, bits=8,
+                   ps=None, nm=None, gr=None, rows_per_batch=None, bits=8,
                    bm=DEFAULT_BM, bn=DEFAULT_BN, bk=DEFAULT_BK,
                    out_dtype=jnp.float32, interpret=False):
     """y[M,N] = (q(x; sx[g], zx[g]) @ wq - corr[g]) * scale[g] (+ bias).
@@ -301,7 +311,8 @@ def int8_matmul_fq(x, wq, sx, zx, scale, corr, bias=None, g=None, *,
     Optional fusions (see module docstring): ``ps`` (K,) channel-balance
     divisors, ``nm=(shift, scale)`` (B,K) adaLN modulate rows (x must be
     PRE-norm), ``gr=(gate, resid)`` ((B,N), (M,N)) gate+residual
-    epilogue, ``bv`` (M,) int32 row->batch index (required by nm/gr).
+    epilogue, ``rows_per_batch`` x rows per batch entry (required by
+    nm/gr).
     """
     half = 2 ** (bits - 1)
     M, K = x.shape
@@ -317,8 +328,9 @@ def int8_matmul_fq(x, wq, sx, zx, scale, corr, bias=None, g=None, *,
         bias = jnp.zeros((N,), jnp.float32)
     if g is None:
         g = 0
-    ps2, bv2, nm_rows, gr_rows = _prep_fusions(
-        x, ps, nm, gr, bv, M=M, K=K, N=N, Mp=Mp, Kp=Kp, Np=Np)
+    fspecs, fargs, fusions = _fusions(
+        x, ps, nm, gr, rows_per_batch, has_g=True, M=M, K=K, N=N, Mp=Mp,
+        Kp=Kp, Np=Np, bm_=bm_, bk_=bk_, bn_=bn_)
     x = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))
     wq = jnp.pad(wq, ((0, Kp - K), (0, Np - N)))
     scale = jnp.pad(scale.astype(jnp.float32), ((0, 0), (0, Np - N)))
@@ -334,19 +346,16 @@ def int8_matmul_fq(x, wq, sx, zx, scale, corr, bias=None, g=None, *,
     # A traced g (the tgroup inside ddpm_sample's scan) therefore changes
     # WHICH rows stream in, never the executable: one compile covers all
     # timestep groups.
-    fspecs, fargs = _fusion_specs_args(
-        has_g=True, ps=ps2, bv=bv2, nm_rows=nm_rows, gr_rows=gr_rows,
-        bm_=bm_, bk_=bk_, bn_=bn_)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda m, n, k, g: (m, k)),    # x tile
             pl.BlockSpec((bk_, bn_), lambda m, n, k, g: (k, n)),    # W tile
-            pl.BlockSpec((1, 1), lambda m, n, k, g: (g[0], 0)),     # sx[g]
-            pl.BlockSpec((1, 1), lambda m, n, k, g: (g[0], 0)),     # zx[g]
-            pl.BlockSpec((1, bn_), lambda m, n, k, g: (g[0], n)),   # scale[g]
-            pl.BlockSpec((1, bn_), lambda m, n, k, g: (g[0], n)),   # corr[g]
+            _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),    # sx[g]
+            _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),    # zx[g]
+            _group_param((bn_,), lambda m, n, k, g: (g[0], 0, n)),  # scale[g]
+            _group_param((bn_,), lambda m, n, k, g: (g[0], 0, n)),  # corr[g]
             pl.BlockSpec((1, bn_), lambda m, n, k, g: (0, n)),      # bias
         ] + fspecs,
         out_specs=pl.BlockSpec((bm_, bn_), lambda m, n, k, g: (m, n)),
@@ -354,19 +363,17 @@ def int8_matmul_fq(x, wq, sx, zx, scale, corr, bias=None, g=None, *,
     )
     out = pl.pallas_call(
         functools.partial(_fq_kernel, nk=nk, half=half,
-                          has_ps=ps2 is not None, has_nm=nm_rows is not None,
-                          has_gr=gr_rows is not None),
+                          **fusions),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         interpret=interpret,
     )(jnp.asarray(g, jnp.int32).reshape(1), x, wq,
-      sx.astype(jnp.float32), zx.astype(jnp.float32), scale, corr, bias,
-      *fargs)
+      _stack3(sx.astype(jnp.float32)), _stack3(zx.astype(jnp.float32)),
+      _stack3(scale), _stack3(corr), bias, *fargs)
     return out[:M, :N]
 
 
-def _mrq_kernel(g_ref, *refs, nk: int, half: int, has_ps: bool = False,
-                has_nm: bool = False, has_gr: bool = False):
+def _mrq_kernel(g_ref, *refs, nk: int, half: int, **fusions):
     """Grid body for ``int8_matmul_mrq_fq`` at grid point (m, n, k).
 
     Same tiling/prefetch contract as ``_fq_kernel`` (group-``g`` rows of
@@ -385,8 +392,7 @@ def _mrq_kernel(g_ref, *refs, nk: int, half: int, has_ps: bool = False,
         refs[:7]
     o_ref, acc_n_ref, acc_p_ref = refs[-3], refs[-2], refs[-1]
     ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
-        _unpack_fusion_refs(refs[7:-3], has_ps=has_ps, has_nm=has_nm,
-                            has_gr=has_gr)
+        _unpack_fusion_refs(refs[7:-3], **fusions)
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -402,11 +408,11 @@ def _mrq_kernel(g_ref, *refs, nk: int, half: int, has_ps: bool = False,
                    0).astype(jnp.int8)
     qp = jnp.where(neg, 0, jnp.clip(jnp.round(xf / sp_ref[0, 0]), 0, half - 1)
                    ).astype(jnp.int8)
-    w = w_ref[...].astype(jnp.int32)          # ONE weight-tile read, two dots
+    w = w_ref[...]                            # ONE weight-tile read, two dots
     dims = (((1,), (0,)), ((), ()))
-    acc_n_ref[...] += jax.lax.dot_general(qn.astype(jnp.int32), w, dims,
+    acc_n_ref[...] += jax.lax.dot_general(qn, w, dims,
                                           preferred_element_type=jnp.int32)
-    acc_p_ref[...] += jax.lax.dot_general(qp.astype(jnp.int32), w, dims,
+    acc_p_ref[...] += jax.lax.dot_general(qp, w, dims,
                                           preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
@@ -419,9 +425,11 @@ def _mrq_kernel(g_ref, *refs, nk: int, half: int, has_ps: bool = False,
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "bm", "bn", "bk",
-                                             "out_dtype", "interpret"))
+                                             "rows_per_batch", "out_dtype",
+                                             "interpret"))
 def int8_matmul_mrq_fq(x, wq, s_neg, s_pos, scale_neg, scale_pos, bias=None,
-                       g=None, *, ps=None, nm=None, gr=None, bv=None, bits=8,
+                       g=None, *, ps=None, nm=None, gr=None,
+                       rows_per_batch=None, bits=8,
                        bm=DEFAULT_BM, bn=DEFAULT_BN, bk=DEFAULT_BK,
                        out_dtype=jnp.float32, interpret=False):
     """Single-pass MRQ matmul: one traversal of wq, dual s32 accumulators.
@@ -430,9 +438,9 @@ def int8_matmul_mrq_fq(x, wq, s_neg, s_pos, scale_neg, scale_pos, bias=None,
     qn/qp are the negative/positive two-region codes of x (disjoint
     support, selected by sign). s_neg/s_pos: (G,1) f32 region steps;
     scale_neg/scale_pos: (G,N) f32 combined region*weight scales.
-    Optional ``ps``/``nm``/``gr``/``bv`` fusions as ``int8_matmul_fq``
-    (the prologue runs before the sign split; prescale divisors are
-    positive, so region selection is unchanged).
+    Optional ``ps``/``nm``/``gr``/``rows_per_batch`` fusions as
+    ``int8_matmul_fq`` (the prologue runs before the sign split; prescale
+    divisors are positive, so region selection is unchanged).
     """
     M, K = x.shape
     K2, N = wq.shape
@@ -448,8 +456,9 @@ def int8_matmul_mrq_fq(x, wq, s_neg, s_pos, scale_neg, scale_pos, bias=None,
         bias = jnp.zeros((N,), jnp.float32)
     if g is None:
         g = 0
-    ps2, bv2, nm_rows, gr_rows = _prep_fusions(
-        x, ps, nm, gr, bv, M=M, K=K, N=N, Mp=Mp, Kp=Kp, Np=Np)
+    fspecs, fargs, fusions = _fusions(
+        x, ps, nm, gr, rows_per_batch, has_g=True, M=M, K=K, N=N, Mp=Mp,
+        Kp=Kp, Np=Np, bm_=bm_, bk_=bk_, bn_=bn_)
     x = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))
     wq = jnp.pad(wq, ((0, Kp - K), (0, Np - N)))
     scale_neg = jnp.pad(scale_neg.astype(jnp.float32), ((0, 0), (0, Np - N)))
@@ -461,19 +470,16 @@ def int8_matmul_mrq_fq(x, wq, s_neg, s_pos, scale_neg, scale_pos, bias=None,
     # Same scalar-prefetch group gather as int8_matmul_fq (see the comment
     # there); here the gathered rows are the two region step sizes and the
     # two combined region*weight scale rows.
-    fspecs, fargs = _fusion_specs_args(
-        has_g=True, ps=ps2, bv=bv2, nm_rows=nm_rows, gr_rows=gr_rows,
-        bm_=bm_, bk_=bk_, bn_=bn_)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda m, n, k, g: (m, k)),    # x tile
             pl.BlockSpec((bk_, bn_), lambda m, n, k, g: (k, n)),    # W tile
-            pl.BlockSpec((1, 1), lambda m, n, k, g: (g[0], 0)),     # s_neg[g]
-            pl.BlockSpec((1, 1), lambda m, n, k, g: (g[0], 0)),     # s_pos[g]
-            pl.BlockSpec((1, bn_), lambda m, n, k, g: (g[0], n)),   # scale_neg
-            pl.BlockSpec((1, bn_), lambda m, n, k, g: (g[0], n)),   # scale_pos
+            _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),    # s_neg[g]
+            _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),    # s_pos[g]
+            _group_param((bn_,), lambda m, n, k, g: (g[0], 0, n)),  # scale_neg
+            _group_param((bn_,), lambda m, n, k, g: (g[0], 0, n)),  # scale_pos
             pl.BlockSpec((1, bn_), lambda m, n, k, g: (0, n)),      # bias
         ] + fspecs,
         out_specs=pl.BlockSpec((bm_, bn_), lambda m, n, k, g: (m, n)),
@@ -482,22 +488,20 @@ def int8_matmul_mrq_fq(x, wq, s_neg, s_pos, scale_neg, scale_pos, bias=None,
     )
     out = pl.pallas_call(
         functools.partial(_mrq_kernel, nk=nk, half=half,
-                          has_ps=ps2 is not None, has_nm=nm_rows is not None,
-                          has_gr=gr_rows is not None),
+                          **fusions),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         interpret=interpret,
     )(jnp.asarray(g, jnp.int32).reshape(1), x, wq,
-      s_neg.astype(jnp.float32), s_pos.astype(jnp.float32),
-      scale_neg, scale_pos, bias, *fargs)
+      _stack3(s_neg.astype(jnp.float32)), _stack3(s_pos.astype(jnp.float32)),
+      _stack3(scale_neg), _stack3(scale_pos), bias, *fargs)
     return out[:M, :N]
 
 
 # ---------------------------------------------------------------------------
 # vector-tgroup variants: per-ROW group indices, one weight stream
 # ---------------------------------------------------------------------------
-def _fq_vec_kernel(gv_ref, *refs, nk: int, half: int, has_ps: bool = False,
-                   has_nm: bool = False, has_gr: bool = False):
+def _fq_vec_kernel(gv_ref, *refs, nk: int, half: int, **fusions):
     """Vector-tgroup body: same math as ``_fq_kernel`` but each ROW of the
     x tile quantizes/dequantizes with its own group's params, gathered
     in VMEM from the full (G, ·) stacks (no scalar prefetch, no per-group
@@ -505,32 +509,30 @@ def _fq_vec_kernel(gv_ref, *refs, nk: int, half: int, has_ps: bool = False,
     x_ref, w_ref, sx_ref, zx_ref, scale_ref, corr_ref, bias_ref = refs[:7]
     o_ref, acc_ref = refs[-2], refs[-1]
     ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
-        _unpack_fusion_refs(refs[7:-2], has_ps=has_ps, has_nm=has_nm,
-                            has_gr=has_gr)
+        _unpack_fusion_refs(refs[7:-2], **fusions)
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    G = sx_ref.shape[0]
-    ohf = _onehot_rows(gv_ref, G).astype(jnp.float32)
-    sx_row = _gather_rows(ohf, sx_ref, jnp.float32)      # (bm, 1)
-    zx_row = _gather_rows(ohf, zx_ref, jnp.float32)      # (bm, 1)
+    gv = gv_ref[...]
+    sx_row = _gather_rows(gv, sx_ref[...])               # (bm, 1)
+    zx_row = _gather_rows(gv, zx_ref[...])               # (bm, 1)
     xf = _fusion_prologue(x_ref[...].astype(jnp.float32), ps_ref, bv_ref,
                           mu_ref, rsig_ref, sh_ref, sc_ref)
     xq = jnp.clip(
         jnp.round(xf / sx_row) + zx_row - half,
         -half, half - 1).astype(jnp.int8)
     acc_ref[...] += jax.lax.dot_general(
-        xq.astype(jnp.int32), w_ref[...].astype(jnp.int32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        xq, w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
-        oh = _onehot_rows(gv_ref, G)
-        scale_row = _gather_rows(oh, scale_ref, jnp.float32)   # (bm, bn)
-        corr_row = _gather_rows(oh, corr_ref, jnp.int32)       # (bm, bn)
+        gv = gv_ref[...]
+        scale_row = _gather_rows(gv, scale_ref[...])           # (bm, bn)
+        corr_row = _gather_rows(gv, corr_ref[...])             # (bm, bn)
         acc = acc_ref[...] - corr_row
         y = acc.astype(jnp.float32) * scale_row + bias_ref[...]
         y = _fusion_epilogue(y, bv_ref, gate_ref, res_ref)
@@ -538,9 +540,10 @@ def _fq_vec_kernel(gv_ref, *refs, nk: int, half: int, has_ps: bool = False,
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "bm", "bn", "bk",
-                                             "out_dtype", "interpret"))
+                                             "rows_per_batch", "out_dtype",
+                                             "interpret"))
 def int8_matmul_fq_vec(x, wq, sx, zx, scale, corr, bias=None, gv=None, *,
-                       ps=None, nm=None, gr=None, bv=None, bits=8,
+                       ps=None, nm=None, gr=None, rows_per_batch=None, bits=8,
                        bm=DEFAULT_BM, bn=DEFAULT_BN, bk=DEFAULT_BK,
                        out_dtype=jnp.float32, interpret=False):
     """``int8_matmul_fq`` with a per-ROW group vector.
@@ -550,7 +553,7 @@ def int8_matmul_fq_vec(x, wq, sx, zx, scale, corr, bias=None, gv=None, *,
     ONCE for the whole mixed-group batch; the full (G, ·) param stacks
     ride along instead (G ≤ ~10, negligible next to W). A constant gv is
     bit-identical to the scalar-prefetch path. Optional ``ps``/``nm``/
-    ``gr``/``bv`` fusions as ``int8_matmul_fq``.
+    ``gr``/``rows_per_batch`` fusions as ``int8_matmul_fq``.
     """
     half = 2 ** (bits - 1)
     M, K = x.shape
@@ -567,8 +570,9 @@ def int8_matmul_fq_vec(x, wq, sx, zx, scale, corr, bias=None, gv=None, *,
     if gv is None:
         gv = jnp.zeros((M,), jnp.int32)
     gv = jnp.pad(jnp.asarray(gv, jnp.int32), (0, Mp - M)).reshape(Mp, 1)
-    ps2, bv2, nm_rows, gr_rows = _prep_fusions(
-        x, ps, nm, gr, bv, M=M, K=K, N=N, Mp=Mp, Kp=Kp, Np=Np)
+    fspecs, fargs, fusions = _fusions(
+        x, ps, nm, gr, rows_per_batch, has_g=False, M=M, K=K, N=N, Mp=Mp,
+        Kp=Kp, Np=Np, bm_=bm_, bk_=bk_, bn_=bn_)
     x = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))
     wq = jnp.pad(wq, ((0, Kp - K), (0, Np - N)))
     scale = jnp.pad(scale.astype(jnp.float32), ((0, 0), (0, Np - N)))
@@ -577,13 +581,9 @@ def int8_matmul_fq_vec(x, wq, sx, zx, scale, corr, bias=None, gv=None, *,
 
     nk = Kp // bk_
     grid = (Mp // bm_, Np // bn_, nk)
-    fspecs, fargs = _fusion_specs_args(
-        has_g=False, ps=ps2, bv=bv2, nm_rows=nm_rows, gr_rows=gr_rows,
-        bm_=bm_, bk_=bk_, bn_=bn_)
     out = pl.pallas_call(
         functools.partial(_fq_vec_kernel, nk=nk, half=half,
-                          has_ps=ps2 is not None, has_nm=nm_rows is not None,
-                          has_gr=gr_rows is not None),
+                          **fusions),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, 1), lambda m, n, k: (m, 0)),     # gv rows
@@ -604,16 +604,14 @@ def int8_matmul_fq_vec(x, wq, sx, zx, scale, corr, bias=None, gv=None, *,
     return out[:M, :N]
 
 
-def _mrq_vec_kernel(gv_ref, *refs, nk: int, half: int, has_ps: bool = False,
-                    has_nm: bool = False, has_gr: bool = False):
+def _mrq_vec_kernel(gv_ref, *refs, nk: int, half: int, **fusions):
     """Vector-tgroup body for the MRQ twin-region matmul: per-row region
-    steps from the one-hot gather, one W read feeding both accumulators."""
+    steps from ``_gather_rows``, one W read feeding both accumulators."""
     x_ref, w_ref, sn_ref, sp_ref, scale_n_ref, scale_p_ref, bias_ref = \
         refs[:7]
     o_ref, acc_n_ref, acc_p_ref = refs[-3], refs[-2], refs[-1]
     ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
-        _unpack_fusion_refs(refs[7:-3], has_ps=has_ps, has_nm=has_nm,
-                            has_gr=has_gr)
+        _unpack_fusion_refs(refs[7:-3], **fusions)
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -621,10 +619,9 @@ def _mrq_vec_kernel(gv_ref, *refs, nk: int, half: int, has_ps: bool = False,
         acc_n_ref[...] = jnp.zeros_like(acc_n_ref)
         acc_p_ref[...] = jnp.zeros_like(acc_p_ref)
 
-    G = sn_ref.shape[0]
-    ohf = _onehot_rows(gv_ref, G).astype(jnp.float32)
-    sn_row = _gather_rows(ohf, sn_ref, jnp.float32)      # (bm, 1)
-    sp_row = _gather_rows(ohf, sp_ref, jnp.float32)      # (bm, 1)
+    gv = gv_ref[...]
+    sn_row = _gather_rows(gv, sn_ref[...])               # (bm, 1)
+    sp_row = _gather_rows(gv, sp_ref[...])               # (bm, 1)
     xf = _fusion_prologue(x_ref[...].astype(jnp.float32), ps_ref, bv_ref,
                           mu_ref, rsig_ref, sh_ref, sc_ref)
     neg = xf < 0
@@ -632,18 +629,18 @@ def _mrq_vec_kernel(gv_ref, *refs, nk: int, half: int, has_ps: bool = False,
                    0).astype(jnp.int8)
     qp = jnp.where(neg, 0, jnp.clip(jnp.round(xf / sp_row), 0, half - 1)
                    ).astype(jnp.int8)
-    w = w_ref[...].astype(jnp.int32)          # ONE weight-tile read, two dots
+    w = w_ref[...]                            # ONE weight-tile read, two dots
     dims = (((1,), (0,)), ((), ()))
-    acc_n_ref[...] += jax.lax.dot_general(qn.astype(jnp.int32), w, dims,
+    acc_n_ref[...] += jax.lax.dot_general(qn, w, dims,
                                           preferred_element_type=jnp.int32)
-    acc_p_ref[...] += jax.lax.dot_general(qp.astype(jnp.int32), w, dims,
+    acc_p_ref[...] += jax.lax.dot_general(qp, w, dims,
                                           preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
-        ohe = _onehot_rows(gv_ref, G).astype(jnp.float32)
-        scale_n_row = _gather_rows(ohe, scale_n_ref, jnp.float32)
-        scale_p_row = _gather_rows(ohe, scale_p_ref, jnp.float32)
+        gv = gv_ref[...]
+        scale_n_row = _gather_rows(gv, scale_n_ref[...])
+        scale_p_row = _gather_rows(gv, scale_p_ref[...])
         y = (acc_n_ref[...].astype(jnp.float32) * scale_n_row
              + acc_p_ref[...].astype(jnp.float32) * scale_p_row
              + bias_ref[...])
@@ -652,12 +649,13 @@ def _mrq_vec_kernel(gv_ref, *refs, nk: int, half: int, has_ps: bool = False,
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "bm", "bn", "bk",
-                                             "out_dtype", "interpret"))
+                                             "rows_per_batch", "out_dtype",
+                                             "interpret"))
 def int8_matmul_mrq_fq_vec(x, wq, s_neg, s_pos, scale_neg, scale_pos,
                            bias=None, gv=None, *, ps=None, nm=None, gr=None,
-                           bv=None, bits=8, bm=DEFAULT_BM, bn=DEFAULT_BN,
-                           bk=DEFAULT_BK, out_dtype=jnp.float32,
-                           interpret=False):
+                           rows_per_batch=None, bits=8, bm=DEFAULT_BM,
+                           bn=DEFAULT_BN, bk=DEFAULT_BK,
+                           out_dtype=jnp.float32, interpret=False):
     """``int8_matmul_mrq_fq`` with a per-ROW group vector (see
     ``int8_matmul_fq_vec`` for the one-weight-read contract)."""
     M, K = x.shape
@@ -675,8 +673,9 @@ def int8_matmul_mrq_fq_vec(x, wq, s_neg, s_pos, scale_neg, scale_pos,
     if gv is None:
         gv = jnp.zeros((M,), jnp.int32)
     gv = jnp.pad(jnp.asarray(gv, jnp.int32), (0, Mp - M)).reshape(Mp, 1)
-    ps2, bv2, nm_rows, gr_rows = _prep_fusions(
-        x, ps, nm, gr, bv, M=M, K=K, N=N, Mp=Mp, Kp=Kp, Np=Np)
+    fspecs, fargs, fusions = _fusions(
+        x, ps, nm, gr, rows_per_batch, has_g=False, M=M, K=K, N=N, Mp=Mp,
+        Kp=Kp, Np=Np, bm_=bm_, bk_=bk_, bn_=bn_)
     x = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))
     wq = jnp.pad(wq, ((0, Kp - K), (0, Np - N)))
     scale_neg = jnp.pad(scale_neg.astype(jnp.float32), ((0, 0), (0, Np - N)))
@@ -685,13 +684,9 @@ def int8_matmul_mrq_fq_vec(x, wq, s_neg, s_pos, scale_neg, scale_pos,
 
     nk = Kp // bk_
     grid = (Mp // bm_, Np // bn_, nk)
-    fspecs, fargs = _fusion_specs_args(
-        has_g=False, ps=ps2, bv=bv2, nm_rows=nm_rows, gr_rows=gr_rows,
-        bm_=bm_, bk_=bk_, bn_=bn_)
     out = pl.pallas_call(
         functools.partial(_mrq_vec_kernel, nk=nk, half=half,
-                          has_ps=ps2 is not None, has_nm=nm_rows is not None,
-                          has_gr=gr_rows is not None),
+                          **fusions),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, 1), lambda m, n, k: (m, 0)),     # gv rows

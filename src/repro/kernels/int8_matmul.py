@@ -36,8 +36,8 @@ def _kernel(x_ref, w_ref, scale_ref, corr_ref, bias_ref, o_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jax.lax.dot_general(
-        x_ref[...].astype(jnp.int32), w_ref[...].astype(jnp.int32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -100,3 +100,19 @@ def _ceil(x, to=8):
 
 def _pad_to(x, b):
     return -b * (-x // b)
+
+
+def _group_param(shape, index_map):
+    """BlockSpec for one group's row of a (G, 1, n) param stack.
+
+    The stack carries a unit middle axis so that the last two block dims
+    equal the array's own (1, n): the TPU tiling then admits a one-row
+    block at any G. The squeezed group axis is picked by ``index_map``,
+    and the kernel sees a plain (1, n) tile."""
+    return pl.BlockSpec((pl.squeezed, 1) + tuple(shape), index_map)
+
+
+def _stack3(p):
+    """(G, n) param stack -> (G, 1, n) for ``_group_param`` blocks."""
+    p = jnp.asarray(p)
+    return p.reshape(p.shape[0], 1, p.shape[1])

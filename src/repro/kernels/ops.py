@@ -492,12 +492,13 @@ def _as_vec(g, B: int):
 
 
 def _fusion_kwargs(pack: dict, xm, norm_mod, gate_residual) -> dict:
-    """Kernel-side ``ps``/``nm``/``gr``/``bv`` operands for one linear.
+    """Kernel-side ``ps``/``nm``/``gr``/``rows_per_batch`` operands for one
+    linear.
 
     ``norm_mod = (shift, scale)`` and ``gate_residual = (gate, residual)``
     carry per-BATCH (B, ·) adaLN rows (the residual is x-shaped). Matmul
-    rows stay batch-major under ``x.reshape(-1, K)``, so the row->batch
-    map the kernels gather with is a plain repeat. The channel-balance
+    rows stay batch-major under ``x.reshape(-1, K)``, so batch entry b
+    owns ``rows_per_batch`` consecutive rows. The channel-balance
     prescale rides the pack itself (``pack_int8_linear``)."""
     kw = {}
     ps = pack.get("x_prescale")
@@ -511,7 +512,7 @@ def _fusion_kwargs(pack: dict, xm, norm_mod, gate_residual) -> dict:
     if n_rows % B != 0:
         raise ValueError(
             f"fusion rows: {n_rows} matmul rows not divisible by batch {B}")
-    kw["bv"] = jnp.repeat(jnp.arange(B, dtype=jnp.int32), n_rows // B)
+    kw["rows_per_batch"] = n_rows // B
     if norm_mod is not None:
         sh, sc = norm_mod
         kw["nm"] = (jnp.asarray(sh, jnp.float32), jnp.asarray(sc, jnp.float32))

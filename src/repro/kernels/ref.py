@@ -387,7 +387,7 @@ def int8_attention_ref(q, k, v, qk_pack, pv_pack, mask=None, scale=1.0,
 # flash-style fused attention (single kernel, no (S,S) HBM round-trip)
 # ---------------------------------------------------------------------------
 def flash_attn_mrq_ref(q, k, v, qk_pack, pv_pack, mask=None, scale=1.0,
-                       g_qk=0, g_pv=0, bits: int = 8, bn: int = 128,
+                       g_qk=0, g_pv=0, bits: int = 8, bn=None,
                        out_dtype=jnp.float32):
     """Tile-faithful oracle for ``flash_attn_mrq`` over FLATTENED
     (B, S, hd) operands (kv materialized per q batch — the kernel's
@@ -400,11 +400,13 @@ def flash_attn_mrq_ref(q, k, v, qk_pack, pv_pack, mask=None, scale=1.0,
     (jitted) bit-exact, the same contract as the composed kernels.
     """
     from repro.nn.ctx import NEG_INF
+    from repro.kernels.flash_attn_mrq import kv_tile
     from repro.kernels.int8_matmul import _ceil
     B, M, D = q.shape
     N = k.shape[1]
     half = 2 ** (bits - 1)
-    bn_ = min(bn, _ceil(N))                    # the kernel's tile rounding
+    # the kernel's kv tile
+    bn_ = kv_tile(M, N) if bn is None else min(bn, _ceil(N))
     Np = -bn_ * (-N // bn_)
 
     sq_g = jnp.take(qk_pack["s_q"], g_qk, axis=0)[0]
@@ -686,16 +688,17 @@ def int8_attention_vec_ref(q, k, v, qk_pack, pv_pack, mask=None, scale=1.0,
 
 def flash_attn_mrq_vec_ref(q, k, v, qk_pack, pv_pack, mask=None, scale=1.0,
                            g_qk=None, g_pv=None, bits: int = 8,
-                           bn: int = 128, out_dtype=jnp.float32):
+                           bn=None, out_dtype=jnp.float32):
     """Tile-faithful per-batch-row oracle for ``flash_attn_mrq_vec``:
     the recurrence of ``flash_attn_mrq_ref`` with every group-gathered
     scalar widened to a (B, 1, 1) per-batch-row column."""
     from repro.nn.ctx import NEG_INF
+    from repro.kernels.flash_attn_mrq import kv_tile
     from repro.kernels.int8_matmul import _ceil
     B, M, D = q.shape
     N = k.shape[1]
     half = 2 ** (bits - 1)
-    bn_ = min(bn, _ceil(N))
+    bn_ = kv_tile(M, N) if bn is None else min(bn, _ceil(N))
     Np = -bn_ * (-N // bn_)
     g_qk = jnp.zeros((B,), jnp.int32) if g_qk is None else jnp.asarray(g_qk)
     g_pv = jnp.zeros((B,), jnp.int32) if g_pv is None else jnp.asarray(g_pv)

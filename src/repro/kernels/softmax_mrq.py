@@ -40,6 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.int8_matmul import _group_param, _stack3
+
 
 def _kernel(s_ref, s1_ref, o_ref, *, bits: int):
     x = s_ref[...].astype(jnp.float32)
@@ -136,7 +138,7 @@ def softmax_mrq_codes(scores, s1, g=None, *, bits: int = 8, br: int = 256,
         grid=(Rp // br_,),
         in_specs=[
             pl.BlockSpec((br_, C), lambda r, g: (r, 0)),
-            pl.BlockSpec((1, 1), lambda r, g: (g[0], 0)),     # s1[g]
+            _group_param((1,), lambda r, g: (g[0], 0, 0)),    # s1[g]
         ],
         out_specs=pl.BlockSpec((br_, C), lambda r, g: (r, 0)),
     )
@@ -145,25 +147,23 @@ def softmax_mrq_codes(scores, s1, g=None, *, bits: int = 8, br: int = 256,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Rp, C), jnp.int8),
         interpret=interpret,
-    )(jnp.asarray(g, jnp.int32).reshape(1), x, s1.astype(jnp.float32))
+    )(jnp.asarray(g, jnp.int32).reshape(1), x, _stack3(s1.astype(jnp.float32)))
     return out[:R].reshape(shape)
 
 
 def _codes_vec_kernel(gv_ref, s_ref, s1_ref, o_ref, *, bits: int):
     """Vector-tgroup ``_codes_kernel``: each ROW quantizes with its own
-    group's s1, gathered from the full (G, 1) stack via the exact one-hot
-    product (deferred import dodges the int8_fused <-> softmax cycle risk
-    at package init — there is none today, but keep the dep one-way)."""
-    from repro.kernels.int8_fused import _gather_rows, _onehot_rows
+    group's s1, gathered exactly from the full (G, 1) stack (deferred
+    import dodges the int8_fused <-> softmax cycle risk at package init —
+    there is none today, but keep the dep one-way)."""
+    from repro.kernels.int8_fused import _gather_rows
     x = s_ref[...].astype(jnp.float32)
     x = x - jnp.max(x, axis=-1, keepdims=True)
     e = jnp.exp(x)
     p = e / jnp.sum(e, axis=-1, keepdims=True)
 
     half = 2 ** (bits - 1)
-    G = s1_ref.shape[0]
-    ohf = _onehot_rows(gv_ref, G).astype(jnp.float32)
-    s1_row = _gather_rows(ohf, s1_ref, jnp.float32)       # (br, 1)
+    s1_row = _gather_rows(gv_ref[...], s1_ref[...])       # (br, 1)
     s2 = 1.0 / half
     q1 = jnp.clip(jnp.round(p / s1_row), 0, half - 1)
     q2 = jnp.clip(jnp.round(p / s2), 0, half)

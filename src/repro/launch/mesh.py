@@ -12,17 +12,25 @@ real 1-CPU backend).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding
+    constraints and shard_map specs in this repo are written for
+    compiler-propagated shardings, not ``Explicit`` sharding-in-types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many devices the backend actually has."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def make_serving_mesh(data: int | None = None):
@@ -31,7 +39,7 @@ def make_serving_mesh(data: int | None = None):
     and shards microbatches on "data" via shard_map — the DiT models in
     this repo fit on one chip, so serving scales out, not up."""
     data = data or jax.device_count()
-    return jax.make_mesh((data, 1), ("data", "model"))
+    return _mesh((data, 1), ("data", "model"))
 
 
 # TPU v5e hardware constants (per chip) used by the roofline analysis.

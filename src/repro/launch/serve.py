@@ -162,9 +162,11 @@ def main() -> None:
     import numpy as np
 
     from repro.configs import get, get_smoke
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import DiTCfg, lm_init, lm_generate
     from repro.nn.ctx import FPContext
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get(args.arch)
     key = jax.random.PRNGKey(args.seed)
     ctx = FPContext()
@@ -271,6 +273,8 @@ def main() -> None:
             print(f"{st['dispatches']} dispatches, {st['chunk_traces']} "
                   f"chunk trace(s), {st['retries']} retries, "
                   f"{len(st['degradations'])} degradations")
+            for d in st["degradations"]:
+                print(f"degraded: {d['reason']} (after {d['error']})")
             print(f"sample mean={samples.mean():.4f} "
                   f"std={samples.std():.4f}")
             return

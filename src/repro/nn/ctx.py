@@ -100,6 +100,16 @@ class OpContext:
     def with_tgroup(self, tgroup) -> "OpContext":
         return dataclasses.replace(self, tgroup=tgroup)
 
+    def split_arrays(self):
+        """``(arrays, rebuild)``: the context's array state, and a function
+        that returns this context with replacements for those arrays.
+
+        A jitted caller passes ``arrays`` as arguments and rebuilds the
+        context inside the traced function: an array the function closes
+        over would be compiled into the program as a constant (for a
+        quantized model, every weight code). This context holds none."""
+        return (), lambda arrays: self
+
     # -- op seams ----------------------------------------------------------
     def linear(self, name: str, x, w, b=None, norm_mod=None,
                gate_residual=None):

@@ -25,8 +25,9 @@ One :class:`ServeEngine` owns
   three-kernel chain under ``attn_impl="composed"`` — so the DDPM scan
   stays one compiled program with no fp attention island inside.
 
-``check_rep=False`` on the shard_map is required: pallas_call has no
-replication rule, and the body is embarrassingly data-parallel anyway.
+``check_vma=False`` on the shard_map is required: pallas_call has no
+varying-manual-axes rule, and the body is embarrassingly data-parallel
+anyway.
 """
 from __future__ import annotations
 
@@ -38,8 +39,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.diffusion import DiffusionCfg, ddpm_sample_paired, make_schedule
 from repro.diffusion.ddpm import (
@@ -55,6 +55,16 @@ from repro.serving.batching import (
 )
 from repro.serving.faults import EngineFault, degrade_context
 from repro.serving.scheduler import validate_label
+
+
+def _ctx_arrays(ctx, mesh: Optional[Mesh]):
+    """The op context's arrays (replicated on ``mesh``) and its rebuild
+    function: executables take a quantized model's weight codes as
+    arguments, not as compiled-in constants (``OpContext.split_arrays``)."""
+    arrays, rebuild = ctx.split_arrays()
+    if mesh is not None:
+        arrays = jax.device_put(arrays, replicated(mesh))
+    return arrays, rebuild
 
 
 class ServeEngine:
@@ -95,6 +105,7 @@ class ServeEngine:
                     f"mesh's {nd} data-parallel shards")
             params = jax.device_put(params, replicated(mesh))
         self.params = params
+        self._qargs, self._rebuild_ctx = _ctx_arrays(self.ctx, mesh)
         self._fns: Dict[int, Any] = {}          # step bucket -> compiled fn
         self.stats: Dict[str, Any] = {
             "compiled_buckets": [], "microbatches": 0, "requests": 0,
@@ -142,23 +153,24 @@ class ServeEngine:
     # -- executable construction -------------------------------------------
     def _build(self, steps: int):
         dcfg, dif, sched = self.dcfg, self.dif, self.sched
-        ctx, clip = self.ctx, self.clip_x0
+        rebuild, clip = self._rebuild_ctx, self.clip_x0
         null_label = dcfg.n_classes                # the extra embedding row
 
-        def run(params, labels, seeds, guidance):
+        def run(params, qargs, labels, seeds, guidance):
             eps = lambda x, t, y, c: dit_apply(params, dcfg, x, t, y, ctx=c)
             shape = (labels.shape[0], dcfg.img_size, dcfg.img_size,
                      dcfg.in_ch)
             return ddpm_sample_paired(eps, dif, sched, shape, labels, seeds,
                                       guidance, null_label=null_label,
-                                      steps=steps, ctx=ctx, clip_x0=clip)
+                                      steps=steps, ctx=rebuild(qargs),
+                                      clip_x0=clip)
 
         if self.mesh is not None:
             rspec = request_spec(self.mesh)
-            run = shard_map(run, mesh=self.mesh,
-                            in_specs=(P(), rspec, rspec, rspec),
-                            out_specs=batch_spec(self.mesh, 4),
-                            check_rep=False)
+            run = jax.shard_map(run, mesh=self.mesh,
+                                in_specs=(P(), P(), rspec, rspec, rspec),
+                                out_specs=batch_spec(self.mesh, 4),
+                                check_vma=False)
         return jax.jit(run)
 
     def _fn(self, steps: int):
@@ -178,7 +190,8 @@ class ServeEngine:
         if mb.steps not in self.step_buckets:
             raise ValueError(f"steps {mb.steps} not in configured buckets "
                              f"{self.step_buckets}")
-        out = self._fn(mb.steps)(self.params, jnp.asarray(mb.labels),
+        out = self._fn(mb.steps)(self.params, self._qargs,
+                                 jnp.asarray(mb.labels),
                                  jnp.asarray(mb.seeds),
                                  jnp.asarray(mb.guidance))
         return np.asarray(jax.block_until_ready(out))
@@ -306,12 +319,12 @@ class AsyncServeEngine:
                             enumerate(self._slot_sched["buckets"])}
         B = self.microbatch
         sshape = (dcfg.img_size, dcfg.img_size, dcfg.in_ch)
-        self._x = jnp.zeros((B,) + sshape, jnp.float32)
-        self._pos = jnp.full((B,), self._n_max, jnp.int32)   # all free
-        self._bk = jnp.zeros((B,), jnp.int32)
-        self._y = jnp.zeros((B,), jnp.int32)
-        self._seeds = jnp.zeros((B,), jnp.uint32)
-        self._gs = jnp.ones((B,), jnp.float32)
+        self._x = self._on_pool(jnp.zeros((B,) + sshape, jnp.float32))
+        self._pos = self._on_pool(jnp.full((B,), self._n_max, jnp.int32))
+        self._bk = self._on_pool(jnp.zeros((B,), jnp.int32))  # ^ all free
+        self._y = self._on_pool(jnp.zeros((B,), jnp.int32))
+        self._seeds = self._on_pool(jnp.zeros((B,), jnp.uint32))
+        self._gs = self._on_pool(jnp.ones((B,), jnp.float32))
 
         self._slot_rid: List[Optional[int]] = [None] * B
         self._pos_host = np.full((B,), self._n_max, np.int64)
@@ -323,12 +336,13 @@ class AsyncServeEngine:
         self._t0 = clock()
 
         self.stats: Dict[str, Any] = {
-            "dispatches": 0, "chunk_traces": 0, "degradations": [],
-            "admitted": 0, "completed": 0, "failed": 0, "rejected": 0,
-            "cancelled": 0, "retries": 0, "queue_peak": 0,
+            "dispatches": 0, "chunk_traces": 0, "compile_s": 0.0,
+            "degradations": [], "admitted": 0, "completed": 0, "failed": 0,
+            "rejected": 0, "cancelled": 0, "retries": 0, "queue_peak": 0,
         }
         self._pending = None            # dispatch-ahead in-flight chunk
         self._chunk_fn = self._build_chunk()
+        self._chunk_exec = None         # compiled by _compile_chunk
         self._init_fn = jax.jit(
             lambda seed, n: ddpm_init_latent(seed, n, sshape))
 
@@ -343,30 +357,60 @@ class AsyncServeEngine:
                    ctx=artifact.context(kernel=kernel, attn_impl=attn_impl),
                    **kw)
 
+    def _on_pool(self, a):
+        """Place per-slot state where the chunk executable keeps it: with a
+        mesh, sharded on the DP axis (slot ``s`` on device
+        ``s // (microbatch / dp)``), the layout the executable returns, so
+        every dispatch sees one input sharding and compiles once."""
+        a = jnp.asarray(a)
+        if self.mesh is None:
+            return a
+        return jax.device_put(a, NamedSharding(
+            self.mesh, batch_spec(self.mesh, a.ndim)))
+
     # -- executable construction -------------------------------------------
     def _build_chunk(self):
         dcfg, dif, S = self.dcfg, self.dif, self._slot_sched
-        ctx, clip, chunk = self.ctx, self.clip_x0, self.chunk
+        clip, chunk = self.clip_x0, self.chunk
         null_label = dcfg.n_classes
         stats = self.stats
+        self._qargs, rebuild = _ctx_arrays(self.ctx, self.mesh)
 
-        def run(params, x, pos, bk, y, seeds, gs):
+        def run(params, qargs, x, pos, bk, y, seeds, gs):
             stats["chunk_traces"] += 1      # python side effect: counts
             eps = lambda xx, t, yy, c: dit_apply(   # TRACES, not dispatches
                 params, dcfg, xx, t, yy, ctx=c)
             return ddpm_chunk_slots(eps, dif, S, x, pos, bk, y, seeds, gs,
                                     null_label=null_label, chunk=chunk,
-                                    ctx=ctx, clip_x0=clip)
+                                    ctx=rebuild(qargs), clip_x0=clip)
 
         if self.mesh is not None:
             rspec = request_spec(self.mesh)
-            run = shard_map(run, mesh=self.mesh,
-                            in_specs=(P(), batch_spec(self.mesh, 4), rspec,
-                                      rspec, rspec, rspec, rspec),
-                            out_specs=(batch_spec(self.mesh, 4), rspec,
-                                       rspec),
-                            check_rep=False)
+            run = jax.shard_map(run, mesh=self.mesh,
+                                in_specs=(P(), P(), batch_spec(self.mesh, 4),
+                                          rspec, rspec, rspec, rspec, rspec),
+                                out_specs=(batch_spec(self.mesh, 4), rspec,
+                                           rspec),
+                                check_vma=False)
         return jax.jit(run)
+
+    def _compile_chunk(self) -> None:
+        """Trace and compile the chunk executable for the pool's shapes.
+
+        This runs outside the degradation ladder: an executable that
+        cannot be traced or compiled (a kernel the backend's compiler
+        refuses, a shape error) is a build fault, and stepping down to a
+        slower, simulated rung would hide it. The dispatch that follows
+        calls this compiled executable, so no compile happens inside the
+        ladder."""
+        t0 = time.perf_counter()
+        self._chunk_exec = self._chunk_fn.lower(
+            *self._chunk_args(self._x, self._pos)).compile()
+        self.stats["compile_s"] += time.perf_counter() - t0
+
+    def _chunk_args(self, x, pos):
+        return (self.params, self._qargs, x, pos, self._bk, self._y,
+                self._seeds, self._gs)
 
     # -- admission ----------------------------------------------------------
     def _reject(self, req: GenRequest, code: str, message: str) -> int:
@@ -529,17 +573,21 @@ class AsyncServeEngine:
 
     def _dispatch(self):
         """One chunk dispatch with the degradation ladder and dispatch-ahead
-        pipelining. Slot state is only replaced AFTER the blocking reads
-        succeed, so a failed dispatch (trace error, kernel fault, injected)
-        is side-effect free and the same chunk can be retried on a degraded
-        context. With ``pipeline >= 2`` the NEXT chunk is enqueued on this
-        chunk's device-resident outputs BEFORE the host blocks on the small
-        (B,) reads — two dispatches in flight, host boundary work overlapped
-        with device compute. The speculative chunk is only consumed if this
+        pipelining. A freshly built executable is compiled first, outside
+        the ladder (``_compile_chunk``), so build faults raise. Slot state
+        is only replaced AFTER the blocking reads succeed, so a failed
+        dispatch (device fault, injected) is side-effect free and the same
+        chunk can be retried on a degraded context. With ``pipeline >= 2``
+        the NEXT chunk is enqueued on this chunk's device-resident outputs
+        BEFORE the host blocks on the small (B,) reads — two dispatches in
+        flight, host boundary work overlapped with device compute. The
+        speculative chunk is only consumed if this
         boundary mutates no slot state; every mutating path drains it
         (``_drain_pipeline``), so fault/deadline/quarantine semantics are
         exactly those of ``pipeline=1``."""
         while True:
+            if self._chunk_exec is None:
+                self._compile_chunk()
             self.stats["dispatches"] += 1
             try:
                 if self._injector is not None:
@@ -548,16 +596,14 @@ class AsyncServeEngine:
                     x, pos, bad = self._pending
                     self._pending = None
                 else:
-                    x, pos, bad = self._chunk_fn(
-                        self.params, self._x, self._pos, self._bk, self._y,
-                        self._seeds, self._gs)
+                    x, pos, bad = self._chunk_exec(
+                        *self._chunk_args(self._x, self._pos))
                 if self.pipeline >= 2:
                     # dispatch-ahead: enqueue the next chunk on the async
                     # dispatch queue now; pump() drains it if this chunk's
                     # boundary mutates any slot
-                    self._pending = self._chunk_fn(
-                        self.params, x, pos, self._bk, self._y,
-                        self._seeds, self._gs)
+                    self._pending = self._chunk_exec(
+                        *self._chunk_args(x, pos))
                 # block on the SMALL outputs only; x stays device-resident
                 pos_h = np.array(pos)      # writable copy: retries reset it
                 bad_h = np.array(bad)
@@ -576,6 +622,7 @@ class AsyncServeEngine:
                 self.stats["degradations"].append(
                     {"reason": reason, "error": f"{type(e).__name__}: {e}"})
                 self._chunk_fn = self._build_chunk()
+                self._chunk_exec = None
 
     def pump(self) -> bool:
         """One engine cycle: admit -> dispatch one chunk -> resolve slots.
@@ -649,7 +696,7 @@ class AsyncServeEngine:
                 continue
 
         self._x = x
-        self._pos = jnp.asarray(pos_h, jnp.int32)
+        self._pos = self._on_pool(pos_h.astype(np.int32))
         for slot, rid in enumerate(self._slot_rid):
             if rid is not None:
                 self._pos_host[slot] = int(pos_h[slot])

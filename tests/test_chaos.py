@@ -19,6 +19,7 @@ dispatch at every fault/lifecycle boundary): outcomes, retry counts,
 degradation logs, and samples are asserted byte-for-byte equal across
 depths, and a subprocess test repeats the quarantine contract on a
 2-device sharded slot pool."""
+import dataclasses
 import os
 import subprocess
 import sys
@@ -160,6 +161,28 @@ def test_ladder_exhausted_fails_everything_structured(tiny_dit):
     assert len(eng.outcomes) == len(REQS)
     assert all(o.status == "FAILED" and o.error.code == "engine_fault"
                for o in eng.outcomes.values())
+
+
+def test_chunk_build_fault_raises_past_the_ladder(tiny_dit, w8a8):
+    """A chunk executable that fails to trace or compile is a build fault,
+    not a dispatch fault: it raises out of the first pump instead of
+    stepping down to a slower rung, and nothing is logged as degraded."""
+    cfg, p = tiny_dit
+
+    class Unbuildable(type(w8a8.context(kernel=True))):
+        def attention(self, name, q, k, v, *, mask=None, scale=1.0):
+            raise NotImplementedError("kernel refused by the compiler")
+
+    eng = AsyncServeEngine.from_artifact(p, w8a8, microbatch=2,
+                                         step_buckets=BUCKETS, chunk=2)
+    eng.ctx = Unbuildable(**{f.name: getattr(eng.ctx, f.name)
+                             for f in dataclasses.fields(eng.ctx)})
+    eng._chunk_fn = eng._build_chunk()
+    eng.submit_request(REQS[0])
+    with pytest.raises(NotImplementedError, match="refused"):
+        eng.pump()
+    assert eng.stats["degradations"] == []
+    assert eng.ctx.kernel and eng.ctx.attn_impl == "flash"
 
 
 # ---------------------------------------------------------------------------

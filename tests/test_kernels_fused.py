@@ -86,6 +86,63 @@ def test_mrq_single_pass_matches_two_matmul_decomposition():
 
 
 # ---------------------------------------------------------------------------
+# adaLN fusions: per-batch rows by index map vs per-row select
+# ---------------------------------------------------------------------------
+def _int4_operands(wq, scale, corr, group_k):
+    """int4 form of an int8 case: nibble-packed 4-bit codes and per-K-group
+    (G, nk, N) scale/correction stacks."""
+    from repro.kernels.int4_packed import pack_int4
+    K = wq.shape[0]
+    w4 = jnp.clip(wq.astype(jnp.int32) // 16, -8, 7).astype(jnp.int8)
+    nk = K // group_k
+    return (pack_int4(w4, axis=0), jnp.repeat(scale[:, None], nk, axis=1),
+            jnp.repeat(corr[:, None] // 16, nk, axis=1))
+
+
+@pytest.mark.parametrize("kernel", ["int8_fq", "int8_mrq_vec", "int4_fq_vec",
+                                    "int4_mrq"])
+def test_fusion_batch_rows_by_index_map_match_select(kernel):
+    """When a batch entry spans whole row tiles (rows_per_batch a multiple
+    of bm, as at DiT-XL/2: 256 tokens, bm 128), the norm-modulate
+    prologue and gate epilogue take each tile's adaLN row from the index
+    map instead of selecting it per row among all entries. Both paths
+    give bit-identical results (bm 96 forces the select path)."""
+    from repro.kernels.int4_packed import (
+        int4_matmul_fq_vec, int4_matmul_mrq_fq,
+    )
+    from repro.kernels.int8_fused import int8_matmul_mrq_fq_vec
+    B, T, K, N, G = 3, 128, 256, 128, 3
+    M = B * T
+    x, wq, sx, zx, scale, corr, bias = _rand_case(M, K, N, G, seed=11)
+    ks = jax.random.split(jax.random.PRNGKey(12), 4)
+    nm = (jax.random.normal(ks[0], (B, K)) * 0.5,
+          jax.random.normal(ks[1], (B, K)) * 0.2)
+    gr = (jax.random.normal(ks[2], (B, N)) * 0.8,
+          jax.random.normal(ks[3], (M, N)))
+    gv = jnp.repeat(jnp.arange(B, dtype=jnp.int32) % G, T)
+    s_neg, s_pos = sx * 0.1, sx
+    if kernel == "int8_fq":
+        call = lambda **kw: int8_matmul_fq(x, wq, sx, zx, scale, corr, bias,
+                                           g=1, **kw)
+    elif kernel == "int8_mrq_vec":
+        call = lambda **kw: int8_matmul_mrq_fq_vec(
+            x, wq, s_neg, s_pos, scale * 0.1, scale, bias, gv=gv, **kw)
+    else:
+        wp, sc4, corr4 = _int4_operands(wq, scale, corr, 128)
+        if kernel == "int4_fq_vec":
+            call = lambda **kw: int4_matmul_fq_vec(
+                x, wp, sx, zx, sc4, corr4, bias, gv=gv, group_k=128, **kw)
+        else:
+            call = lambda **kw: int4_matmul_mrq_fq(
+                x, wp, s_neg, s_pos, sc4 * 0.1, sc4, bias, g=1, group_k=128,
+                **kw)
+    by_map = call(nm=nm, gr=gr, rows_per_batch=T, bm=128, interpret=True)
+    by_select = call(nm=nm, gr=gr, rows_per_batch=T, bm=96, interpret=True)
+    assert bool(jnp.all(jnp.isfinite(by_map)))
+    np.testing.assert_array_equal(np.asarray(by_map), np.asarray(by_select))
+
+
+# ---------------------------------------------------------------------------
 # TGQ packing: group sweep bit-identical to per-group repacking
 # ---------------------------------------------------------------------------
 def _tgq_uniform_qp(key, K, N, G):

@@ -7,12 +7,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_debug_mesh
 from repro.models import ModelCfg, lm_init, lm_apply
 
 
 @pytest.fixture(scope="module")
 def mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_debug_mesh(1, 1)
 
 
 def test_attn_sp_preserves_values(mesh11):

@@ -43,6 +43,20 @@ def test_recording_discovers_ops_and_provenance(dit_setup):
     assert rec.registry["blk0/attn/pv"].kind == "einsum"
 
 
+def test_recording_provenance_ignores_reused_ids():
+    """Eager forwards free arrays and allocate new ones at the same
+    address, so a later tensor can carry a marked tensor's id: the mark
+    must classify only the tensor it was made on."""
+    rec = RecordingContext()
+    probs, q = jnp.ones((2, 2)), jnp.zeros((2, 2))
+    rec.act("probs", probs, "post_softmax")
+    rec._marks[id(q)] = rec._marks[id(probs)]    # q lands on a marked id
+    rec.einsum("qk", "ij,jk->ik", q, q)
+    rec.einsum("pv", "ij,jk->ik", probs, q)
+    assert rec.registry["qk"].a_kind == "plain"
+    assert rec.registry["pv"].a_kind == "post_softmax"
+
+
 def test_fisher_taps_match_finite_difference(dit_setup):
     cfg, p, dif, sched, calib = dit_setup
     loss = dit_loss_fn(p, cfg)

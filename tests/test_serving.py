@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from repro.diffusion import DiffusionCfg, ddpm_sample_paired, make_schedule
+from repro.launch.mesh import make_debug_mesh
 from repro.models import dit_apply
 from repro.quant import QuantRecipe, quantize
 from repro.serving import (
@@ -163,7 +164,7 @@ def test_guidance_one_matches_conditional_sampling(tiny_dit):
 # engine end-to-end
 # ---------------------------------------------------------------------------
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_debug_mesh(1, 1)
 
 
 def test_engine_fp_end_to_end(tiny_dit):
@@ -258,6 +259,7 @@ _SHARDED_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 assert jax.device_count() == 2, jax.device_count()
 from repro.diffusion import DiffusionCfg, make_schedule
+from repro.launch.mesh import make_serving_mesh
 from repro.models import DiTCfg, dit_init
 from repro.quant import QuantRecipe, quantize
 from repro.serving import GenRequest, ServeEngine
@@ -276,7 +278,7 @@ reqs = [GenRequest(request_id=i, label=i % 8, steps=4, cfg_scale=1.5,
                    seed=300 + i) for i in range(4)]
 out = {}
 for nd in (2, 1):
-    mesh = jax.make_mesh((nd, 1), ("data", "model"))
+    mesh = make_serving_mesh(nd)
     eng = ServeEngine.from_artifact(p, art, sched=sched, mesh=mesh,
                                     microbatch=4, step_buckets=(4,))
     out[nd] = eng.serve(reqs)
@@ -317,3 +319,32 @@ def test_modeled_throughput_floor():
         assert qc["req_per_s"] / fp["req_per_s"] >= 1.5
         assert q8["req_per_s"] > qc["req_per_s"]
         assert q8["req_per_s"] / fp["req_per_s"] >= 1.9
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache location
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("env_dir", [None, "/cache/from/env"],
+                         ids=["checkout", "env"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """The serving entry points keep JAX's compile cache where
+    JAX_COMPILATION_CACHE_DIR says (setting nothing over it), and
+    otherwise in one fixed, git-ignored directory of the checkout."""
+    from repro.launch import compile_cache as cc
+    if env_dir is None:
+        monkeypatch.delenv(cc.ENV_VAR, raising=False)
+    else:
+        monkeypatch.setenv(cc.ENV_VAR, env_dir)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        got = cc.enable_compile_cache()
+        now = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    if env_dir is not None:
+        assert got == env_dir and now == prev
+        return
+    root = os.path.join(os.path.dirname(__file__), "..")
+    assert got == now == os.path.join(os.path.realpath(root), ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

@@ -8,12 +8,13 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.distributed import (
     batch_axes, bind_logical, logical_axes, param_specs,
 )
+from repro.launch.mesh import make_debug_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh11():
     # 1x1 mesh works on one CPU device but exercises the rule machinery
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_debug_mesh(1, 1)
 
 
 def test_logical_axes_rules():

@@ -1,0 +1,132 @@
+"""The served kernels compile for a TPU v5e at DiT-XL/2 widths.
+
+Interpret mode (every other kernel test) runs the kernel bodies on the
+CPU and cannot see what the chip's compiler (Mosaic) refuses: MXU
+operand types, block shapes the tiling does not admit, vector ops the
+VPU lacks. These tests compile for a v5e chip that is described, not
+attached, so they need no TPU.
+
+The topology is described inside a fixture and nowhere at import: only
+one process at a time may load the TPU library, and every pytest worker
+imports every test file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attn_mrq import flash_attn_mrq, flash_attn_mrq_vec
+from repro.kernels.int4_packed import int4_matmul_fq_vec
+from repro.kernels.int8_fused import (
+    int8_matmul_fq, int8_matmul_fq_vec, int8_matmul_mrq_fq,
+    int8_matmul_mrq_fq_vec,
+)
+
+# DiT-XL/2: d_model 1152, mlp 4608, 16 heads of 72, 256 tokens; a pool of
+# 4 slots runs 8 CFG rows.
+D, FF, HEADS, HD, TOK = 1152, 4608, 16, 72, 256
+ROWS = 8
+M = ROWS * TOK
+f32, bf16, i32, i8 = jnp.float32, jnp.bfloat16, jnp.int32, jnp.int8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:     # noqa: BLE001 — any failure means: no TPU lib
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described v5e chip, with the persistent compilation cache off:
+    a compile for a chip that is not attached is written to the cache
+    but cannot be read back here."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, *args, **kwargs):
+    """Compile ``fn`` for the described chip; python-int keyword
+    arguments are static, the rest abstract operands."""
+    static = {k: v for k, v in kwargs.items() if isinstance(v, int)}
+    operands = {k: v for k, v in kwargs.items() if k not in static}
+    compiled = jax.jit(functools.partial(fn, out_dtype=bf16, interpret=False,
+                                         **static)
+                       ).lower(*args, **operands).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# (kernel, G, K, N, fusion) — the linears of one DiT block: qkv and fc1
+# take the adaLN norm-modulate prologue, proj and fc2 (MRQ input) the
+# gate+residual epilogue; ada runs on one row per batch entry, unfused.
+# A batch entry owns TOK rows, so each row tile lies in one entry;
+# "nm-rows" gives an entry 64 rows, which makes the kernel select each
+# row's entry in VMEM instead.
+LINEARS = [
+    ("fq", 1, D, 3 * D, "nm"),
+    ("fq", 10, D, 3 * D, "nm"),
+    ("fq", 10, D, 6 * D, None),
+    ("mrq", 10, FF, D, "gr"),
+    ("fq_vec", 10, D, FF, "nm"),
+    ("fq_vec", 10, D, FF, "nm-rows"),
+    ("fq_vec", 10, D, D, "gr"),
+    ("mrq_vec", 10, FF, D, "gr"),
+]
+KERNELS = {"fq": int8_matmul_fq, "mrq": int8_matmul_mrq_fq,
+           "fq_vec": int8_matmul_fq_vec, "mrq_vec": int8_matmul_mrq_fq_vec}
+
+
+@pytest.mark.parametrize("kind,G,K,N,fusion", LINEARS,
+                         ids=[f"{k}-G{g}-{n}-{f}"
+                              for k, g, _, n, f in LINEARS])
+def test_int8_linear_compiles_for_v5e(one_chip, kind, G, K, N, fusion):
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    n = M if fusion else ROWS
+    corr = sds((G, N), f32 if kind.startswith("mrq") else i32)
+    args = (sds((n, K), bf16), sds((K, N), i8), sds((G, 1), f32),
+            sds((G, 1), f32), sds((G, N), f32), corr, sds((N,), f32))
+    kw = ({"gv": sds((n,), i32)} if kind.endswith("_vec")
+          else {"g": sds((), i32)})
+    per_batch = 64 if fusion == "nm-rows" else TOK
+    if fusion in ("nm", "nm-rows"):
+        kw["nm"] = (sds((n // per_batch, K), f32),
+                    sds((n // per_batch, K), f32))
+    elif fusion == "gr":
+        kw["gr"] = (sds((ROWS, N), f32), sds((n, N), f32))
+    if fusion:
+        kw["rows_per_batch"] = per_batch
+    _compile(KERNELS[kind], *args, **kw)
+
+
+def test_int4_linear_vec_compiles_for_v5e(one_chip):
+    """The nibble unpack (packed int4 weights widened to s8 in VMEM)."""
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    G, nk, N = 10, 5, 3 * D                    # K = 1152 pads to 5 x 256
+    _compile(int4_matmul_fq_vec, sds((M, D), bf16), sds((nk * 128, N), i8),
+             sds((G, 1), f32), sds((G, 1), f32), sds((G, nk, N), f32),
+             sds((G, nk, N), i32), sds((N,), f32), gv=sds((M,), i32))
+
+
+@pytest.mark.parametrize("vec", [False, True], ids=["scalar", "vec"])
+def test_flash_attention_compiles_for_v5e(one_chip, vec):
+    """head_dim 72 (not a lane multiple), S = 256, G = 10."""
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    BH, G = ROWS * HEADS, 10
+    qkv = [sds((BH, TOK, HD), bf16) for _ in range(3)]
+    params = [sds((G, 1), f32) for _ in range(7)]
+    g = sds((BH,), i32) if vec else sds((), i32)
+    _compile(flash_attn_mrq_vec if vec else flash_attn_mrq, *qkv, *params,
+             g_qk=g, g_pv=g)
