@@ -63,5 +63,6 @@ def act_mrq(x, s_neg, s_pos, *, bits: int = 8, kind: str = "gelu",
         out_specs=pl.BlockSpec((bm_, bn_), lambda m, n: (m, n)),
         out_shape=jax.ShapeDtypeStruct((Rp, Np), out_dtype),
         interpret=interpret,
+        name="act_mrq",
     )(xm, sn, sp)
     return out[:R, :N].reshape(shape)

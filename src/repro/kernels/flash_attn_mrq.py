@@ -318,6 +318,7 @@ def flash_attn_mrq(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Mp, bd_), out_dtype),
         interpret=interpret,
+        name="flash_attn_mrq",
     )(g, *operands)
     return out[:, :M, :D]
 
@@ -426,5 +427,6 @@ def flash_attn_mrq_vec(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Mp, bd_), out_dtype),
         interpret=interpret,
+        name="flash_attn_mrq_vec",
     )(g, *operands)
     return out[:, :M, :D]

@@ -259,6 +259,7 @@ def int4_matmul_fq(x, wp, sx, zx, scale, corr, bias=None, g=None, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         interpret=interpret,
+        name="int4_matmul_fq",
     )(jnp.asarray(g, jnp.int32).reshape(1), x, wp,
       _stack3(sx.astype(jnp.float32)), _stack3(zx.astype(jnp.float32)),
       scale.reshape(G, nk, 1, Np), corr.reshape(G, nk, 1, Np), bias, *fargs)
@@ -372,6 +373,7 @@ def int4_matmul_mrq_fq(x, wp, s_neg, s_pos, scale_neg, scale_pos, bias=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         interpret=interpret,
+        name="int4_matmul_mrq_fq",
     )(jnp.asarray(g, jnp.int32).reshape(1), x, wp,
       _stack3(s_neg.astype(jnp.float32)), _stack3(s_pos.astype(jnp.float32)),
       scale_neg.reshape(G, nk, 1, Np), scale_pos.reshape(G, nk, 1, Np),
@@ -481,6 +483,7 @@ def int4_matmul_fq_vec(x, wp, sx, zx, scale, corr, bias=None, gv=None, *,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
         interpret=interpret,
+        name="int4_matmul_fq_vec",
     )(gv, x, wp, sx.astype(jnp.float32), zx.astype(jnp.float32),
       scale.reshape(G, nk * Np), corr.reshape(G, nk * Np), bias, *fargs)
     return out[:M, :N]
@@ -584,6 +587,7 @@ def int4_matmul_mrq_fq_vec(x, wp, s_neg, s_pos, scale_neg, scale_pos,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
         interpret=interpret,
+        name="int4_matmul_mrq_fq_vec",
     )(gv, x, wp, s_neg.astype(jnp.float32), s_pos.astype(jnp.float32),
       scale_neg.reshape(G, nk * Np), scale_pos.reshape(G, nk * Np), bias,
       *fargs)

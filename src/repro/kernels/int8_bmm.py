@@ -151,6 +151,7 @@ def int8_bmm_qk(q, k, s_q, s_k, scale, g=None, *, bits=8, bm=DEFAULT_BM,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Mp, Np), out_dtype),
         interpret=interpret,
+        name="int8_bmm_qk",
     )(jnp.asarray(g, jnp.int32).reshape(1), q, k,
       _stack3(s_q.astype(jnp.float32)), _stack3(s_k.astype(jnp.float32)),
       _stack3(scale.astype(jnp.float32)))
@@ -250,6 +251,7 @@ def int8_bmm_pv(codes, v, s_v, scale1, scale2, g=None, *, bits=8,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Mp, Dp), out_dtype),
         interpret=interpret,
+        name="int8_bmm_pv",
     )(jnp.asarray(g, jnp.int32).reshape(1), codes, v,
       _stack3(s_v.astype(jnp.float32)), _stack3(scale1.astype(jnp.float32)),
       _stack3(scale2.astype(jnp.float32)))
@@ -309,6 +311,7 @@ def int8_bmm_qk_vec(q, k, s_q, s_k, scale, gv=None, *, bits=8, bm=DEFAULT_BM,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Mp, Np), out_dtype),
         interpret=interpret,
+        name="int8_bmm_qk_vec",
     )(gv, q, k, _stack3(s_q.astype(jnp.float32)),
       _stack3(s_k.astype(jnp.float32)), _stack3(scale.astype(jnp.float32)))
     return out[:, :M, :N]
@@ -359,6 +362,7 @@ def int8_bmm_pv_vec(codes, v, s_v, scale1, scale2, gv=None, *, bits=8,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Mp, Dp), out_dtype),
         interpret=interpret,
+        name="int8_bmm_pv_vec",
     )(gv, codes, v, _stack3(s_v.astype(jnp.float32)),
       _stack3(scale1.astype(jnp.float32)), _stack3(scale2.astype(jnp.float32)))
     return out[:, :M, :D]

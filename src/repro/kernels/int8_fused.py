@@ -367,6 +367,7 @@ def int8_matmul_fq(x, wq, sx, zx, scale, corr, bias=None, g=None, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         interpret=interpret,
+        name="int8_matmul_fq",
     )(jnp.asarray(g, jnp.int32).reshape(1), x, wq,
       _stack3(sx.astype(jnp.float32)), _stack3(zx.astype(jnp.float32)),
       _stack3(scale), _stack3(corr), bias, *fargs)
@@ -492,6 +493,7 @@ def int8_matmul_mrq_fq(x, wq, s_neg, s_pos, scale_neg, scale_pos, bias=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         interpret=interpret,
+        name="int8_matmul_mrq_fq",
     )(jnp.asarray(g, jnp.int32).reshape(1), x, wq,
       _stack3(s_neg.astype(jnp.float32)), _stack3(s_pos.astype(jnp.float32)),
       _stack3(scale_neg), _stack3(scale_pos), bias, *fargs)
@@ -599,6 +601,7 @@ def int8_matmul_fq_vec(x, wq, sx, zx, scale, corr, bias=None, gv=None, *,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
         interpret=interpret,
+        name="int8_matmul_fq_vec",
     )(gv, x, wq, sx.astype(jnp.float32), zx.astype(jnp.float32),
       scale, corr, bias, *fargs)
     return out[:M, :N]
@@ -703,6 +706,7 @@ def int8_matmul_mrq_fq_vec(x, wq, s_neg, s_pos, scale_neg, scale_pos,
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32),
                         pltpu.VMEM((bm_, bn_), jnp.int32)],
         interpret=interpret,
+        name="int8_matmul_mrq_fq_vec",
     )(gv, x, wq, s_neg.astype(jnp.float32), s_pos.astype(jnp.float32),
       scale_neg, scale_pos, bias, *fargs)
     return out[:M, :N]

@@ -90,6 +90,7 @@ def int8_matmul(xq, wq, scale, corr, bias=None, *, bm=DEFAULT_BM,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
         interpret=interpret,
+        name="int8_matmul",
     )(xq, wq, scale, corr, bias)
     return out[:M, :N]
 

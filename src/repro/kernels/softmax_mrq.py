@@ -87,6 +87,7 @@ def softmax_mrq(scores, s1, *, bits: int = 8, br: int = 256,
         out_specs=pl.BlockSpec((br_, C), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, C), out_dtype),
         interpret=interpret,
+        name="softmax_mrq",
     )(x, s1)
     return out[:R].reshape(shape)
 
@@ -147,6 +148,7 @@ def softmax_mrq_codes(scores, s1, g=None, *, bits: int = 8, br: int = 256,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Rp, C), jnp.int8),
         interpret=interpret,
+        name="softmax_mrq_codes",
     )(jnp.asarray(g, jnp.int32).reshape(1), x, _stack3(s1.astype(jnp.float32)))
     return out[:R].reshape(shape)
 
@@ -207,5 +209,6 @@ def softmax_mrq_codes_vec(scores, s1, gv=None, *, bits: int = 8,
         out_specs=pl.BlockSpec((br_, C), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, C), jnp.int8),
         interpret=interpret,
+        name="softmax_mrq_codes_vec",
     )(gv, x, s1.astype(jnp.float32))
     return out[:R].reshape(shape)

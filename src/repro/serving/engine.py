@@ -31,6 +31,7 @@ anyway.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 import warnings
 from collections import deque
@@ -65,6 +66,10 @@ def _ctx_arrays(ctx, mesh: Optional[Mesh]):
     if mesh is not None:
         arrays = jax.device_put(arrays, replicated(mesh))
     return arrays, rebuild
+
+
+# the pump's phases timed into ``AsyncServeEngine.stats["phase_s"]``
+PHASES = ("admit", "dispatch", "wait", "resolve", "pull")
 
 
 class ServeEngine:
@@ -275,6 +280,17 @@ class AsyncServeEngine:
     arrays (positions + bad flags) — the full latent is pulled once per
     request, at completion. ``clock`` is injectable
     (``faults.FakeClock``) so deadline tests never sleep.
+
+    Tracing: each pump opens ``jax.profiler.TraceAnnotation`` spans,
+    ``engine.pump`` around ``engine.admit``, ``engine.dispatch`` (with
+    ``engine.compile`` and the blocking reads, ``engine.wait``) and
+    ``engine.resolve`` (with ``engine.pull``, tagged ``request_id``).
+    They land in a ``jax.profiler`` trace on the device's clock; with no
+    trace running each is an inactive TraceMe. ``stats`` counts
+    ``chunk_runs`` (every call of the chunk executable), ``drained``
+    (dispatch-ahead chunks run and thrown away) and ``live_slot_steps``
+    (steps the consumed chunks advanced for live, unpoisoned requests),
+    and keeps ``[total_s, longest_s]`` per phase in ``phase_s``.
     """
 
     # a freed slot parks at pos >= every bucket length: bucket 0, pos n_max
@@ -337,8 +353,9 @@ class AsyncServeEngine:
 
         self.stats: Dict[str, Any] = {
             "dispatches": 0, "chunk_traces": 0, "compile_s": 0.0,
-            "degradations": [], "admitted": 0, "completed": 0, "failed": 0,
-            "rejected": 0, "cancelled": 0, "retries": 0, "queue_peak": 0,
+            "degradations": [], "admitted": 0, "rejected": 0, "retries": 0,
+            "queue_peak": 0, "chunk_runs": 0, "drained": 0,
+            "live_slot_steps": 0, "phase_s": {p: [0.0, 0.0] for p in PHASES},
         }
         self._pending = None            # dispatch-ahead in-flight chunk
         self._chunk_fn = self._build_chunk()
@@ -404,13 +421,37 @@ class AsyncServeEngine:
         calls this compiled executable, so no compile happens inside the
         ladder."""
         t0 = time.perf_counter()
-        self._chunk_exec = self._chunk_fn.lower(
-            *self._chunk_args(self._x, self._pos)).compile()
+        with self._span("compile"):
+            self._chunk_exec = self._chunk_fn.lower(
+                *self._chunk_args(self._x, self._pos)).compile()
         self.stats["compile_s"] += time.perf_counter() - t0
 
     def _chunk_args(self, x, pos):
         return (self.params, self._qargs, x, pos, self._bk, self._y,
                 self._seeds, self._gs)
+
+    def _run_chunk(self, x, pos):
+        """Enqueue one run of the compiled chunk executable on ``(x,
+        pos)`` and the pool's other slot state."""
+        out = self._chunk_exec(*self._chunk_args(x, pos))
+        self.stats["chunk_runs"] += 1
+        return out
+
+    @contextlib.contextmanager
+    def _span(self, phase: str, **tags):
+        """``engine.<phase>`` as a profiler span (``tags`` become its
+        metadata); a phase in ``PHASES`` also adds its seconds to
+        ``stats["phase_s"][phase]`` as ``[total_s, longest_s]``."""
+        acc = self.stats["phase_s"].get(phase)
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(f"engine.{phase}", **tags):
+                yield
+        finally:
+            if acc is not None:
+                dt = time.perf_counter() - t0
+                acc[0] += dt
+                acc[1] = max(acc[1], dt)
 
     # -- admission ----------------------------------------------------------
     def _reject(self, req: GenRequest, code: str, message: str) -> int:
@@ -529,9 +570,6 @@ class AsyncServeEngine:
             rec.slot = None
         self.outcomes[rec.request.request_id] = lc.outcome_of(
             rec, sample, now)
-        key = {lc.OK: "completed", lc.FAILED: "failed",
-               lc.CANCELLED: "cancelled"}[status]
-        self.stats[key] += 1
 
     def _admit(self) -> None:
         free = self._free_slots()
@@ -568,8 +606,11 @@ class AsyncServeEngine:
     def _drain_pipeline(self) -> None:
         """Discard any dispatch-ahead chunk: its inputs no longer match
         the slot pool (admission, release, quarantine reset, or a
-        degradation rebuilt the executable)."""
-        self._pending = None
+        degradation rebuilt the executable). The device runs a dropped
+        chunk all the same; ``stats["drained"]`` counts them."""
+        if self._pending is not None:
+            self.stats["drained"] += 1
+            self._pending = None
 
     def _dispatch(self):
         """One chunk dispatch with the degradation ladder and dispatch-ahead
@@ -596,17 +637,16 @@ class AsyncServeEngine:
                     x, pos, bad = self._pending
                     self._pending = None
                 else:
-                    x, pos, bad = self._chunk_exec(
-                        *self._chunk_args(self._x, self._pos))
+                    x, pos, bad = self._run_chunk(self._x, self._pos)
                 if self.pipeline >= 2:
                     # dispatch-ahead: enqueue the next chunk on the async
                     # dispatch queue now; pump() drains it if this chunk's
                     # boundary mutates any slot
-                    self._pending = self._chunk_exec(
-                        *self._chunk_args(x, pos))
+                    self._pending = self._run_chunk(x, pos)
                 # block on the SMALL outputs only; x stays device-resident
-                pos_h = np.array(pos)      # writable copy: retries reset it
-                bad_h = np.array(bad)
+                with self._span("wait"):
+                    pos_h = np.array(pos)  # writable copy: retries reset it
+                    bad_h = np.array(bad)
                 return x, pos_h, bad_h
             except Exception as e:            # noqa: BLE001 — ladder seam
                 self._drain_pipeline()
@@ -628,10 +668,20 @@ class AsyncServeEngine:
         """One engine cycle: admit -> dispatch one chunk -> resolve slots.
         Returns False when there was nothing to do (pool empty and queue
         empty after admission)."""
-        self._admit()
-        if self.active == 0:
-            return False
-        x, pos_h, bad_h = self._dispatch()
+        with self._span("pump"):
+            with self._span("admit"):
+                self._admit()
+            if self.active == 0:
+                return False
+            with self._span("dispatch"):
+                x, pos_h, bad_h = self._dispatch()
+            with self._span("resolve"):
+                self._resolve(x, pos_h, bad_h)
+            return True
+
+    def _resolve(self, x, pos_h, bad_h) -> None:
+        """The chunk boundary: finish, cancel or quarantine each live
+        slot from the chunk's outputs, then take them as the pool's state."""
         didx = self.stats["dispatches"]
         now = self._clock()
 
@@ -673,9 +723,11 @@ class AsyncServeEngine:
                     jnp.uint32(rec.request.seed), jnp.int32(n)))
                 pos_h[slot] = 0
                 continue
+            self.stats["live_slot_steps"] += min(p_after, n) - p_before
             if p_after >= n:                      # finished: the ONE place
                 self._x = x                       # the full latent leaves
-                sample = np.asarray(self._x[slot])     # the device
+                with self._span("pull", request_id=rid):  # the device
+                    sample = np.asarray(self._x[slot])
                 self._finish(rec, lc.OK, sample)
                 x = self._x
                 continue
@@ -700,7 +752,6 @@ class AsyncServeEngine:
         for slot, rid in enumerate(self._slot_rid):
             if rid is not None:
                 self._pos_host[slot] = int(pos_h[slot])
-        return True
 
     def run_until_drained(self, max_pumps: int = 100_000
                           ) -> Dict[int, lc.RequestOutcome]:

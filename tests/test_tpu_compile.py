@@ -12,6 +12,7 @@ imports every test file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,13 +61,19 @@ def one_chip(topo):
 
 def _compile(fn, *args, **kwargs):
     """Compile ``fn`` for the described chip; python-int keyword
-    arguments are static, the rest abstract operands."""
+    arguments are static, the rest abstract operands. The kernel's own
+    ``jax.jit`` is unwrapped, so the Pallas call's instruction name must
+    come from its ``name=`` and not from the wrapper (the benchmark tells
+    kernels apart by that name in the trace). Returns the instruction
+    names of the compiled program's Pallas calls, without their ids."""
     static = {k: v for k, v in kwargs.items() if isinstance(v, int)}
     operands = {k: v for k, v in kwargs.items() if k not in static}
-    compiled = jax.jit(functools.partial(fn, out_dtype=bf16, interpret=False,
-                                         **static)
+    compiled = jax.jit(functools.partial(fn.__wrapped__, out_dtype=bf16,
+                                         interpret=False, **static)
                        ).lower(*args, **operands).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    return set(re.findall(r"%([\w\-]+?)(?:\.\d+)? = [^\n]*"
+                          r'custom_call_target="tpu_custom_call"',
+                          compiled.as_text()))
 
 
 # (kernel, G, K, N, fusion) — the linears of one DiT block: qkv and fc1
@@ -87,6 +94,8 @@ LINEARS = [
 ]
 KERNELS = {"fq": int8_matmul_fq, "mrq": int8_matmul_mrq_fq,
            "fq_vec": int8_matmul_fq_vec, "mrq_vec": int8_matmul_mrq_fq_vec}
+NAMES = {"fq": "int8_matmul_fq", "mrq": "int8_matmul_mrq_fq",
+         "fq_vec": "int8_matmul_fq_vec", "mrq_vec": "int8_matmul_mrq_fq_vec"}
 
 
 @pytest.mark.parametrize("kind,G,K,N,fusion", LINEARS,
@@ -108,16 +117,18 @@ def test_int8_linear_compiles_for_v5e(one_chip, kind, G, K, N, fusion):
         kw["gr"] = (sds((ROWS, N), f32), sds((n, N), f32))
     if fusion:
         kw["rows_per_batch"] = per_batch
-    _compile(KERNELS[kind], *args, **kw)
+    assert _compile(KERNELS[kind], *args, **kw) == {NAMES[kind]}
 
 
 def test_int4_linear_vec_compiles_for_v5e(one_chip):
     """The nibble unpack (packed int4 weights widened to s8 in VMEM)."""
     sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
     G, nk, N = 10, 5, 3 * D                    # K = 1152 pads to 5 x 256
-    _compile(int4_matmul_fq_vec, sds((M, D), bf16), sds((nk * 128, N), i8),
-             sds((G, 1), f32), sds((G, 1), f32), sds((G, nk, N), f32),
-             sds((G, nk, N), i32), sds((N,), f32), gv=sds((M,), i32))
+    names = _compile(int4_matmul_fq_vec, sds((M, D), bf16),
+                     sds((nk * 128, N), i8), sds((G, 1), f32),
+                     sds((G, 1), f32), sds((G, nk, N), f32),
+                     sds((G, nk, N), i32), sds((N,), f32), gv=sds((M,), i32))
+    assert names == {"int4_matmul_fq_vec"}
 
 
 @pytest.mark.parametrize("vec", [False, True], ids=["scalar", "vec"])
@@ -128,5 +139,6 @@ def test_flash_attention_compiles_for_v5e(one_chip, vec):
     qkv = [sds((BH, TOK, HD), bf16) for _ in range(3)]
     params = [sds((G, 1), f32) for _ in range(7)]
     g = sds((BH,), i32) if vec else sds((), i32)
-    _compile(flash_attn_mrq_vec if vec else flash_attn_mrq, *qkv, *params,
-             g_qk=g, g_pv=g)
+    names = _compile(flash_attn_mrq_vec if vec else flash_attn_mrq, *qkv,
+                     *params, g_qk=g, g_pv=g)
+    assert names == {"flash_attn_mrq_vec" if vec else "flash_attn_mrq"}
