@@ -3,10 +3,10 @@
 Two kernels replace the old quantize -> int8_matmul -> (add) chain:
 
 ``int8_matmul_fq``
-    Takes FP activations and quantizes each (bm, bk) tile **in VMEM**
-    immediately before it is fed to the MXU — the standalone
-    ``quantize_int8`` pass (an extra fp32 read + int8 write of the full
-    activation through HBM) disappears. The epilogue applies the
+    Takes FP activations and quantizes each row block **in VMEM**, once,
+    before it is fed to the MXU — the standalone ``quantize_int8`` pass
+    (an extra fp32 read + int8 write of the full activation through HBM)
+    disappears. The epilogue applies the
     zero-point correction, the combined per-output-channel scale and the
     bias, so the FP result is written to HBM exactly once.
 
@@ -15,9 +15,9 @@ Two kernels replace the old quantize -> int8_matmul -> (add) chain:
     uniform) input quantizer. The old path ran TWO full int8 matmuls
     (negative-region codes, positive-region codes) — 2x weight HBM
     traffic plus two (M, N) fp32 intermediates and an add. Here each
-    weight tile is read once; the sign mask splits the activation tile
-    into the two region codes in VMEM and feeds TWO s32 accumulators,
-    each epilogued with its region scale. Weight traffic halves and the
+    weight tile is read once; the sign mask splits the activation block
+    into the two region codes in VMEM and feeds TWO s32 dots, each
+    epilogued with its region scale. Weight traffic halves and the
     intermediates never exist.
 
 TGQ (time-grouped quantization, the paper's §III-A) lives *inside* the
@@ -73,24 +73,32 @@ and leave the original kernels byte-for-byte unchanged), and all three
 compose with both the scalar-prefetch and vector-tgroup group gathers —
 the DDPM scan still compiles ONCE with fusions active.
 
-Tiling matches ``int8_matmul``: grid (M/bm, N/bn, K/bk), k innermost,
-MXU-aligned blocks, s32 accumulator(s) in VMEM scratch. Non-aligned
-shapes are zero-padded; padded K columns of x quantize to the zero
-point but meet zero-padded weight rows, so they contribute nothing
-(fusion operands pad inertly too: shift/scale with 0, prescale with 1).
+Tiling (``_fq_plan``, from the shapes alone): grid (M/bm, N/bn, 1) with
+n innermost and ONE k step — the x block is (bm, K), the whole
+contraction. Its index map ``(m, 0)`` does not move while n advances, so
+each row block is fetched from HBM once (the next one is prefetched
+during the last n step) and quantized once, at n == 0, into an int8
+VMEM scratch (two for MRQ) that every n step of that row block reuses;
+the (K, bn) weight blocks stream per n. The quantize runs over 32-row
+slices of the block, so its f32 temporaries stay small at any K. A
+single k step leaves the s32 dot the same integer sum as a k-tiled one,
+so outputs are bit-identical to it. x enters in its stored dtype (the
+f32 upcast happens in VMEM, exactly) and nothing is padded along K; M
+and N are zero-padded only where the planned block does not divide
+them (fusion operands pad inertly too: shift/scale with 0, prescale
+with 1), and padded rows and columns are sliced away.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.int8_matmul import (
-    DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, _ceil, _group_param, _pad_to, _stack3,
-)
+from repro.kernels.int8_matmul import _ceil, _group_param, _pad_to, _stack3
 
 
 # ---------------------------------------------------------------------------
@@ -241,62 +249,332 @@ def _fusions(x, ps, nm, gr, rows_per_batch, *, has_g: bool, M, K, N, Mp,
     return specs, args, flags
 
 
-def _fq_kernel(g_ref, *refs, nk: int, half: int, **fusions):
-    """Grid body for ``int8_matmul_fq`` at grid point (m, n, k).
+# ---------------------------------------------------------------------------
+# tiling plan (shared by the four int8 fused kernels)
+# ---------------------------------------------------------------------------
+_ROW_TILES = (512, 256, 128, 64, 32, 16, 8)
+_QUANT_ROWS = 32               # x rows per quantize slice (an s8 sublane tile)
+_VMEM_BUDGET = 64 * 2 ** 20    # planned working set of one call
+_VMEM_DEFAULT = 16 * 2 ** 20   # the compiler's default scoped VMEM limit
+_VMEM_MAX = 96 * 2 ** 20       # of the v5e's 128 MiB
 
-    Refs arrive as VMEM tiles already gathered by the BlockSpec index
-    maps: x (bm, bk) fp32, w (bk, bn) int8, and the TGQ-resolved rows of
-    the activation-side params — sx/zx (1, 1) and scale/corr (1, bn) are
-    the group-``g`` slices of the stacked (G, ·) arrays (see the
-    ``(g[0], 0, n)`` index maps below), so the body itself is
-    group-agnostic. Both dot operands are s8 (the MXU multiplies s8 x s8
-    into s32; it takes no s32 operands).
-    ``acc_ref`` is a persistent (bm, bn) s32 scratch: zeroed at k == 0,
-    accumulated over the K-traversal (k innermost), epilogued at
-    k == nk - 1. ``g_ref`` itself is unused here — prefetched scalars
-    exist to feed index maps. Optional fusion refs follow ``bias``
-    (``_unpack_fusion_refs`` order); absent fusions leave the body
-    identical to the unfused original.
+
+class FqPlan(NamedTuple):
+    """Tiles and VMEM of one fused int8 call (``_fq_plan``)."""
+    bm: int                    # x rows per block (the grid's m tile)
+    bn: int                    # weight columns per block (the n tile)
+    rows: int                  # x rows per quantize slice
+    Mp: int                    # M, N padded to the blocks
+    Np: int
+    grid: tuple
+    vmem_bytes: int            # planned working set
+    vmem_limit_bytes: int      # passed to the compiler
+    quant_passes: int          # times each x element is quantized
+
+    @property
+    def steps(self) -> int:
+        return self.grid[0] * self.grid[1] * self.grid[2]
+
+
+def _fq_vmem_bytes(bm, bn, K, rows, x_itemsize, mrq):
+    """Planned VMEM working set of one call: double-buffered x, W, output
+    and residual blocks, the code scratch, the epilogue's (bm, bn)
+    values, one quantize slice's f32 temporaries and the lane-padded
+    per-row columns (gv, mu, 1/sigma, row->batch), with 2 MiB for the
+    small param blocks."""
+    codes = 2 if mrq else 1
+    return (2 * bm * K * x_itemsize + codes * bm * K + 2 * K * bn
+            + 4 * bm * bn * 4 + (codes + 3) * bm * bn * 4
+            + 4 * rows * K * 4 + 8 * bm * 128 * 4 + 2 * 2 ** 20)
+
+
+def _col_tiles(N):
+    """Weight-block widths to try, widest first: the whole of N, then the
+    multiples of 128 that divide it; a ragged N that does not fit whole
+    pads to 128-multiples."""
+    tiles = [N] + [b for b in range(N - N % 128, 0, -128)
+                   if N % b == 0 and b < N]
+    return tiles + [512, 256, 128] if N % 128 else tiles
+
+
+def _fq_plan(M, K, N, *, x_itemsize, mrq, rows_per_batch=None, bm=None,
+             bn=None) -> FqPlan:
+    """Tiles of a fused int8 call from its shapes (``bm``/``bn`` override).
+
+    bm: one block for M <= 512 (rounded up to 8); above that, with a
+    per-batch fusion (``rows_per_batch``), the largest row tile up to 512
+    that divides a batch entry's rows, so the adaLN rows come from the
+    index maps, else the largest of 512/256/128 dividing M. At bm >= 256
+    each weight byte streamed feeds 2*bm >= 512 operations, above the
+    v5e's int8 ridge (393 TOP/s over 819 GB/s, about 480), so streaming W
+    once per row block does not bind.
+    bn: the widest of ``_col_tiles(N)`` whose working set fits
+    ``_VMEM_BUDGET`` (the next bm otherwise). Wider is faster: fewer grid
+    steps, and at bn = N the weight block never moves, so W is read once
+    a call.
     """
-    del g_ref  # consumed by the index maps (per-group row gather)
+    if bm is not None:
+        bms = [min(bm, _ceil(M))]
+    elif M <= _ROW_TILES[0]:
+        bms = [_ceil(M)]
+    elif rows_per_batch and any(rows_per_batch % b == 0 for b in _ROW_TILES):
+        bms = [b for b in _ROW_TILES if rows_per_batch % b == 0]
+    else:
+        bms = [b for b in _ROW_TILES[:3] if M % b == 0] or \
+            list(_ROW_TILES[:3])
+    bns = [min(bn, _ceil(N))] if bn is not None else _col_tiles(N)
+
+    def rows_of(b):
+        return _QUANT_ROWS if b % _QUANT_ROWS == 0 else b
+
+    def need(b, c):
+        return _fq_vmem_bytes(b, c, K, rows_of(b), x_itemsize, mrq)
+
+    options = [(b, c) for b in bms for c in bns]
+    bm_, bn_ = next((o for o in options if need(*o) <= _VMEM_BUDGET),
+                    options[-1])
+    Mp, Np = _pad_to(M, bm_), _pad_to(N, bn_)
+    grid = (Mp // bm_, Np // bn_, 1)
+    vmem = need(bm_, bn_)
+    limit = min(max(_VMEM_DEFAULT, -(-3 * vmem // 2 // 2 ** 20) * 2 ** 20),
+                _VMEM_MAX)
+    # each row block is quantized at its first n step only
+    quant_passes = grid[0] * bm_ // Mp
+    return FqPlan(bm_, bn_, rows_of(bm_), Mp, Np, grid, vmem, limit,
+                  quant_passes)
+
+
+# ---------------------------------------------------------------------------
+# kernel bodies: quantize the row block once, then one dot per n step
+# ---------------------------------------------------------------------------
+def _by_rows(bm, rows, body):
+    """Run ``body(rs)`` over the row slices ``rs`` of a (bm, ·) block."""
+    if rows == bm:
+        body(pl.ds(0, bm))
+        return
+
+    def step(i, carry):
+        body(pl.ds(pl.multiple_of(i * rows, rows), rows))
+        return carry
+    jax.lax.fori_loop(0, bm // rows, step, 0)
+
+
+def _row_params(grp_ref, rs, refs):
+    """Activation-side params for the rows ``rs``: with a per-row group
+    vector (``grp_ref`` the (bm, 1) gv block, ``rs`` None for the whole
+    block) each row's group row of the full (G, ·) stacks, gathered by
+    ``_gather_rows``; on the scalar-prefetch path (``grp_ref`` None) the
+    group-g row the index map already fetched."""
+    if grp_ref is None:
+        return [r[...] for r in refs]
+    gv = grp_ref[...] if rs is None else grp_ref[rs]
+    return [_gather_rows(gv, r[...]) for r in refs]
+
+
+def _row_fusion_refs(rs, bv_ref, mu_ref, rsig_ref):
+    """The per-row fusion refs (row->batch, mu, 1/sigma) cut to ``rs``."""
+    return [None if r is None else r.at[rs] for r in (bv_ref, mu_ref,
+                                                      rsig_ref)]
+
+
+_DOT = (((1,), (0,)), ((), ()))
+
+
+def _fq_kernel(grp_ref, *refs, vec: bool, half: int, rows: int, **fusions):
+    """Grid body of ``int8_matmul_fq`` / ``int8_matmul_fq_vec`` at (m, n, 0).
+
+    Refs arrive as VMEM blocks placed by the index maps: x (bm, K) in its
+    stored dtype, w (K, bn) int8, then the activation-side params — on
+    the scalar-prefetch path (``vec`` False; ``grp_ref`` is the
+    prefetched g, consumed by the index maps) sx/zx (1, 1) and
+    scale/corr (1, bn), the group-g rows of the stacked (G, ·) arrays; on
+    the vector-tgroup path ``grp_ref`` is the (bm, 1) per-row group block
+    and the full (G, ·) stacks ride along, each row gathering its own
+    group's params (``_row_params``). Optional fusion refs follow
+    ``bias`` (``_unpack_fusion_refs`` order); ``xq_ref`` is the (bm, K)
+    int8 code scratch.
+
+    At n == 0 the fusion prologue and the quantize (the byte range is
+    [-half, half-1]: 8-bit uses the full s8 range, 6-bit codes live in
+    [-32, 31] inside the same bytes) fill ``xq_ref``; every n step then
+    runs one s8 x s8 -> s32 MXU dot on it (the MXU takes no s32
+    operands) and the epilogue: zero-point correction, combined scale,
+    bias, gate+residual, one HBM write.
+    """
     x_ref, w_ref, sx_ref, zx_ref, scale_ref, corr_ref, bias_ref = refs[:7]
-    o_ref, acc_ref = refs[-2], refs[-1]
+    o_ref, xq_ref = refs[-2], refs[-1]
     ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
         _unpack_fusion_refs(refs[7:-2], **fusions)
-    k = pl.program_id(2)
+    gv_ref = grp_ref if vec else None
 
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    @pl.when(pl.program_id(1) == 0)
+    def _quantize():
+        def slice_(rs):
+            bv, mu, rsig = _row_fusion_refs(rs, bv_ref, mu_ref, rsig_ref)
+            xf = _fusion_prologue(x_ref[rs].astype(jnp.float32), ps_ref, bv,
+                                  mu, rsig, sh_ref, sc_ref)
+            sx, zx = _row_params(gv_ref, rs, (sx_ref, zx_ref))
+            xq_ref[rs] = jnp.clip(jnp.round(xf / sx) + zx - half,
+                                  -half, half - 1).astype(jnp.int8)
+        _by_rows(xq_ref.shape[0], rows, slice_)
 
-    # fused-quantize prologue: fp tile -> signed codes in VMEM (the byte
-    # range is [-half, half-1] — 8-bit uses the full s8 range, 6-bit
-    # codes live in [-32, 31] inside the same int8 bytes)
-    sx = sx_ref[0, 0]
-    zx = zx_ref[0, 0]
-    xf = _fusion_prologue(x_ref[...].astype(jnp.float32), ps_ref, bv_ref,
-                          mu_ref, rsig_ref, sh_ref, sc_ref)
-    xq = jnp.clip(jnp.round(xf / sx) + zx - half,
-                  -half, half - 1).astype(jnp.int8)
-    acc_ref[...] += jax.lax.dot_general(
-        xq, w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        acc = acc_ref[...] - corr_ref[...]
-        y = acc.astype(jnp.float32) * scale_ref[...] + bias_ref[...]
-        y = _fusion_epilogue(y, bv_ref, gate_ref, res_ref)
-        o_ref[...] = y.astype(o_ref.dtype)
+    acc = jax.lax.dot_general(xq_ref[...], w_ref[...], _DOT,
+                              preferred_element_type=jnp.int32)
+    scale, corr = _row_params(gv_ref, None, (scale_ref, corr_ref))
+    y = (acc - corr).astype(jnp.float32) * scale + bias_ref[...]
+    y = _fusion_epilogue(y, bv_ref, gate_ref, res_ref)
+    o_ref[...] = y.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "bm", "bn", "bk",
-                                             "rows_per_batch", "out_dtype",
-                                             "interpret"))
+def _mrq_kernel(grp_ref, *refs, vec: bool, half: int, rows: int,
+                **fusions):
+    """Grid body of ``int8_matmul_mrq_fq`` / ``int8_matmul_mrq_fq_vec``.
+
+    The ``_fq_kernel`` contract with the MRQ twin-region structure: at
+    n == 0 the prologue's f32 values are split by sign into two DISJOINT
+    int8 code blocks (each element is zero in exactly one), ``qn_ref``
+    and ``qp_ref``; every n step multiplies both against the SAME weight
+    block — one VMEM-resident W read feeding two s32 dots — and the
+    epilogue recombines them with their per-region scales. That is what
+    collapses the old two-matmul MRQ deployment into a single W
+    traversal. The fusion prologue (norm-modulate, prescale) runs BEFORE
+    the sign split, so the region selection sees the same values the
+    fake-quant path would.
+    """
+    x_ref, w_ref, sn_ref, sp_ref, scale_n_ref, scale_p_ref, bias_ref = \
+        refs[:7]
+    o_ref, qn_ref, qp_ref = refs[-3], refs[-2], refs[-1]
+    ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
+        _unpack_fusion_refs(refs[7:-3], **fusions)
+    gv_ref = grp_ref if vec else None
+
+    @pl.when(pl.program_id(1) == 0)
+    def _quantize():
+        def slice_(rs):
+            bv, mu, rsig = _row_fusion_refs(rs, bv_ref, mu_ref, rsig_ref)
+            xf = _fusion_prologue(x_ref[rs].astype(jnp.float32), ps_ref, bv,
+                                  mu, rsig, sh_ref, sc_ref)
+            sn, sp = _row_params(gv_ref, rs, (sn_ref, sp_ref))
+            neg = xf < 0
+            qn_ref[rs] = jnp.where(neg, jnp.clip(jnp.round(xf / sn), -half, 0),
+                                   0).astype(jnp.int8)
+            qp_ref[rs] = jnp.where(neg, 0, jnp.clip(jnp.round(xf / sp), 0,
+                                                    half - 1)).astype(jnp.int8)
+        _by_rows(qn_ref.shape[0], rows, slice_)
+
+    w = w_ref[...]                            # ONE weight-block read, two dots
+    acc_n = jax.lax.dot_general(qn_ref[...], w, _DOT,
+                                preferred_element_type=jnp.int32)
+    acc_p = jax.lax.dot_general(qp_ref[...], w, _DOT,
+                                preferred_element_type=jnp.int32)
+    scale_n, scale_p = _row_params(gv_ref, None, (scale_n_ref, scale_p_ref))
+    y = (acc_n.astype(jnp.float32) * scale_n
+         + acc_p.astype(jnp.float32) * scale_p + bias_ref[...])
+    y = _fusion_epilogue(y, bv_ref, gate_ref, res_ref)
+    o_ref[...] = y.astype(o_ref.dtype)
+
+
+def _fused_call(kernel, name, x, wq, row_params, col_params, bias, grp, *,
+                vec, mrq, half, ps, nm, gr, rows_per_batch, bm, bn,
+                out_dtype, interpret):
+    """Plan, pad and launch one fused int8 call.
+
+    ``row_params``: the two (G, 1) quantizer stacks (sx/zx, or the MRQ
+    region steps); ``col_params``: the two (G, N) epilogue stacks
+    (scale/corr, or the MRQ region scales), already in their dtypes.
+    ``grp``: the scalar group g (``vec`` False, scalar-prefetched and
+    gathered by the index maps) or the (M,) per-row group vector.
+    """
+    M, K = x.shape
+    K2, N = wq.shape
+    assert K == K2, (x.shape, wq.shape)
+    G = col_params[0].shape[0]
+    per_batch = nm is not None or gr is not None
+    plan = _fq_plan(M, K, N, x_itemsize=jnp.dtype(x.dtype).itemsize,
+                    mrq=mrq, rows_per_batch=rows_per_batch if per_batch
+                    else None, bm=bm, bn=bn)
+    bm_, bn_, Mp, Np = plan.bm, plan.bn, plan.Mp, plan.Np
+
+    if bias is None:
+        bias = jnp.zeros((N,), jnp.float32)
+    fspecs, fargs, fusions = _fusions(
+        x, ps, nm, gr, rows_per_batch, has_g=not vec, M=M, K=K, N=N, Mp=Mp,
+        Kp=K, Np=Np, bm_=bm_, bk_=K, bn_=bn_)
+    if Mp > M:
+        x = jnp.pad(x, ((0, Mp - M), (0, 0)))
+    if Np > N:
+        wq = jnp.pad(wq, ((0, 0), (0, Np - N)))
+    row_params = [p.astype(jnp.float32) for p in row_params]
+    col_params = [jnp.pad(p, ((0, 0), (0, Np - N))) for p in col_params]
+    bias = jnp.pad(bias.astype(jnp.float32), (0, Np - N)).reshape(1, Np)
+    out_spec = (bm_, bn_)
+    scratch = [pltpu.VMEM((bm_, K), jnp.int8)] * (2 if mrq else 1)
+
+    if vec:
+        gv = jnp.pad(jnp.asarray(grp, jnp.int32), (0, Mp - M)).reshape(Mp, 1)
+        grid_spec = pl.GridSpec(
+            grid=plan.grid,
+            in_specs=[
+                pl.BlockSpec((bm_, 1), lambda m, n, k: (m, 0)),   # gv rows
+                pl.BlockSpec((bm_, K), lambda m, n, k: (m, 0)),   # x block
+                pl.BlockSpec((K, bn_), lambda m, n, k: (0, n)),   # W block
+                pl.BlockSpec((G, 1), lambda m, n, k: (0, 0)),     # row stacks
+                pl.BlockSpec((G, 1), lambda m, n, k: (0, 0)),
+                pl.BlockSpec((G, bn_), lambda m, n, k: (0, n)),   # col stacks
+                pl.BlockSpec((G, bn_), lambda m, n, k: (0, n)),
+                pl.BlockSpec((1, bn_), lambda m, n, k: (0, n)),   # bias
+            ] + fspecs,
+            out_specs=pl.BlockSpec(out_spec, lambda m, n, k: (m, n)),
+            scratch_shapes=scratch)
+        args = (gv, x, wq, *row_params, *col_params, bias)
+    else:
+        # TGQ group gather: ``g`` rides as the single prefetched scalar
+        # (read before the blocks stream in), and every activation-side
+        # param picks its block row with ``g[0]`` — the DMA engine fetches
+        # only group g's row of each stacked (G, ·) array. A traced g (the
+        # tgroup inside ddpm_sample's scan) therefore changes WHICH rows
+        # stream in, never the executable: one compile covers all groups.
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=plan.grid,
+            in_specs=[
+                pl.BlockSpec((bm_, K), lambda m, n, k, g: (m, 0)),     # x
+                pl.BlockSpec((K, bn_), lambda m, n, k, g: (0, n)),     # W
+                _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),   # rows
+                _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),
+                _group_param((bn_,), lambda m, n, k, g: (g[0], 0, n)),  # cols
+                _group_param((bn_,), lambda m, n, k, g: (g[0], 0, n)),
+                pl.BlockSpec((1, bn_), lambda m, n, k, g: (0, n)),     # bias
+            ] + fspecs,
+            out_specs=pl.BlockSpec(out_spec, lambda m, n, k, g: (m, n)),
+            scratch_shapes=scratch)
+        args = (jnp.asarray(grp, jnp.int32).reshape(1), x, wq,
+                *map(_stack3, row_params), *map(_stack3, col_params), bias)
+    out = pl.pallas_call(
+        functools.partial(kernel, vec=vec, half=half, rows=plan.rows,
+                          **fusions),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
+        # the code scratch is carried across n: only m may be split
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=plan.vmem_limit_bytes),
+        interpret=interpret,
+        name=name,
+    )(*args, *fargs)
+    return out if (Mp, Np) == (M, N) else out[:M, :N]
+
+
+# ---------------------------------------------------------------------------
+# the four kernels: scalar-prefetch TGQ and per-row (vector-tgroup) groups
+# ---------------------------------------------------------------------------
+_STATIC = ("bits", "bm", "bn", "rows_per_batch", "out_dtype", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def int8_matmul_fq(x, wq, sx, zx, scale, corr, bias=None, g=None, *,
                    ps=None, nm=None, gr=None, rows_per_batch=None, bits=8,
-                   bm=DEFAULT_BM, bn=DEFAULT_BN, bk=DEFAULT_BK,
-                   out_dtype=jnp.float32, interpret=False):
+                   bm=None, bn=None, out_dtype=jnp.float32, interpret=False):
     """y[M,N] = (q(x; sx[g], zx[g]) @ wq - corr[g]) * scale[g] (+ bias).
 
     x: (M,K) float, wq: (K,N) int8. Activation-side params are stacked
@@ -312,401 +590,89 @@ def int8_matmul_fq(x, wq, sx, zx, scale, corr, bias=None, g=None, *,
     divisors, ``nm=(shift, scale)`` (B,K) adaLN modulate rows (x must be
     PRE-norm), ``gr=(gate, resid)`` ((B,N), (M,N)) gate+residual
     epilogue, ``rows_per_batch`` x rows per batch entry (required by
-    nm/gr).
+    nm/gr). ``bm``/``bn`` override the planned tiles (``_fq_plan``).
     """
-    half = 2 ** (bits - 1)
-    M, K = x.shape
-    K2, N = wq.shape
-    assert K == K2, (x.shape, wq.shape)
     G = scale.shape[0]
+    N = wq.shape[1]
     assert sx.shape == (G, 1) and zx.shape == (G, 1), (sx.shape, zx.shape)
     assert corr.shape == (G, N), (corr.shape, (G, N))
-    bm_, bn_, bk_ = min(bm, _ceil(M)), min(bn, _ceil(N)), min(bk, _ceil(K))
-    Mp, Np, Kp = _pad_to(M, bm_), _pad_to(N, bn_), _pad_to(K, bk_)
-
-    if bias is None:
-        bias = jnp.zeros((N,), jnp.float32)
-    if g is None:
-        g = 0
-    fspecs, fargs, fusions = _fusions(
-        x, ps, nm, gr, rows_per_batch, has_g=True, M=M, K=K, N=N, Mp=Mp,
-        Kp=Kp, Np=Np, bm_=bm_, bk_=bk_, bn_=bn_)
-    x = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))
-    wq = jnp.pad(wq, ((0, Kp - K), (0, Np - N)))
-    scale = jnp.pad(scale.astype(jnp.float32), ((0, 0), (0, Np - N)))
-    corr = jnp.pad(corr.astype(jnp.int32), ((0, 0), (0, Np - N)))
-    bias = jnp.pad(bias.astype(jnp.float32), (0, Np - N)).reshape(1, Np)
-
-    nk = Kp // bk_
-    grid = (Mp // bm_, Np // bn_, nk)
-    # TGQ group gather: ``g`` rides as the single prefetched scalar (it is
-    # read on the HOST side of the pipeline, before tiles stream in), and
-    # every activation-side param picks its block row with ``g[0]`` — the
-    # DMA engine fetches only group g's row of each stacked (G, ·) array.
-    # A traced g (the tgroup inside ddpm_sample's scan) therefore changes
-    # WHICH rows stream in, never the executable: one compile covers all
-    # timestep groups.
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm_, bk_), lambda m, n, k, g: (m, k)),    # x tile
-            pl.BlockSpec((bk_, bn_), lambda m, n, k, g: (k, n)),    # W tile
-            _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),    # sx[g]
-            _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),    # zx[g]
-            _group_param((bn_,), lambda m, n, k, g: (g[0], 0, n)),  # scale[g]
-            _group_param((bn_,), lambda m, n, k, g: (g[0], 0, n)),  # corr[g]
-            pl.BlockSpec((1, bn_), lambda m, n, k, g: (0, n)),      # bias
-        ] + fspecs,
-        out_specs=pl.BlockSpec((bm_, bn_), lambda m, n, k, g: (m, n)),
-        scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_fq_kernel, nk=nk, half=half,
-                          **fusions),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        interpret=interpret,
-        name="int8_matmul_fq",
-    )(jnp.asarray(g, jnp.int32).reshape(1), x, wq,
-      _stack3(sx.astype(jnp.float32)), _stack3(zx.astype(jnp.float32)),
-      _stack3(scale), _stack3(corr), bias, *fargs)
-    return out[:M, :N]
+    return _fused_call(
+        _fq_kernel, "int8_matmul_fq", x, wq, (sx, zx),
+        (scale.astype(jnp.float32), corr.astype(jnp.int32)), bias,
+        0 if g is None else g, vec=False, mrq=False, half=2 ** (bits - 1),
+        ps=ps, nm=nm, gr=gr, rows_per_batch=rows_per_batch, bm=bm, bn=bn,
+        out_dtype=out_dtype, interpret=interpret)
 
 
-def _mrq_kernel(g_ref, *refs, nk: int, half: int, **fusions):
-    """Grid body for ``int8_matmul_mrq_fq`` at grid point (m, n, k).
-
-    Same tiling/prefetch contract as ``_fq_kernel`` (group-``g`` rows of
-    the stacked (G, ·) params are pre-gathered by the index maps), but
-    with the MRQ twin-region structure: the fp32 x tile is split by sign
-    into two DISJOINT int8 code tiles (each element is zero in exactly
-    one), both multiplied against the SAME weight tile — one VMEM-resident
-    W read feeding two s32 accumulators — and the epilogue recombines them
-    with their per-region scales. That is what collapses the old
-    two-matmul MRQ deployment into a single W traversal. The fusion
-    prologue (norm-modulate, prescale) runs BEFORE the sign split, so the
-    region selection sees the same values the fake-quant path would.
-    """
-    del g_ref
-    x_ref, w_ref, sn_ref, sp_ref, scale_n_ref, scale_p_ref, bias_ref = \
-        refs[:7]
-    o_ref, acc_n_ref, acc_p_ref = refs[-3], refs[-2], refs[-1]
-    ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
-        _unpack_fusion_refs(refs[7:-3], **fusions)
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_n_ref[...] = jnp.zeros_like(acc_n_ref)
-        acc_p_ref[...] = jnp.zeros_like(acc_p_ref)
-
-    # region split in VMEM: sign mask -> two disjoint int8 code tiles
-    xf = _fusion_prologue(x_ref[...].astype(jnp.float32), ps_ref, bv_ref,
-                          mu_ref, rsig_ref, sh_ref, sc_ref)
-    neg = xf < 0
-    qn = jnp.where(neg, jnp.clip(jnp.round(xf / sn_ref[0, 0]), -half, 0),
-                   0).astype(jnp.int8)
-    qp = jnp.where(neg, 0, jnp.clip(jnp.round(xf / sp_ref[0, 0]), 0, half - 1)
-                   ).astype(jnp.int8)
-    w = w_ref[...]                            # ONE weight-tile read, two dots
-    dims = (((1,), (0,)), ((), ()))
-    acc_n_ref[...] += jax.lax.dot_general(qn, w, dims,
-                                          preferred_element_type=jnp.int32)
-    acc_p_ref[...] += jax.lax.dot_general(qp, w, dims,
-                                          preferred_element_type=jnp.int32)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        y = (acc_n_ref[...].astype(jnp.float32) * scale_n_ref[...]
-             + acc_p_ref[...].astype(jnp.float32) * scale_p_ref[...]
-             + bias_ref[...])
-        y = _fusion_epilogue(y, bv_ref, gate_ref, res_ref)
-        o_ref[...] = y.astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("bits", "bm", "bn", "bk",
-                                             "rows_per_batch", "out_dtype",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def int8_matmul_mrq_fq(x, wq, s_neg, s_pos, scale_neg, scale_pos, bias=None,
                        g=None, *, ps=None, nm=None, gr=None,
-                       rows_per_batch=None, bits=8,
-                       bm=DEFAULT_BM, bn=DEFAULT_BN, bk=DEFAULT_BK,
+                       rows_per_batch=None, bits=8, bm=None, bn=None,
                        out_dtype=jnp.float32, interpret=False):
-    """Single-pass MRQ matmul: one traversal of wq, dual s32 accumulators.
+    """Single-pass MRQ matmul: one traversal of wq, dual s32 dots.
 
     y = s_neg[g]*s_w*(qn @ wq) + s_pos[g]*s_w*(qp @ wq) (+ bias) where
     qn/qp are the negative/positive two-region codes of x (disjoint
     support, selected by sign). s_neg/s_pos: (G,1) f32 region steps;
     scale_neg/scale_pos: (G,N) f32 combined region*weight scales.
-    Optional ``ps``/``nm``/``gr``/``rows_per_batch`` fusions as
-    ``int8_matmul_fq`` (the prologue runs before the sign split; prescale
-    divisors are positive, so region selection is unchanged).
+    Optional ``ps``/``nm``/``gr``/``rows_per_batch`` fusions and
+    ``bm``/``bn`` overrides as ``int8_matmul_fq`` (the prologue runs
+    before the sign split; prescale divisors are positive, so region
+    selection is unchanged).
     """
-    M, K = x.shape
-    K2, N = wq.shape
-    assert K == K2, (x.shape, wq.shape)
     G = scale_neg.shape[0]
     assert s_neg.shape == (G, 1) and s_pos.shape == (G, 1)
-    assert scale_pos.shape == (G, N)
-    half = 2 ** (bits - 1)
-    bm_, bn_, bk_ = min(bm, _ceil(M)), min(bn, _ceil(N)), min(bk, _ceil(K))
-    Mp, Np, Kp = _pad_to(M, bm_), _pad_to(N, bn_), _pad_to(K, bk_)
-
-    if bias is None:
-        bias = jnp.zeros((N,), jnp.float32)
-    if g is None:
-        g = 0
-    fspecs, fargs, fusions = _fusions(
-        x, ps, nm, gr, rows_per_batch, has_g=True, M=M, K=K, N=N, Mp=Mp,
-        Kp=Kp, Np=Np, bm_=bm_, bk_=bk_, bn_=bn_)
-    x = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))
-    wq = jnp.pad(wq, ((0, Kp - K), (0, Np - N)))
-    scale_neg = jnp.pad(scale_neg.astype(jnp.float32), ((0, 0), (0, Np - N)))
-    scale_pos = jnp.pad(scale_pos.astype(jnp.float32), ((0, 0), (0, Np - N)))
-    bias = jnp.pad(bias.astype(jnp.float32), (0, Np - N)).reshape(1, Np)
-
-    nk = Kp // bk_
-    grid = (Mp // bm_, Np // bn_, nk)
-    # Same scalar-prefetch group gather as int8_matmul_fq (see the comment
-    # there); here the gathered rows are the two region step sizes and the
-    # two combined region*weight scale rows.
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm_, bk_), lambda m, n, k, g: (m, k)),    # x tile
-            pl.BlockSpec((bk_, bn_), lambda m, n, k, g: (k, n)),    # W tile
-            _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),    # s_neg[g]
-            _group_param((1,), lambda m, n, k, g: (g[0], 0, 0)),    # s_pos[g]
-            _group_param((bn_,), lambda m, n, k, g: (g[0], 0, n)),  # scale_neg
-            _group_param((bn_,), lambda m, n, k, g: (g[0], 0, n)),  # scale_pos
-            pl.BlockSpec((1, bn_), lambda m, n, k, g: (0, n)),      # bias
-        ] + fspecs,
-        out_specs=pl.BlockSpec((bm_, bn_), lambda m, n, k, g: (m, n)),
-        scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32),
-                        pltpu.VMEM((bm_, bn_), jnp.int32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_mrq_kernel, nk=nk, half=half,
-                          **fusions),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        interpret=interpret,
-        name="int8_matmul_mrq_fq",
-    )(jnp.asarray(g, jnp.int32).reshape(1), x, wq,
-      _stack3(s_neg.astype(jnp.float32)), _stack3(s_pos.astype(jnp.float32)),
-      _stack3(scale_neg), _stack3(scale_pos), bias, *fargs)
-    return out[:M, :N]
+    assert scale_pos.shape == (G, wq.shape[1])
+    return _fused_call(
+        _mrq_kernel, "int8_matmul_mrq_fq", x, wq, (s_neg, s_pos),
+        (scale_neg.astype(jnp.float32), scale_pos.astype(jnp.float32)),
+        bias, 0 if g is None else g, vec=False, mrq=True,
+        half=2 ** (bits - 1), ps=ps, nm=nm, gr=gr,
+        rows_per_batch=rows_per_batch, bm=bm, bn=bn, out_dtype=out_dtype,
+        interpret=interpret)
 
 
-# ---------------------------------------------------------------------------
-# vector-tgroup variants: per-ROW group indices, one weight stream
-# ---------------------------------------------------------------------------
-def _fq_vec_kernel(gv_ref, *refs, nk: int, half: int, **fusions):
-    """Vector-tgroup body: same math as ``_fq_kernel`` but each ROW of the
-    x tile quantizes/dequantizes with its own group's params, gathered
-    in VMEM from the full (G, ·) stacks (no scalar prefetch, no per-group
-    weight re-stream)."""
-    x_ref, w_ref, sx_ref, zx_ref, scale_ref, corr_ref, bias_ref = refs[:7]
-    o_ref, acc_ref = refs[-2], refs[-1]
-    ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
-        _unpack_fusion_refs(refs[7:-2], **fusions)
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    gv = gv_ref[...]
-    sx_row = _gather_rows(gv, sx_ref[...])               # (bm, 1)
-    zx_row = _gather_rows(gv, zx_ref[...])               # (bm, 1)
-    xf = _fusion_prologue(x_ref[...].astype(jnp.float32), ps_ref, bv_ref,
-                          mu_ref, rsig_ref, sh_ref, sc_ref)
-    xq = jnp.clip(
-        jnp.round(xf / sx_row) + zx_row - half,
-        -half, half - 1).astype(jnp.int8)
-    acc_ref[...] += jax.lax.dot_general(
-        xq, w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        gv = gv_ref[...]
-        scale_row = _gather_rows(gv, scale_ref[...])           # (bm, bn)
-        corr_row = _gather_rows(gv, corr_ref[...])             # (bm, bn)
-        acc = acc_ref[...] - corr_row
-        y = acc.astype(jnp.float32) * scale_row + bias_ref[...]
-        y = _fusion_epilogue(y, bv_ref, gate_ref, res_ref)
-        o_ref[...] = y.astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("bits", "bm", "bn", "bk",
-                                             "rows_per_batch", "out_dtype",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def int8_matmul_fq_vec(x, wq, sx, zx, scale, corr, bias=None, gv=None, *,
                        ps=None, nm=None, gr=None, rows_per_batch=None, bits=8,
-                       bm=DEFAULT_BM, bn=DEFAULT_BN, bk=DEFAULT_BK,
-                       out_dtype=jnp.float32, interpret=False):
+                       bm=None, bn=None, out_dtype=jnp.float32,
+                       interpret=False):
     """``int8_matmul_fq`` with a per-ROW group vector.
 
     gv: (M,) int32 — row i quantizes with sx[gv[i]]/zx[gv[i]] and
     dequantizes with scale[gv[i]]/corr[gv[i]]. The weight matrix streams
-    ONCE for the whole mixed-group batch; the full (G, ·) param stacks
-    ride along instead (G ≤ ~10, negligible next to W). A constant gv is
-    bit-identical to the scalar-prefetch path. Optional ``ps``/``nm``/
-    ``gr``/``rows_per_batch`` fusions as ``int8_matmul_fq``.
+    once per row block for the whole mixed-group batch; the full (G, ·)
+    param stacks ride along instead (G ≤ ~10, negligible next to W). A
+    constant gv is bit-identical to the scalar-prefetch path. Optional
+    ``ps``/``nm``/``gr``/``rows_per_batch`` fusions and ``bm``/``bn``
+    overrides as ``int8_matmul_fq``.
     """
-    half = 2 ** (bits - 1)
-    M, K = x.shape
-    K2, N = wq.shape
-    assert K == K2, (x.shape, wq.shape)
-    G = scale.shape[0]
+    G, N = scale.shape[0], wq.shape[1]
     assert sx.shape == (G, 1) and zx.shape == (G, 1), (sx.shape, zx.shape)
     assert corr.shape == (G, N), (corr.shape, (G, N))
-    bm_, bn_, bk_ = min(bm, _ceil(M)), min(bn, _ceil(N)), min(bk, _ceil(K))
-    Mp, Np, Kp = _pad_to(M, bm_), _pad_to(N, bn_), _pad_to(K, bk_)
-
-    if bias is None:
-        bias = jnp.zeros((N,), jnp.float32)
-    if gv is None:
-        gv = jnp.zeros((M,), jnp.int32)
-    gv = jnp.pad(jnp.asarray(gv, jnp.int32), (0, Mp - M)).reshape(Mp, 1)
-    fspecs, fargs, fusions = _fusions(
-        x, ps, nm, gr, rows_per_batch, has_g=False, M=M, K=K, N=N, Mp=Mp,
-        Kp=Kp, Np=Np, bm_=bm_, bk_=bk_, bn_=bn_)
-    x = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))
-    wq = jnp.pad(wq, ((0, Kp - K), (0, Np - N)))
-    scale = jnp.pad(scale.astype(jnp.float32), ((0, 0), (0, Np - N)))
-    corr = jnp.pad(corr.astype(jnp.int32), ((0, 0), (0, Np - N)))
-    bias = jnp.pad(bias.astype(jnp.float32), (0, Np - N)).reshape(1, Np)
-
-    nk = Kp // bk_
-    grid = (Mp // bm_, Np // bn_, nk)
-    out = pl.pallas_call(
-        functools.partial(_fq_vec_kernel, nk=nk, half=half,
-                          **fusions),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm_, 1), lambda m, n, k: (m, 0)),     # gv rows
-            pl.BlockSpec((bm_, bk_), lambda m, n, k: (m, k)),   # x tile
-            pl.BlockSpec((bk_, bn_), lambda m, n, k: (k, n)),   # W tile
-            pl.BlockSpec((G, 1), lambda m, n, k: (0, 0)),       # sx stack
-            pl.BlockSpec((G, 1), lambda m, n, k: (0, 0)),       # zx stack
-            pl.BlockSpec((G, bn_), lambda m, n, k: (0, n)),     # scale stack
-            pl.BlockSpec((G, bn_), lambda m, n, k: (0, n)),     # corr stack
-            pl.BlockSpec((1, bn_), lambda m, n, k: (0, n)),     # bias
-        ] + fspecs,
-        out_specs=pl.BlockSpec((bm_, bn_), lambda m, n, k: (m, n)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
-        interpret=interpret,
-        name="int8_matmul_fq_vec",
-    )(gv, x, wq, sx.astype(jnp.float32), zx.astype(jnp.float32),
-      scale, corr, bias, *fargs)
-    return out[:M, :N]
+    gv = jnp.zeros((x.shape[0],), jnp.int32) if gv is None else gv
+    return _fused_call(
+        _fq_kernel, "int8_matmul_fq_vec", x, wq, (sx, zx),
+        (scale.astype(jnp.float32), corr.astype(jnp.int32)), bias, gv,
+        vec=True, mrq=False, half=2 ** (bits - 1), ps=ps, nm=nm, gr=gr,
+        rows_per_batch=rows_per_batch, bm=bm, bn=bn, out_dtype=out_dtype,
+        interpret=interpret)
 
 
-def _mrq_vec_kernel(gv_ref, *refs, nk: int, half: int, **fusions):
-    """Vector-tgroup body for the MRQ twin-region matmul: per-row region
-    steps from ``_gather_rows``, one W read feeding both accumulators."""
-    x_ref, w_ref, sn_ref, sp_ref, scale_n_ref, scale_p_ref, bias_ref = \
-        refs[:7]
-    o_ref, acc_n_ref, acc_p_ref = refs[-3], refs[-2], refs[-1]
-    ps_ref, bv_ref, mu_ref, rsig_ref, sh_ref, sc_ref, gate_ref, res_ref = \
-        _unpack_fusion_refs(refs[7:-3], **fusions)
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_n_ref[...] = jnp.zeros_like(acc_n_ref)
-        acc_p_ref[...] = jnp.zeros_like(acc_p_ref)
-
-    gv = gv_ref[...]
-    sn_row = _gather_rows(gv, sn_ref[...])               # (bm, 1)
-    sp_row = _gather_rows(gv, sp_ref[...])               # (bm, 1)
-    xf = _fusion_prologue(x_ref[...].astype(jnp.float32), ps_ref, bv_ref,
-                          mu_ref, rsig_ref, sh_ref, sc_ref)
-    neg = xf < 0
-    qn = jnp.where(neg, jnp.clip(jnp.round(xf / sn_row), -half, 0),
-                   0).astype(jnp.int8)
-    qp = jnp.where(neg, 0, jnp.clip(jnp.round(xf / sp_row), 0, half - 1)
-                   ).astype(jnp.int8)
-    w = w_ref[...]                            # ONE weight-tile read, two dots
-    dims = (((1,), (0,)), ((), ()))
-    acc_n_ref[...] += jax.lax.dot_general(qn, w, dims,
-                                          preferred_element_type=jnp.int32)
-    acc_p_ref[...] += jax.lax.dot_general(qp, w, dims,
-                                          preferred_element_type=jnp.int32)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        gv = gv_ref[...]
-        scale_n_row = _gather_rows(gv, scale_n_ref[...])
-        scale_p_row = _gather_rows(gv, scale_p_ref[...])
-        y = (acc_n_ref[...].astype(jnp.float32) * scale_n_row
-             + acc_p_ref[...].astype(jnp.float32) * scale_p_row
-             + bias_ref[...])
-        y = _fusion_epilogue(y, bv_ref, gate_ref, res_ref)
-        o_ref[...] = y.astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("bits", "bm", "bn", "bk",
-                                             "rows_per_batch", "out_dtype",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def int8_matmul_mrq_fq_vec(x, wq, s_neg, s_pos, scale_neg, scale_pos,
                            bias=None, gv=None, *, ps=None, nm=None, gr=None,
-                           rows_per_batch=None, bits=8, bm=DEFAULT_BM,
-                           bn=DEFAULT_BN, bk=DEFAULT_BK,
+                           rows_per_batch=None, bits=8, bm=None, bn=None,
                            out_dtype=jnp.float32, interpret=False):
     """``int8_matmul_mrq_fq`` with a per-ROW group vector (see
-    ``int8_matmul_fq_vec`` for the one-weight-read contract)."""
-    M, K = x.shape
-    K2, N = wq.shape
-    assert K == K2, (x.shape, wq.shape)
+    ``int8_matmul_fq_vec`` for the one-weight-stream contract)."""
     G = scale_neg.shape[0]
     assert s_neg.shape == (G, 1) and s_pos.shape == (G, 1)
-    assert scale_pos.shape == (G, N)
-    half = 2 ** (bits - 1)
-    bm_, bn_, bk_ = min(bm, _ceil(M)), min(bn, _ceil(N)), min(bk, _ceil(K))
-    Mp, Np, Kp = _pad_to(M, bm_), _pad_to(N, bn_), _pad_to(K, bk_)
-
-    if bias is None:
-        bias = jnp.zeros((N,), jnp.float32)
-    if gv is None:
-        gv = jnp.zeros((M,), jnp.int32)
-    gv = jnp.pad(jnp.asarray(gv, jnp.int32), (0, Mp - M)).reshape(Mp, 1)
-    fspecs, fargs, fusions = _fusions(
-        x, ps, nm, gr, rows_per_batch, has_g=False, M=M, K=K, N=N, Mp=Mp,
-        Kp=Kp, Np=Np, bm_=bm_, bk_=bk_, bn_=bn_)
-    x = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, Kp - K)))
-    wq = jnp.pad(wq, ((0, Kp - K), (0, Np - N)))
-    scale_neg = jnp.pad(scale_neg.astype(jnp.float32), ((0, 0), (0, Np - N)))
-    scale_pos = jnp.pad(scale_pos.astype(jnp.float32), ((0, 0), (0, Np - N)))
-    bias = jnp.pad(bias.astype(jnp.float32), (0, Np - N)).reshape(1, Np)
-
-    nk = Kp // bk_
-    grid = (Mp // bm_, Np // bn_, nk)
-    out = pl.pallas_call(
-        functools.partial(_mrq_vec_kernel, nk=nk, half=half,
-                          **fusions),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm_, 1), lambda m, n, k: (m, 0)),     # gv rows
-            pl.BlockSpec((bm_, bk_), lambda m, n, k: (m, k)),   # x tile
-            pl.BlockSpec((bk_, bn_), lambda m, n, k: (k, n)),   # W tile
-            pl.BlockSpec((G, 1), lambda m, n, k: (0, 0)),       # s_neg stack
-            pl.BlockSpec((G, 1), lambda m, n, k: (0, 0)),       # s_pos stack
-            pl.BlockSpec((G, bn_), lambda m, n, k: (0, n)),     # scale_neg
-            pl.BlockSpec((G, bn_), lambda m, n, k: (0, n)),     # scale_pos
-            pl.BlockSpec((1, bn_), lambda m, n, k: (0, n)),     # bias
-        ] + fspecs,
-        out_specs=pl.BlockSpec((bm_, bn_), lambda m, n, k: (m, n)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32),
-                        pltpu.VMEM((bm_, bn_), jnp.int32)],
-        interpret=interpret,
-        name="int8_matmul_mrq_fq_vec",
-    )(gv, x, wq, s_neg.astype(jnp.float32), s_pos.astype(jnp.float32),
-      scale_neg, scale_pos, bias, *fargs)
-    return out[:M, :N]
+    assert scale_pos.shape == (G, wq.shape[1])
+    gv = jnp.zeros((x.shape[0],), jnp.int32) if gv is None else gv
+    return _fused_call(
+        _mrq_kernel, "int8_matmul_mrq_fq_vec", x, wq, (s_neg, s_pos),
+        (scale_neg.astype(jnp.float32), scale_pos.astype(jnp.float32)),
+        bias, gv, vec=True, mrq=True, half=2 ** (bits - 1), ps=ps, nm=nm,
+        gr=gr, rows_per_batch=rows_per_batch, bm=bm, bn=bn,
+        out_dtype=out_dtype, interpret=interpret)

@@ -34,14 +34,109 @@ def _rand_case(M, K, N, G, seed=0):
 # ---------------------------------------------------------------------------
 # fused-quantize matmul
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("block", [(32, 64, 64), (128, 128, 256)])
+@pytest.mark.parametrize("block", [(32, 128), (128, 256)])
 def test_int8_matmul_fq_block_shapes(block):
-    bm, bn, bk = block
-    x, wq, sx, zx, scale, corr, _ = _rand_case(100, 300, 90, G=2, seed=1)
+    """Overridden (bm, bn) tiles: several row and column blocks over
+    ragged M and N (K is always one block)."""
+    bm, bn = block
+    x, wq, sx, zx, scale, corr, _ = _rand_case(100, 300, 290, G=2, seed=1)
     out = int8_matmul_fq(x, wq, sx, zx, scale, corr, g=1, bm=bm, bn=bn,
-                         bk=bk, interpret=True)
+                         interpret=True)
     want = ref.int8_matmul_fq_ref(x, wq, sx, zx, scale, corr, g=1)
     assert float(jnp.max(jnp.abs(out - want))) <= 1e-4
+
+
+# (kernel, fusion, bm): B = 4 entries of T = 64 rows, so bm 64 takes the
+# adaLN rows from the index maps and bm 128 ("nm-rows") selects each
+# row's entry in VMEM; bm 64 and 128 quantize in 2 and 4 row slices.
+_TILE_KERNELS = ("fq", "mrq", "fq_vec", "mrq_vec")
+_TILE_FUSIONS = (("none", 64), ("nm", 64), ("nm-rows", 128), ("gr", 64),
+                 ("ps", 64))
+
+
+@pytest.mark.parametrize("fusion,bm", _TILE_FUSIONS,
+                         ids=[f for f, _ in _TILE_FUSIONS])
+@pytest.mark.parametrize("kernel", _TILE_KERNELS)
+def test_int8_fused_tiles_bit_identical(kernel, fusion, bm):
+    """Several m and n tiles (4 or 2 row blocks x 3 weight blocks, N
+    ragged) with the overrides: the int8 codes quantized at each row
+    block's first n step are reused by its other n steps and refreshed
+    at the next row block, bit-identically to the jitted oracles."""
+    from repro.kernels.int8_fused import (
+        int8_matmul_fq_vec, int8_matmul_mrq_fq_vec,
+    )
+    B, T, K, N, G = 4, 64, 200, 300, 3
+    M = B * T
+    x, wq, sx, zx, scale, corr, bias = _rand_case(M, K, N, G, seed=21)
+    ks = jax.random.split(jax.random.PRNGKey(22), 5)
+    bv = jnp.repeat(jnp.arange(B, dtype=jnp.int32), T)
+    fkw = {}
+    if fusion in ("nm", "nm-rows"):
+        fkw["nm"] = (jax.random.normal(ks[0], (B, K)) * 0.5,
+                     jax.random.normal(ks[1], (B, K)) * 0.2)
+    elif fusion == "gr":
+        fkw["gr"] = (jax.random.normal(ks[2], (B, N)) * 0.8,
+                     jax.random.normal(ks[3], (M, N)))
+    elif fusion == "ps":
+        fkw["ps"] = jnp.exp(jax.random.uniform(ks[4], (K,), minval=-1.0,
+                                               maxval=1.0))
+    rkw = dict(fkw, bv=bv)
+    if "nm" in fkw or "gr" in fkw:
+        fkw["rows_per_batch"] = T
+    gv = jnp.repeat(jnp.arange(B, dtype=jnp.int32) % G, T)
+    if kernel.startswith("mrq"):
+        x = jax.nn.gelu(x)
+        params = (x, wq, sx * 0.1, sx, scale * 0.1, scale, bias)
+        kern = int8_matmul_mrq_fq_vec if kernel == "mrq_vec" else \
+            int8_matmul_mrq_fq
+        oracle = ref.int8_matmul_mrq_fq_vec_fused_ref \
+            if kernel == "mrq_vec" else ref.int8_matmul_mrq_fq_fused_ref
+    else:
+        params = (x, wq, sx, zx, scale, corr, bias)
+        kern = int8_matmul_fq_vec if kernel == "fq_vec" else int8_matmul_fq
+        oracle = ref.int8_matmul_fq_vec_fused_ref if kernel == "fq_vec" \
+            else ref.int8_matmul_fq_fused_ref
+    grp = {"gv": gv} if kernel.endswith("_vec") else {"g": 2}
+    got = kern(*params, **grp, bm=bm, bn=128, interpret=True, **fkw)
+    want = jax.jit(oracle)(*params, **grp, **rkw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# DiT-XL/2's linears as served: (site, K, N, MRQ input, per-batch fusion).
+# The pool runs 4,096 token rows at 256 tokens (8 slots x 2 CFG halves)
+# and at 1,024 (2 x 2); the per-sample sites take one row per entry.
+_XL2_SITES = [
+    ("x_proj", 16, 1152, False, False), ("t_mlp1", 256, 1152, False, False),
+    ("t_mlp2", 1152, 1152, False, False), ("ada", 1152, 6912, False, False),
+    ("qkv", 1152, 3456, False, True), ("proj", 1152, 1152, False, True),
+    ("fc1", 1152, 4608, False, True), ("fc2", 4608, 1152, True, True),
+    ("final_ada", 1152, 2304, False, False), ("final", 1152, 16, False, True),
+]
+_PER_SAMPLE = ("t_mlp1", "t_mlp2", "ada", "final_ada")
+
+
+@pytest.mark.parametrize("tokens", [256, 1024])
+@pytest.mark.parametrize("site,K,N,mrq,fused", _XL2_SITES,
+                         ids=[c[0] for c in _XL2_SITES])
+def test_fq_plan_at_served_shapes(site, K, N, mrq, fused, tokens):
+    """The planned tiles quantize each x element once, keep the adaLN
+    rows on the index-map path, stream each weight byte into at least 512
+    operations at 4,096 rows, fit the VMEM limit they ask for, and take
+    under 200 grid steps a call (the k-tiled grid took up to 5,760)."""
+    from repro.kernels.int8_fused import _fq_plan
+    entries = 4096 // tokens
+    M = entries if site in _PER_SAMPLE else 4096
+    plan = _fq_plan(M, K, N, x_itemsize=2, mrq=mrq,
+                    rows_per_batch=tokens if fused else None)
+    assert plan.quant_passes == 1
+    if fused:
+        assert tokens % plan.bm == 0
+    if M == 4096:
+        assert plan.bm >= 256
+    assert plan.vmem_bytes <= plan.vmem_limit_bytes <= 128 * 2 ** 20
+    assert plan.steps < 200
+    assert plan.grid == (plan.Mp // plan.bm, plan.Np // plan.bn, 1)
+    assert plan.Np == N and plan.Mp == max(M, 8)
 
 
 def test_int8_matmul_fq_matches_unfused_pipeline():

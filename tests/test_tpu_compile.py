@@ -76,21 +76,31 @@ def _compile(fn, *args, **kwargs):
                           compiled.as_text()))
 
 
-# (kernel, G, K, N, fusion) — the linears of one DiT block: qkv and fc1
-# take the adaLN norm-modulate prologue, proj and fc2 (MRQ input) the
+# (kernel, G, K, N, fusion, rows) — the linears of one DiT block: qkv and
+# fc1 take the adaLN norm-modulate prologue, proj and fc2 (MRQ input) the
 # gate+residual epilogue; ada runs on one row per batch entry, unfused.
 # A batch entry owns TOK rows, so each row tile lies in one entry;
 # "nm-rows" gives an entry 64 rows, which makes the kernel select each
-# row's entry in VMEM instead.
+# row's entry in VMEM instead. The served pool (8 slots x 2 CFG halves)
+# runs 4,096 token rows and 16 per-sample rows, at the planned tiles;
+# x_proj (K = 16) and final (N = 16) are its narrowest linears.
+SERVED = 2 * M
 LINEARS = [
-    ("fq", 1, D, 3 * D, "nm"),
-    ("fq", 10, D, 3 * D, "nm"),
-    ("fq", 10, D, 6 * D, None),
-    ("mrq", 10, FF, D, "gr"),
-    ("fq_vec", 10, D, FF, "nm"),
-    ("fq_vec", 10, D, FF, "nm-rows"),
-    ("fq_vec", 10, D, D, "gr"),
-    ("mrq_vec", 10, FF, D, "gr"),
+    ("fq", 1, D, 3 * D, "nm", M),
+    ("fq", 10, D, 3 * D, "nm", M),
+    ("fq", 10, D, 6 * D, None, ROWS),
+    ("mrq", 10, FF, D, "gr", M),
+    ("fq_vec", 10, D, FF, "nm", M),
+    ("fq_vec", 10, D, FF, "nm-rows", M),
+    ("fq_vec", 10, D, D, "gr", M),
+    ("mrq_vec", 10, FF, D, "gr", M),
+    ("fq_vec", 10, D, 3 * D, "nm", SERVED),
+    ("fq_vec", 10, D, D, "gr", SERVED),
+    ("fq_vec", 10, D, FF, "nm", SERVED),
+    ("mrq_vec", 10, FF, D, "gr", SERVED),
+    ("fq_vec", 10, D, 6 * D, None, 16),
+    ("fq_vec", 10, 16, D, None, SERVED),
+    ("fq_vec", 10, D, 16, "nm", SERVED),
 ]
 KERNELS = {"fq": int8_matmul_fq, "mrq": int8_matmul_mrq_fq,
            "fq_vec": int8_matmul_fq_vec, "mrq_vec": int8_matmul_mrq_fq_vec}
@@ -98,12 +108,17 @@ NAMES = {"fq": "int8_matmul_fq", "mrq": "int8_matmul_mrq_fq",
          "fq_vec": "int8_matmul_fq_vec", "mrq_vec": "int8_matmul_mrq_fq_vec"}
 
 
-@pytest.mark.parametrize("kind,G,K,N,fusion", LINEARS,
-                         ids=[f"{k}-G{g}-{n}-{f}"
-                              for k, g, _, n, f in LINEARS])
-def test_int8_linear_compiles_for_v5e(one_chip, kind, G, K, N, fusion):
+def _linear_id(kind, G, K, N, fusion, n):
+    base = f"{kind}-G{G}-{N}-{fusion}"
+    if n == (M if fusion else ROWS):
+        return base
+    return base + ("" if K == D else f"-K{K}") + f"-{n}rows"
+
+
+@pytest.mark.parametrize("kind,G,K,N,fusion,n", LINEARS,
+                         ids=[_linear_id(*c) for c in LINEARS])
+def test_int8_linear_compiles_for_v5e(one_chip, kind, G, K, N, fusion, n):
     sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
-    n = M if fusion else ROWS
     corr = sds((G, N), f32 if kind.startswith("mrq") else i32)
     args = (sds((n, K), bf16), sds((K, N), i8), sds((G, 1), f32),
             sds((G, 1), f32), sds((G, N), f32), corr, sds((N,), f32))
@@ -114,7 +129,7 @@ def test_int8_linear_compiles_for_v5e(one_chip, kind, G, K, N, fusion):
         kw["nm"] = (sds((n // per_batch, K), f32),
                     sds((n // per_batch, K), f32))
     elif fusion == "gr":
-        kw["gr"] = (sds((ROWS, N), f32), sds((n, N), f32))
+        kw["gr"] = (sds((n // per_batch, N), f32), sds((n, N), f32))
     if fusion:
         kw["rows_per_batch"] = per_batch
     assert _compile(KERNELS[kind], *args, **kw) == {NAMES[kind]}
