@@ -6,7 +6,8 @@ Pipeline (Algorithm 1):
      is a marked post-softmax / post-GELU / post-SiLU tensor).
   2. ``CalibrationContext``  — eager FP forwards over the calibration set;
      stores (batch-subsampled) operand tensors per op, tagged with the
-     TGQ timestep group.
+     TGQ timestep group. The range pipeline's ``RangeCaptureContext``
+     keeps only statistics instead, reduced on the device.
   3. ``TapContext``          — jitted forward with additive zero "taps" on
      every op output; ``jax.grad`` w.r.t. the taps yields exactly
      dL/dz^(l), the Fisher weights of Hessian-guided optimization.
@@ -118,15 +119,21 @@ def stable_seed(name: str, base: int = 0) -> int:
     return base + (zlib.crc32(name.encode()) & 0xFFFF)
 
 
+def _row_index(n_rows, max_rows, seed):
+    """The rows a capture keeps of ``n_rows``: all (None), or a seeded
+    draw of ``max_rows`` without replacement."""
+    if n_rows <= max_rows:
+        return None
+    return np.random.default_rng(seed).choice(n_rows, max_rows,
+                                              replace=False)
+
+
 def _subsample_rows(x, max_rows, seed):
     """Flatten leading dims to rows and subsample; returns np.ndarray."""
     x = np.asarray(x)
     rows = x.reshape(-1, x.shape[-1])
-    if rows.shape[0] > max_rows:
-        rng = np.random.default_rng(seed)
-        idx = rng.choice(rows.shape[0], max_rows, replace=False)
-        rows = rows[idx]
-    return rows
+    idx = _row_index(rows.shape[0], max_rows, seed)
+    return rows if idx is None else rows[idx]
 
 
 @dataclasses.dataclass
@@ -194,6 +201,148 @@ class CalibrationContext(OpContext):
             self.act_store.setdefault(name, []).append(_subsample_rows(
                 x, self.max_rows_per_batch, stable_seed(name, self.seed)))
         return x
+
+
+# ---------------------------------------------------------------------------
+# range capture: statistics reduced on the device
+# ---------------------------------------------------------------------------
+PROB_BINS_PER_OCTAVE = 8
+PROB_OCTAVES = 24
+PROB_BINS = PROB_BINS_PER_OCTAVE * PROB_OCTAVES
+# rows of probabilities (one query's softmax each) a capture histograms per
+# site and batch, strided evenly over batch entries, heads and queries: a
+# scatter into the bins costs per element on a TPU, and 2,048 rows of 1,024
+# keys describe the distribution as well as every row does
+PROB_ROWS = 2048
+
+
+def prob_bin_edges() -> np.ndarray:
+    """The ``PROB_BINS + 1`` edges of the post-softmax histogram,
+    log-spaced over [2^-24, 1]; bin 0 also takes everything below."""
+    return 2.0 ** (np.arange(PROB_BINS + 1) / PROB_BINS_PER_OCTAVE
+                   - PROB_OCTAVES)
+
+
+@jax.jit
+def prob_histogram(p):
+    """``(PROB_BINS, 3)`` f32: per bin of ``prob_bin_edges``, the count of
+    the probabilities ``p`` in it and their sum and sum of squares. Counts
+    are exact up to 2^24 a bin."""
+    p = p.astype(jnp.float32).reshape(-1)
+    lg = jnp.log2(jnp.maximum(p, 2.0 ** -PROB_OCTAVES))
+    idx = jnp.clip(jnp.floor((lg + PROB_OCTAVES) * PROB_BINS_PER_OCTAVE),
+                   0, PROB_BINS - 1).astype(jnp.int32)
+    rows = jnp.stack([jnp.ones_like(p), p, p * p], axis=-1)
+    return jnp.zeros((PROB_BINS, 3), jnp.float32).at[idx].add(rows)
+
+
+def key_moments(spec: str, b):
+    """``[sum of mean^2, sum of variance]`` of einsum operand ``b`` over
+    the axis it contracts with operand a (the keys of P V), summed over
+    every other axis."""
+    ins, out = spec.split("->")
+    a_spec, b_spec = ins.split(",")
+    axis = next(i for i, c in enumerate(b_spec)
+                if c in a_spec and c not in out)
+    b = b.astype(jnp.float32)
+    m = jnp.mean(b, axis=axis, keepdims=True)
+    return jnp.stack([jnp.sum(m * m),
+                      jnp.sum(jnp.mean(jnp.square(b - m), axis=axis))])
+
+
+@dataclasses.dataclass
+class RangeCaptureContext(OpContext):
+    """The range pipeline's capture. Run EAGERLY; every statistic is
+    reduced ON THE DEVICE as the forward runs, and only ``reduce()``
+    brings them to the host, so what the host holds does not grow with
+    the number or size of calibration batches.
+
+    stats[name][stat][tg] (device arrays until ``reduce``), folded over
+    batches by max, or by sum for 'p_hist' and 'v_moments':
+      linear 'x': ``[-min, max]`` of the row-subsampled input (the same
+        rows ``CalibrationContext`` keeps);
+      einsum 'a' / 'b': ``[absmax]`` of each operand; a post-softmax 'a'
+        gets 'p_hist' instead (``prob_histogram`` of ``PROB_ROWS`` of
+        its rows), and its 'b' (the values) also 'v_moments'
+        (``key_moments``).
+    Weights are captured once in ``weights[name]``, as in
+    ``CalibrationContext``; they are the model's, not calibration data.
+    """
+    registry: Dict[str, OpInfo] = dataclasses.field(default_factory=dict)
+    stats: Dict[str, Dict[str, Dict[int, Any]]] = dataclasses.field(
+        default_factory=dict)
+    weights: Dict[str, np.ndarray] = dataclasses.field(default_factory=dict)
+    max_rows_per_batch: int = 256
+    seed: int = 0
+    _seen: set = dataclasses.field(default_factory=set)
+
+    SUMMED = ("p_hist", "v_moments")
+
+    def begin_batch(self):
+        """Reset per-forward dedup (only the FIRST call site of a shared
+        op name is captured, as in ``CalibrationContext``)."""
+        self._seen.clear()
+
+    def _first(self, name) -> bool:
+        if name in self._seen:
+            return False
+        self._seen.add(name)
+        return True
+
+    def _add(self, name, stat, value):
+        """Fold one batch's statistic into the running one."""
+        per = self.stats.setdefault(name, {}).setdefault(stat, {})
+        tg = int(self.tgroup) if self.tgroup is not None else 0
+        prev = per.get(tg)
+        if prev is not None:
+            value = (prev + value if stat in self.SUMMED
+                     else jnp.maximum(prev, value))
+        per[tg] = value
+
+    @staticmethod
+    def _absmax(a):
+        return jnp.max(jnp.abs(a)).astype(jnp.float32)[None]
+
+    def linear(self, name, x, w, b=None, norm_mod=None, gate_residual=None):
+        x = apply_norm_mod(x, norm_mod)
+        if self._first(name):
+            if name not in self.weights:
+                self.weights[name] = np.asarray(w)
+            rows = x.reshape(-1, x.shape[-1])
+            idx = _row_index(rows.shape[0], self.max_rows_per_batch,
+                             stable_seed(name, self.seed))
+            if idx is not None:
+                rows = rows[jnp.asarray(idx)]
+            self._add(name, "x", jnp.stack(
+                [-jnp.min(rows), jnp.max(rows)]).astype(jnp.float32))
+        y = x @ w
+        if b is not None:
+            y = y + b
+        return apply_gate_residual(y, gate_residual)
+
+    def einsum(self, name, spec, a, b, b_is_weight=False):
+        if self._first(name):
+            info = self.registry.get(name)
+            if info is not None and info.a_kind == "post_softmax":
+                rows = a.reshape(-1, a.shape[-1])
+                stride = -(-rows.shape[0] // PROB_ROWS)
+                self._add(name, "p_hist", prob_histogram(rows[::stride]))
+                self._add(name, "v_moments", key_moments(spec, b))
+            else:
+                self._add(name, "a", self._absmax(a))
+            if b_is_weight:
+                if name not in self.weights:
+                    self.weights[name] = np.asarray(b)
+            else:
+                self._add(name, "b", self._absmax(b))
+        return jnp.einsum(spec, a, b)
+
+    def act(self, name, x, kind):
+        return x
+
+    def reduce(self) -> Dict[str, Dict[str, Dict[int, Any]]]:
+        """The statistics on the host (numpy), same nesting."""
+        return jax.tree.map(np.asarray, jax.device_get(self.stats))
 
 
 # ---------------------------------------------------------------------------

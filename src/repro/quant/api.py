@@ -106,34 +106,37 @@ def quantize(params, model_cfg, dif_cfg, recipe: QuantRecipe,
     sched = sched if sched is not None else make_schedule(dif_cfg)
     key = jax.random.PRNGKey(recipe.seed)
 
-    if recipe.method == "range":
-        from repro.serving.quickcal import range_calibrate
-        qparams, weights = range_calibrate(
-            params, model_cfg, dif_cfg, sched, key,
-            wbits=recipe.wbits, abits=recipe.abits,
-            n_per_group=recipe.n_per_group, batch=recipe.calib_batch,
-            max_rows=recipe.max_rows_per_batch)
-        calib_stats: Dict[str, Any] = {"n_quantized": len(qparams)}
-    else:                                               # "ho"
-        from repro.core.calib import build_dit_calibration, dit_loss_fn
-        from repro.core.ptq import run_ptq
-        if calib_data is None:
-            x0 = lambda n, k: jax.random.normal(
-                k, (n, model_cfg.img_size, model_cfg.img_size,
-                    model_cfg.in_ch))
-            calib_data = build_dit_calibration(
-                params, model_cfg, dif_cfg, sched, x0, key,
-                n_per_group=recipe.n_per_group, batch=recipe.calib_batch)
-        qparams, report = run_ptq(dit_loss_fn(params, model_cfg),
-                                  calib_data,
-                                  recipe.ptq_config(dif_cfg.tgq_groups))
-        weights = report.pop("weights")     # full fp copy — never persisted
-        calib_stats = {k: v for k, v in report.items()
-                       if isinstance(v, (int, float, str))}
+    with jax.profiler.TraceAnnotation("quant.calibrate"):
+        if recipe.method == "range":
+            from repro.serving.quickcal import range_calibrate
+            calib_stats: Dict[str, Any] = {}
+            qparams, weights = range_calibrate(
+                params, model_cfg, dif_cfg, sched, key,
+                wbits=recipe.wbits, abits=recipe.abits,
+                n_per_group=recipe.n_per_group, batch=recipe.calib_batch,
+                max_rows=recipe.max_rows_per_batch, stats=calib_stats)
+            calib_stats = {"n_quantized": len(qparams), **calib_stats}
+        else:                                           # "ho"
+            from repro.core.calib import build_dit_calibration, dit_loss_fn
+            from repro.core.ptq import run_ptq
+            if calib_data is None:
+                x0 = lambda n, k: jax.random.normal(
+                    k, (n, model_cfg.img_size, model_cfg.img_size,
+                        model_cfg.in_ch))
+                calib_data = build_dit_calibration(
+                    params, model_cfg, dif_cfg, sched, x0, key,
+                    n_per_group=recipe.n_per_group, batch=recipe.calib_batch)
+            qparams, report = run_ptq(dit_loss_fn(params, model_cfg),
+                                      calib_data,
+                                      recipe.ptq_config(dif_cfg.tgq_groups))
+            weights = report.pop("weights")  # full fp copy — never persisted
+            calib_stats = {k: v for k, v in report.items()
+                           if isinstance(v, (int, float, str))}
 
     if recipe.kernel_deployable:
         from repro.kernels.ops import convert_for_kernels
-        qparams = convert_for_kernels(qparams, weights)
+        with jax.profiler.TraceAnnotation("quant.pack"):
+            qparams = convert_for_kernels(qparams, weights)
 
     from repro.checkpoint import ckpt
     meta = {

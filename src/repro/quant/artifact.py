@@ -80,6 +80,14 @@ def _encode(obj: Any, leaves: List[np.ndarray]) -> dict:
                     f"arrays, {sorted(_QUANTIZERS)})")
 
 
+def _fmt(v: Any) -> str:
+    """A calibration counter for ``summary()``: numbers to 3 figures,
+    lists compactly."""
+    if isinstance(v, (list, tuple)):
+        return "[" + ",".join(_fmt(x) for x in v) + "]"
+    return f"{v:.3g}" if isinstance(v, float) else str(v)
+
+
 def _decode(spec: dict, leaves: List[Any]) -> Any:
     k = spec["k"]
     if k == "none":
@@ -224,10 +232,15 @@ class QuantArtifact:
         packs = f"{n8} int8 linear packs"
         if n4:
             packs = f"{n4} packed-int4 linear packs"
+        cal = self.meta.get("calib", {})
+        counters = "".join(
+            f", {k}={_fmt(cal[k])}" for k in
+            ("capture_host_bytes", "softmax_s1", "softmax_r2_share")
+            if k in cal)
         return (f"QuantArtifact({self.recipe.bits}/{self.recipe.method}: "
                 f"{len(self.qparams)} ops, {packs}, "
                 f"{n_attn} int8 attention blocks, "
-                f"G={self.meta.get('tgq_groups')})")
+                f"G={self.meta.get('tgq_groups')}{counters})")
 
     # -- persistence --------------------------------------------------------
     def save(self, path: str) -> str:

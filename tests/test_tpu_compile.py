@@ -146,12 +146,16 @@ def test_int4_linear_vec_compiles_for_v5e(one_chip):
     assert names == {"int4_matmul_fq_vec"}
 
 
-@pytest.mark.parametrize("vec", [False, True], ids=["scalar", "vec"])
-def test_flash_attention_compiles_for_v5e(one_chip, vec):
-    """head_dim 72 (not a lane multiple), S = 256, G = 10."""
+# (vec, tokens, rows): the 256-token pool, and DiT-XL/2 at 512x512 (1,024
+# tokens; 2 slots run 4 CFG rows), where one kv tile spans every key.
+@pytest.mark.parametrize("vec,S,rows", [(False, TOK, ROWS), (True, TOK, ROWS),
+                                        (False, 1024, 4), (True, 1024, 4)],
+                         ids=["scalar", "vec", "scalar-1k", "vec-1k"])
+def test_flash_attention_compiles_for_v5e(one_chip, vec, S, rows):
+    """head_dim 72 (not a lane multiple), S = 256 and 1,024, G = 10."""
     sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
-    BH, G = ROWS * HEADS, 10
-    qkv = [sds((BH, TOK, HD), bf16) for _ in range(3)]
+    BH, G = rows * HEADS, 10
+    qkv = [sds((BH, S, HD), bf16) for _ in range(3)]
     params = [sds((G, 1), f32) for _ in range(7)]
     g = sds((BH,), i32) if vec else sds((), i32)
     names = _compile(flash_attn_mrq_vec if vec else flash_attn_mrq, *qkv,
