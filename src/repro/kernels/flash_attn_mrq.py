@@ -9,11 +9,13 @@ remaining attention traffic. This kernel streams K/V tiles per
 (batch·head, q-tile) grid point and keeps the whole quadratic
 intermediate in VMEM:
 
-1. **int8 QK^T** — the q and k tiles are quantized with the group-``g``
-   symmetric per-tensor steps in the VMEM prologue (same ``SymQ``
-   contract as ``int8_bmm_qk``); the s32 MXU product dequantizes with the
-   combined ``s_q[g]·s_k[g]·alpha`` scale into an f32 (bm, bn) score tile
-   that never leaves VMEM.
+1. **int8 QK^T** — q, k and v arrive as symmetric s8 codes at the
+   group-``g`` steps (same ``SymQ`` contract as ``int8_bmm_qk``), formed
+   ONCE per call before the kernel (``group_codes``; ``ops.flash_attention``
+   forms them ahead of its head transposes, so XLA fuses the quantize into
+   the relayout of the qkv output and the transposes move s8). The
+   s32 MXU product dequantizes with the combined ``s_q[g]·s_k[g]·alpha``
+   scale into an f32 (bm, bn) score tile that never leaves VMEM.
 2. **Ragged / user masking BEFORE the online max** — kv lanes past the
    true sequence length (S not a multiple of the k-tile) and user-masked
    lanes are set to ``NEG_INF`` *before* the running-max update.
@@ -33,8 +35,8 @@ intermediate in VMEM:
    composed path transports as region-signed bytes — here they are formed
    and consumed inside VMEM.
 5. **Dual-region P·V with fp running-rescale** — each region tile
-   multiplies the in-VMEM-quantized v tile on the MXU into an s32
-   product, accumulated into two f32 region accumulators with the flash
+   multiplies the v code tile on the MXU into an s32 product,
+   accumulated into two f32 region accumulators with the flash
    rescale ``rho = exp(m - m')·l/l'`` applied to the previously
    accumulated contributions. Because ``p̃·(Π rho) == exp(s - m_fin)/l_fin``
    exactly in real arithmetic, the only divergence from the composed
@@ -59,14 +61,16 @@ k/v-side batch; the shared kv tile is gathered via a ``b // rep`` index
 map — no materialized copies, and kv HBM traffic does not scale with the
 number of query groups.
 
-Traffic: q is read from HBM once in fp, the output written once, and
-the K/V stream is re-fetched once per q-tile (the standard flash trade:
-``ceil(M/bm)`` reads each — exactly ONE at DiT-serving sequence lengths,
-since the default q-tile ``bm = 256`` covers DiT-XL/2's S = 256). The
-(S, S) scores/codes round-trip — ``BH·S²·10`` bytes on the composed
-path — is eliminated entirely: ≥3x whole-attention traffic cut at
-DiT-XL/2 shapes (``benchmarks/kernel_micro.py::traffic_attention_flash``
-charges the kv re-reads honestly).
+Traffic: the layout pass reads q, k and v once in their stored dtype
+and writes s8 codes (nibble-packed k/v at 4 bits); the kernel reads the
+q codes once, writes the output once, and re-fetches the k/v codes once
+per q-tile (the standard flash trade: ``ceil(M/bm)`` reads each — one at
+256 tokens, where the default q-tile ``bm = 256`` covers the sequence,
+four at 1,024). The (S, S) scores/codes round-trip — ``BH·S²·10`` bytes
+on the composed path — is eliminated entirely: ≥3x whole-attention
+traffic cut at DiT-XL/2 shapes
+(``benchmarks/kernel_micro.py::traffic_attention_flash`` charges the kv
+re-reads honestly).
 
 Grid: (B, M/bm, N/bn) with the kv axis innermost; the running stats and
 both accumulators live in VMEM scratch persisting across the kv axis.
@@ -83,7 +87,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.int4_packed import nibble_split, pack_int4
-from repro.kernels.int8_bmm import _sym_codes, _sym_levels
+from repro.kernels.int8_bmm import _sym_codes
 from repro.kernels.int8_matmul import _ceil, _group_param, _pad_to, _stack3
 
 # The q-tile covers DiT-XL/2's full S = 256, so K/V stream from HBM
@@ -112,31 +116,55 @@ def kv_tile(m: int, n: int, bm: int = DEFAULT_BM) -> int:
     return MULTI_TILE_BN
 
 
+def group_codes(x, s, g, bits: int):
+    """x -> symmetric s8 codes at group ``g``'s step of the (G, 1) stack
+    ``s``; ``g`` is a scalar, or a vector over x's leading axis (each row
+    or slot at its own group)."""
+    step = jnp.take(s.astype(jnp.float32)[:, 0], g, axis=0)
+    step = step.reshape(step.shape + (1,) * (x.ndim - step.ndim))
+    return _sym_codes(x, step, 2 ** (bits - 1))
+
+
+def _code_pass(q, k, v, s_q, s_k, s_v, g_q, g_k, g_v, *, bits: int,
+               mp: int, np_: int, bd: int, packed_kv: bool):
+    """The layout pass before the kernel: float q/k/v -> s8 codes at their
+    groups' steps (``group_codes``; s8 inputs are codes already), padded
+    to the kernel's blocks with code 0, which is inert. ``packed_kv`` then
+    nibble-packs the k/v codes along D."""
+    def codes(x, s, g, rows):
+        c = x if x.dtype == jnp.int8 else group_codes(x, s, g, bits)
+        return jnp.pad(c, ((0, 0), (0, rows - x.shape[1]),
+                           (0, bd - x.shape[2])))
+
+    q8, k8, v8 = (codes(q, s_q, g_q, mp), codes(k, s_k, g_k, np_),
+                  codes(v, s_v, g_v, np_))
+    if packed_kv:
+        k8, v8 = pack_int4(k8, axis=-1), pack_int4(v8, axis=-1)
+    return q8, k8, v8
+
+
 def _flash_kernel(g_ref, *refs, nkv: int, half: int, n_real: int, bn: int,
                   neg_inf: float, has_mask: bool, packed_kv: bool = False,
                   bd: int = 0):
     """Grid body at (b, m, n) — n (the kv tile) innermost.
 
-    ``refs`` unpacks to the tile refs (q, k, v[, mask8]), the group-``g``
-    rows of the stacked (G, 1) params (s_q, s_k, qk_scale, s1, s_v,
-    scale1, scale2), the output ref and the four VMEM scratch refs
-    (running max / denominator as (bm, 128) lane-broadcast stats, two
-    (bm, D) f32 region accumulators). ``g_ref`` ([g_qk, g_pv]) feeds the
-    index maps only.
+    ``refs`` unpacks to the s8 code tiles (q, k, v[, mask8]), the
+    group-``g`` rows of the stacked (G, 1) params (qk_scale, s1, scale1,
+    scale2), the output ref and the four VMEM scratch refs (running max /
+    denominator as (bm, 128) lane-broadcast stats, two (bm, D) f32 region
+    accumulators). ``g_ref`` ([g_qk, g_pv]) feeds the index maps only.
 
-    ``packed_kv``: k/v tiles arrive as (bn, bd/2) nibble-PACKED
-    pre-quantized 4-bit codes (the W4A4 path's one-time pack pass) and
-    are widened to s8-range codes here instead of running ``_sym_codes``
-    — halving the kv bytes streamed per q-tile.
+    ``packed_kv``: k/v tiles arrive as (bn, bd/2) nibble-packed 4-bit
+    codes and are widened to s8 here, halving the kv bytes streamed per
+    q-tile.
     """
     del g_ref
     if has_mask:
-        (q_ref, k_ref, v_ref, mask_ref, sq_ref, sk_ref, qs_ref, s1_ref,
-         sv_ref, sc1_ref, sc2_ref, o_ref, m_ref, l_ref, acc1_ref,
-         acc2_ref) = refs
+        (q_ref, k_ref, v_ref, mask_ref, qs_ref, s1_ref, sc1_ref, sc2_ref,
+         o_ref, m_ref, l_ref, acc1_ref, acc2_ref) = refs
     else:
-        (q_ref, k_ref, v_ref, sq_ref, sk_ref, qs_ref, s1_ref, sv_ref,
-         sc1_ref, sc2_ref, o_ref, m_ref, l_ref, acc1_ref, acc2_ref) = refs
+        (q_ref, k_ref, v_ref, qs_ref, s1_ref, sc1_ref, sc2_ref, o_ref,
+         m_ref, l_ref, acc1_ref, acc2_ref) = refs
     n = pl.program_id(2)
 
     @pl.when(n == 0)
@@ -146,17 +174,15 @@ def _flash_kernel(g_ref, *refs, nkv: int, half: int, n_real: int, bn: int,
         acc1_ref[...] = jnp.zeros_like(acc1_ref)
         acc2_ref[...] = jnp.zeros_like(acc2_ref)
 
-    # -- int8 QK^T for this tile (scores stay in VMEM) ----------------------
-    q8 = _sym_codes(q_ref[0], sq_ref[0, 0], half)
+    k8, v8 = k_ref[0], v_ref[0]
     if packed_kv:                # widen two-nibbles-per-byte codes in VMEM
-        lo, hi = nibble_split(k_ref[0])
-        k8 = jnp.stack([lo, hi], axis=2).reshape(
-            k_ref.shape[1], bd).astype(jnp.int8)
-    else:
-        k8 = _sym_codes(k_ref[0], sk_ref[0, 0], half)
+        k8, v8 = (jnp.stack(nibble_split(c), axis=2).reshape(
+            c.shape[0], bd).astype(jnp.int8) for c in (k8, v8))
+
+    # -- int8 QK^T for this tile (scores stay in VMEM) ----------------------
     s = jax.lax.dot_general(
-        q8, k8, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
-    ).astype(jnp.float32) * qs_ref[0, 0]
+        q_ref[0], k8, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32).astype(jnp.float32) * qs_ref[0, 0]
 
     # -- NEG_INF masking BEFORE the online max ------------------------------
     # Ragged kv: lanes past the true length get the additive mask now —
@@ -183,24 +209,17 @@ def _flash_kernel(g_ref, *refs, nkv: int, half: int, n_real: int, bn: int,
     c1 = jnp.where(region1, jnp.clip(jnp.round(p / s1), 0, half - 1), 0.0
                    ).astype(jnp.int8)
     # region-2 codes reach 2^{k-1}, one past the s8 maximum: they enter the
-    # MXU negated, against the negated v codes (symmetric, so still s8)
+    # MXU negated, and their product is subtracted
     c2n = jnp.where(region1, 0.0, -jnp.clip(jnp.round(p / s2), 0, half)
                     ).astype(jnp.int8)
 
     # -- dual-region P·V with fp running-rescale ----------------------------
-    if packed_kv:
-        lo_v, hi_v = nibble_split(v_ref[0])
-        vq = jnp.stack([lo_v, hi_v], axis=2).reshape(v_ref.shape[1], bd)
-    else:
-        vq = _sym_levels(v_ref[0], sv_ref[0, 0], half)
     dims = (((1,), (0,)), ((), ()))                  # ONE v-tile read
-    d1 = jax.lax.dot_general(c1, vq.astype(jnp.int8), dims,
-                             preferred_element_type=jnp.int32)
-    d2 = jax.lax.dot_general(c2n, (-vq).astype(jnp.int8), dims,
-                             preferred_element_type=jnp.int32)
+    d1 = jax.lax.dot_general(c1, v8, dims, preferred_element_type=jnp.int32)
+    d2n = jax.lax.dot_general(c2n, v8, dims, preferred_element_type=jnp.int32)
     rho = corr * l_prev / l_new                      # <= 1; 0 at n == 0
     acc1_ref[...] = acc1_ref[...] * rho + d1.astype(jnp.float32)
-    acc2_ref[...] = acc2_ref[...] * rho + d2.astype(jnp.float32)
+    acc2_ref[...] = acc2_ref[...] * rho - d2n.astype(jnp.float32)
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
@@ -219,7 +238,7 @@ def flash_attn_mrq(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
     """out[B,M,D] = MRQ-quantized softmax(q8 k8^T · qk_scale[g]) @ v8 —
     one kernel, no (S, S) HBM round-trip.
 
-    q: (B, M, D) float; k, v: (Bk, N, D) float with B = rep · Bk (GQA —
+    q: (B, M, D); k, v: (Bk, N, D) with B = rep · Bk (GQA —
     the shared kv head is gathered via a ``b // rep`` index map).
     s_q/s_k: (Gq, 1) f32 symmetric steps; qk_scale: (Gq, 1) combined
     ``s_q[g]·s_k[g]·alpha`` (alpha = the softmax scale, folded by the
@@ -229,16 +248,15 @@ def flash_attn_mrq(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
     (scalar-prefetched together; no retrace across groups). mask:
     optional (B, M, N) boolean (True = attend), streamed as int8 tiles.
 
-    ``packed_kv`` (4-bit only): k/v are quantized with the group-g steps
-    and nibble-packed along D in ONE jnp pre-pass; the kernel then
-    streams half the kv bytes per q-tile and widens nibbles in its
-    prologue. The trade is honest: the pack pass reads kv in fp and
-    writes the packed codes once, so it wins when kv is re-streamed
-    (ceil(M/bm) > 1, long S) and is neutral at one q-tile — see
-    ``benchmarks/kernel_micro.traffic_attention_flash_packed``.
-    Numerics are IDENTICAL to the unpacked 4-bit path (same symmetric
-    codes, formed once instead of per tile), so the same oracle and
-    flash-vs-composed tolerance contract apply.
+    The kernel streams s8 codes: float q, k and v are quantized at their
+    group's steps in ONE layout pass before it (``_code_pass``), and s8
+    q, k and v are taken as those codes already (``group_codes``: how
+    ``ops.flash_attention`` hands them over, formed before its head
+    transposes). ``packed_kv`` (4-bit only) nibble-packs the k/v codes
+    along D in the same pass, so the kernel streams half the kv bytes per
+    q-tile and widens nibbles in VMEM. Either way the codes are the same
+    symmetric codes, so one oracle and one flash-vs-composed tolerance
+    contract apply.
     """
     B, M, D = q.shape
     B2, N, D2 = k.shape
@@ -250,6 +268,7 @@ def flash_attn_mrq(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
         (s_q.shape, s_k.shape, qk_scale.shape)
     assert s_v.shape == (Gp, 1) and scale1.shape == (Gp, 1) \
         and scale2.shape == (Gp, 1), (s1.shape, s_v.shape)
+    assert bits == 4 or not packed_kv, "packed_kv: 4-bit codes only"
     half = 2 ** (bits - 1)
     bm_ = min(bm, _ceil(M))
     bn_ = kv_tile(M, N, bm) if bn is None else min(bn, _ceil(N))
@@ -258,20 +277,10 @@ def flash_attn_mrq(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
 
     g = jnp.stack([jnp.asarray(0 if g_qk is None else g_qk, jnp.int32),
                    jnp.asarray(0 if g_pv is None else g_pv, jnp.int32)])
-    q = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, Mp - M), (0, bd_ - D)))
-    k = jnp.pad(k.astype(jnp.float32), ((0, 0), (0, Np - N), (0, bd_ - D)))
-    v = jnp.pad(v.astype(jnp.float32), ((0, 0), (0, Np - N), (0, bd_ - D)))
-
-    kv_bd = bd_
-    if packed_kv:
-        assert bits == 4, "packed_kv streams nibbles: 4-bit codes only"
-        # one-time quantize+pack pass (jnp): group-g symmetric codes,
-        # two per byte along D. Padded lanes/dims are code 0 — inert.
-        sk_g = jnp.take(s_k.astype(jnp.float32), g[0], axis=0)[0]
-        sv_g = jnp.take(s_v.astype(jnp.float32), g[1], axis=0)[0]
-        k = pack_int4(_sym_codes(k, sk_g, half), axis=-1)
-        v = pack_int4(_sym_codes(v, sv_g, half), axis=-1)
-        kv_bd = bd_ // 2
+    q, k, v = _code_pass(q, k, v, s_q, s_k, s_v, g[0], g[0], g[1],
+                         bits=bits, mp=Mp, np_=Np, bd=bd_,
+                         packed_kv=packed_kv)
+    kv_bd = bd_ // 2 if packed_kv else bd_
 
     has_mask = mask is not None
     operands = [q, k, v]
@@ -292,9 +301,9 @@ def flash_attn_mrq(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
     qk_row = lambda b, m, n, g: (g[0], 0, 0)                 # qk-side group
     pv_row = lambda b, m, n, g: (g[1], 0, 0)                 # pv-side group
     operands += [_stack3(p.astype(jnp.float32)) for p in
-                 (s_q, s_k, qk_scale, s1, s_v, scale1, scale2)]
-    in_specs += [_group_param((1,), qk_row)] * 3 \
-        + [_group_param((1,), pv_row)] * 4
+                 (qk_scale, s1, scale1, scale2)]
+    in_specs += [_group_param((1,), qk_row)] \
+        + [_group_param((1,), pv_row)] * 3
 
     # the one masking value, shared with the composed path and the oracle
     # (deferred import: repro.nn pulls in model layers at package init)
@@ -342,8 +351,8 @@ def flash_attn_mrq_vec(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
 
     GQA: q rows sharing a kv row (``b // rep``) must share a group —
     true by construction when rows are slots (``ops.flash_attention``
-    repeats each slot's group over its heads/query-groups); ``packed_kv``
-    uses kv row ``j``'s group ``g[j * rep]`` for the one-time pack pass.
+    repeats each slot's group over its heads/query-groups); the layout
+    pass codes kv row ``j`` at group ``g[j * rep]``'s steps.
     """
     B, M, D = q.shape
     B2, N, D2 = k.shape
@@ -355,6 +364,7 @@ def flash_attn_mrq_vec(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
         (s_q.shape, s_k.shape, qk_scale.shape)
     assert s_v.shape == (Gp, 1) and scale1.shape == (Gp, 1) \
         and scale2.shape == (Gp, 1), (s1.shape, s_v.shape)
+    assert bits == 4 or not packed_kv, "packed_kv: 4-bit codes only"
     half = 2 ** (bits - 1)
     bm_ = min(bm, _ceil(M))
     bn_ = kv_tile(M, N, bm) if bn is None else min(bn, _ceil(N))
@@ -366,23 +376,12 @@ def flash_attn_mrq_vec(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
     gpv = (jnp.zeros((B,), jnp.int32) if g_pv is None
            else jnp.asarray(g_pv, jnp.int32).reshape(B))
     g = jnp.concatenate([gqk, gpv])                          # (2B,)
-    q = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, Mp - M), (0, bd_ - D)))
-    k = jnp.pad(k.astype(jnp.float32), ((0, 0), (0, Np - N), (0, bd_ - D)))
-    v = jnp.pad(v.astype(jnp.float32), ((0, 0), (0, Np - N), (0, bd_ - D)))
-
-    kv_bd = bd_
-    if packed_kv:
-        assert bits == 4, "packed_kv streams nibbles: 4-bit codes only"
-        # one-time quantize+pack pass with PER-KV-ROW group steps: kv row
-        # j serves q rows [j*rep, (j+1)*rep) which share a group (slots),
-        # so row j packs with g[j*rep]'s step.
-        gk_kv = gqk.reshape(B2, rep)[:, 0]
-        gp_kv = gpv.reshape(B2, rep)[:, 0]
-        sk_g = jnp.take(s_k.astype(jnp.float32), gk_kv, axis=0)[:, :, None]
-        sv_g = jnp.take(s_v.astype(jnp.float32), gp_kv, axis=0)[:, :, None]
-        k = pack_int4(_sym_codes(k, sk_g, half), axis=-1)
-        v = pack_int4(_sym_codes(v, sv_g, half), axis=-1)
-        kv_bd = bd_ // 2
+    # kv row j serves q rows [j*rep, (j+1)*rep), which share a group
+    # (slots), so it takes row j*rep's steps
+    gk, gv = gqk.reshape(B2, rep)[:, 0], gpv.reshape(B2, rep)[:, 0]
+    q, k, v = _code_pass(q, k, v, s_q, s_k, s_v, gqk, gk, gv, bits=bits,
+                         mp=Mp, np_=Np, bd=bd_, packed_kv=packed_kv)
+    kv_bd = bd_ // 2 if packed_kv else bd_
 
     has_mask = mask is not None
     operands = [q, k, v]
@@ -403,9 +402,9 @@ def flash_attn_mrq_vec(q, k, v, s_q, s_k, qk_scale, s1, s_v, scale1, scale2,
     qk_row = lambda b, m, n, g: (g[b], 0, 0)             # row b's qk group
     pv_row = lambda b, m, n, g: (g[B + b], 0, 0)         # row b's pv group
     operands += [_stack3(p.astype(jnp.float32)) for p in
-                 (s_q, s_k, qk_scale, s1, s_v, scale1, scale2)]
-    in_specs += [_group_param((1,), qk_row)] * 3 \
-        + [_group_param((1,), pv_row)] * 4
+                 (qk_scale, s1, scale1, scale2)]
+    in_specs += [_group_param((1,), qk_row)] \
+        + [_group_param((1,), pv_row)] * 3
 
     from repro.nn.ctx import NEG_INF
 

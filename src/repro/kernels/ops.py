@@ -73,7 +73,8 @@ from repro.kernels.int4_packed import (
 from repro.kernels.int8_bmm import (
     int8_bmm_pv, int8_bmm_pv_vec, int8_bmm_qk, int8_bmm_qk_vec,
 )
-from repro.kernels.flash_attn_mrq import flash_attn_mrq, flash_attn_mrq_vec
+from repro.kernels.flash_attn_mrq import flash_attn_mrq, flash_attn_mrq_vec, \
+    group_codes
 from repro.kernels.softmax_mrq import (
     softmax_mrq, softmax_mrq_codes, softmax_mrq_codes_vec,
 )
@@ -726,6 +727,12 @@ def flash_attention(q, k, v, qk_pack: dict, pv_pack: dict, *, mask=None,
     g_qk = _group_index(qk_pack, tgroup)
     g_pv = _group_index(pv_pack, tgroup)
 
+    bits = int(qk_pack.get("bits", 8))
+    # s8 codes at each slot's steps BEFORE the head transposes: XLA fuses
+    # the quantize into the relayout of q/k/v, and the transposes move s8
+    q = group_codes(q, qk_pack["s_q"], g_qk, bits)
+    k = group_codes(k, qk_pack["s_k"], g_qk, bits)
+    v = group_codes(v, pv_pack["s_v"], g_pv, bits)
     qf = q.transpose(0, 2, 3, 1, 4).reshape(BHG, Sq, hd)
     kf = k.transpose(0, 2, 1, 3).reshape(B * Hk, Skv, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(B * Hk, Skv, hd)
@@ -734,7 +741,6 @@ def flash_attention(q, k, v, qk_pack: dict, pv_pack: dict, *, mask=None,
         mf = jnp.broadcast_to(mask, (B, Hk, G, Sq, Skv)
                               ).reshape(BHG, Sq, Skv)
 
-    bits = int(qk_pack.get("bits", 8))
     if _is_vec(g_qk) or _is_vec(g_pv):
         # Vector-tgroup batched path: slot-major (BHG,) group vectors,
         # one flash call for the whole mixed-timestep batch (weights and

@@ -32,6 +32,7 @@ from repro.core.quantizers import MRQSoftmaxQ, SymQ, TGQ
 from repro.kernels import flash_attn_mrq, int8_bmm_pv, int8_bmm_qk, \
     softmax_mrq_codes
 from repro.kernels import ops, ref
+from repro.kernels.flash_attn_mrq import flash_attn_mrq_vec
 
 
 def _attn_qparams(G, seed=0):
@@ -226,6 +227,51 @@ def test_flash_user_mask_matches_composed():
     out_t = out_t.reshape(B, Hk, Gq, Sq, hd).transpose(0, 3, 1, 2, 4)
     np.testing.assert_allclose(np.asarray(out_t), np.asarray(want),
                                rtol=0, atol=atol)
+
+
+def _pallas_operands(jaxpr):
+    """The operand avals of the one ``pallas_call`` inside ``jaxpr``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return [v.aval for v in eqn.invars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found = _pallas_operands(sub)
+            if found:
+                return found
+    return None
+
+
+@pytest.mark.parametrize("path", ["scalar", "vec", "ops"])
+@pytest.mark.parametrize("bits", [8, 4])
+def test_flash_streams_int8_codes(bits, path):
+    """q, k and v reach the kernel as s8 codes formed once before it, not
+    as f32 for the kernel to quantize at every grid step: in the wrappers'
+    layout pass for float inputs, and ahead of the head transposes on the
+    served path (``ops.flash_attention``). At 4 bits the k/v codes are
+    nibble-packed, two per byte along D."""
+    B, M, N, D = 2, 9, 20, 16
+    qk_pack, pv_pack = _packs(G=3)
+    q, k, v = (x.astype(jnp.bfloat16) for x in _case(B, M, N, D, seed=12))
+    if path == "ops":
+        qk_pack, pv_pack = (dict(p, bits=bits) for p in (qk_pack, pv_pack))
+        fn = functools.partial(
+            ops.flash_attention, q[:, :, None, None], k[:, :, None],
+            v[:, :, None], qk_pack, pv_pack, tgroup=jnp.asarray([2, 0]))
+    else:
+        g = jnp.asarray([2, 0], jnp.int32) if path == "vec" else 1
+        fn = functools.partial(
+            flash_attn_mrq_vec if path == "vec" else flash_attn_mrq,
+            q, k, v, qk_pack["s_q"], qk_pack["s_k"], qk_pack["scale"],
+            pv_pack["s1"], pv_pack["s_v"], pv_pack["scale1"],
+            pv_pack["scale2"], g_qk=g, g_pv=g, bits=bits,
+            packed_kv=(bits == 4), interpret=True)
+    avals = _pallas_operands(jax.make_jaxpr(fn)().jaxpr)
+    q8, k8, v8 = avals[1:4]                 # after the prefetched groups
+    assert (q8.dtype, k8.dtype, v8.dtype) == (jnp.int8,) * 3
+    assert q8.shape[-1] == D
+    assert k8.shape[-1] == v8.shape[-1] == (D // 2 if bits == 4 else D)
+    # four param rows remain: qk_scale, s1, scale1, scale2
+    assert len(avals) == 1 + 3 + 4
 
 
 def test_flash_gqa_shared_kv():

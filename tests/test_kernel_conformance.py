@@ -693,6 +693,35 @@ def test_flash_vector_tgroup_conformance(bname, shape):
                 _assert_conforms("flash_packed_kv", got, unpacked)
 
 
+@pytest.mark.parametrize("vec", [False, True], ids=["scalar", "vec"])
+@pytest.mark.parametrize("bname", sorted(BITS))
+def test_flash_several_q_tiles_one_kv_tile(bname, vec):
+    """M = N = 512 at the default bm 256 and kv tile: two q tiles stream
+    the same whole-sequence kv tile (the 1,024-token serving grid in
+    small), with per-row mixed groups on the vector path."""
+    bits = BITS[bname]
+    B, S, D, G = 3, 512, 8, 5
+    scale = D ** -0.5
+    qk_qp, pv_qp = _attn_qparams(G, bits, seed=41)
+    qk_pack = ops.pack_int8_qk(qk_qp)
+    pv_pack = ops.pack_int8_pv(pv_qp)
+    q, k, v = _attn_case(B, S, S, D, seed=43)
+    if vec:
+        gv = _mix_rows(B, G, salt=1)
+        got = _flash_vec(q, k, v, qk_pack, pv_pack, gv, scale, None, bits,
+                         packed_kv=(bits == 4))
+        want = _jit_ref(ref.flash_attn_mrq_vec_ref, bits=bits,
+                        scale=scale)(q, k, v, qk_pack, pv_pack, g_qk=gv,
+                                     g_pv=gv)
+        _assert_conforms("flash_vec", got, want)
+    else:
+        got = _flash(q, k, v, qk_pack, pv_pack, G - 1, scale, None, bits,
+                     packed_kv=(bits == 4))
+        want = _jit_ref(ref.flash_attn_mrq_ref, bits=bits, scale=scale)(
+            q, k, v, qk_pack, pv_pack, g_qk=G - 1, g_pv=G - 1)
+        _assert_conforms("flash", got, want)
+
+
 @pytest.mark.parametrize("bname", sorted(BITS))
 def test_ops_vector_tgroup_matches_per_slot(bname):
     """The ops-layer contract of the vector-tgroup batched path: ONE call

@@ -161,3 +161,49 @@ def test_flash_attention_compiles_for_v5e(one_chip, vec, S, rows):
     names = _compile(flash_attn_mrq_vec if vec else flash_attn_mrq, *qkv,
                      *params, g_qk=g, g_pv=g)
     assert names == {"flash_attn_mrq_vec" if vec else "flash_attn_mrq"}
+
+
+def _unfused_instructions(text):
+    """(name, type) of every instruction of the compiled program outside
+    fusion bodies: what the program writes to memory."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    out, keep = [], False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) ", line)
+        if head and not line.startswith(" "):
+            keep = head.group(1) not in fused
+        elif keep:
+            m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (\(?\w+\[[\d,]*\])", line)
+            if m:
+                out.append(m.groups())
+    return out
+
+
+@pytest.mark.parametrize("S,rows", [(TOK, ROWS), (1024, 4)],
+                         ids=["256", "1k"])
+def test_served_attention_writes_no_f32_qkv(one_chip, monkeypatch, S, rows):
+    """The served attention, from the qkv linear's output to the flash
+    call: q/k/v become s8 codes in the pass that splits the heads, so no
+    instruction writes them in f32 (once, every grid step quantized a
+    transposed f32 copy)."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "INTERPRET", False)
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    G = 10
+
+    def attn(qkv, sq, sk, sc, s1, sv, sc1, sc2, gv):
+        q, k, v = jnp.split(qkv.reshape(rows, S, 3, HEADS, HD), 3, axis=2)
+        qk = {"s_q": sq, "s_k": sk, "scale": sc, "bits": 8, "groups": G}
+        pv = {"s1": s1, "s_v": sv, "scale1": sc1, "scale2": sc2, "bits": 8,
+              "groups": G}
+        return ops.flash_attention(q[:, :, 0, :, None], k[:, :, 0],
+                                   v[:, :, 0], qk, pv, scale=HD ** -0.5,
+                                   tgroup=gv)
+
+    text = jax.jit(attn).lower(sds((rows, S, 3 * D), bf16),
+                               *[sds((G, 1), f32)] * 7,
+                               sds((rows,), i32)).compile().as_text()
+    written = _unfused_instructions(text)
+    assert any(n.startswith("flash_attn_mrq_vec") for n, _ in written)
+    qkv_f32 = f"f32[{rows},{HEADS}"
+    assert not [w for w in written if qkv_f32 in w[1]], written
